@@ -67,7 +67,6 @@ def run_fused_round(worker_counts=(256, 1024, 4096, 10240), *, e2e=True,
     the largest W ≤ 4096 — interpret-mode Pallas is NOT on this path;
     on CPU the fused chain dispatches to the identical flat-jnp math).
     """
-    from repro.compat.xla import normalize_cost_analysis
     from repro.configs.base import FederationConfig, TrainConfig
     from repro.configs.registry import get_config
     from repro.core import fl_step, hierarchy, trust
@@ -108,8 +107,7 @@ def run_fused_round(worker_counts=(256, 1024, 4096, 10240), *, e2e=True,
                             iters=iters, warmup=1)
         fused_us = timeit(jax.jit(fused), flat, lb, la,
                           iters=iters, warmup=1)
-        cost = normalize_cost_analysis(
-            jax.jit(per_leaf).lower(upd, lb, la).compile().cost_analysis())
+        cost = jax.jit(per_leaf).lower(upd, lb, la).compile().cost_analysis()
         vol = W * D * 4
         unfused_passes = cost.get("bytes accessed", 0.0) / vol
         fused_passes = fused_round.update_passes(W, D, jnp.float32)

@@ -23,6 +23,9 @@ def main() -> None:
     only = set(args.only.split(",")) if args.only else None
     q = args.quick
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (async_ablation, async_node, cfl_baseline,
                             fig2_blockchain, fig3_scalability,
                             fig4_reliability, fig56_convergence,

@@ -790,6 +790,20 @@ class FederatedTask:
 
     # -- one round, split around the tick's settlement handoff ---------------
 
+    def lower_round(self, batch: Dict[str, np.ndarray],
+                    participation: Optional[np.ndarray] = None):
+        """The jitted round this task dispatches, lowered for a batch of
+        this shape (leaves (W, B, ...)) at the current state: ``.compile()``
+        gives the executable, whose ``as_text()`` and ``memory_analysis()``
+        show what the device runs."""
+        batch = {k: jnp.asarray(v)[:, None] for k, v in batch.items()}
+        part = (None if participation is None
+                else jnp.asarray(participation, jnp.int32))
+        args = (self.global_params, self.opt_state, batch, self.rng, part)
+        if self.fed.async_mode:
+            args += (self.async_state,)
+        return self._round_fn.lower(*args)
+
     def _dispatch_round(self, batch: Dict[str, np.ndarray],
                         participation: Optional[np.ndarray]
                         ) -> _StartedRound:
@@ -820,10 +834,7 @@ class FederatedTask:
             out = self._round_fn(self.global_params, self.opt_state, batch,
                                  rkey, part)
         self.global_params, self.opt_state = out.global_params, out.opt_state
-        try:                       # start device→host copy of the scores
-            out.scores.copy_to_host_async()
-        except AttributeError:     # backend without async host copies
-            pass
+        out.scores.copy_to_host_async()   # start device→host score copy
         return _StartedRound(ridx, out, t0, participation, stale)
 
     def _finish_round(self, st: _StartedRound, chain_time: float

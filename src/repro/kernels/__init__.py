@@ -12,13 +12,20 @@ Module map
     precision — and every kernel upcasts tiles to f32 on read. Trees
     mixing leaf dtypes are not packable and stay on the per-leaf path.
 
+``tpu``
+    What the kernels know about the chip: per-lowering dispatch
+    (``on_tpu`` — kernel when lowered for a TPU, reference elsewhere) and
+    tile planning against the scoped-VMEM budget (``plan_tiles``).
+
 ``trust_score``
     One-sweep trust statistics over the packed (W, D) update matrix:
     per-worker <u_w, c> / ‖u_w‖² plus ‖c‖² vs the consensus mean, in a
-    single streamed HBM pass (column-blocked, full-W tiles).
+    single streamed HBM pass (full-W column strips; W-tiled, two sweeps,
+    where a strip cannot fit VMEM).
 
 ``trust_agg``
-    Trust-weighted aggregate Σ_w w_w·u_w → (D,) f32, one streamed pass.
+    Trust-weighted aggregate Σ_w w_w·u_w → (D,) f32, one streamed pass
+    (W tiles accumulated per D tile where a full-W strip cannot fit).
 
 ``fused_round``
     The fused device-resident trust round: chains ``trust_score`` +
@@ -26,8 +33,8 @@ Module map
     update volume — the information floor, since aggregation weights
     depend on global statistics of the whole matrix), plus the 2-D-grid
     async kernel folding pending buffers + participation masking into
-    the same sweep. Backend dispatch lives here: TPU runs the Pallas
-    kernels natively, CPU runs the identical flat-jnp reference math
+    the same sweep. A round lowered for a TPU runs the Pallas kernels;
+    lowered for CPU it runs the identical flat-jnp reference math
     (``SDFLB_FUSED_INTERPRET=1`` forces interpret-mode Pallas — the CI
     kernel-correctness smoke). Also the analytic HBM accounting
     (``streamed_bytes`` / ``update_passes``) behind the benchmark gate.

@@ -24,18 +24,22 @@ floor is two streamed passes, and this module hits it:
                               path's buffer logic costs no extra pass
                               over the update matrix
 
-Dispatch: on TPU the Pallas kernels run natively; on CPU/CI the flat-jnp
-references (``kernels.ref``) execute the identical packed math (interpret
-mode is for kernel-correctness tests — set ``SDFLB_FUSED_INTERPRET=1`` to
-force the Pallas bodies through the interpreter end-to-end).
+Dispatch (``tpu.on_tpu``): a program lowered for a TPU runs the Pallas
+kernels; lowered for any other platform it runs the flat-jnp references
+(``kernels.ref``), the identical packed math. The platform the round is
+compiled for decides, when it is lowered — not the process's backend at
+import. ``SDFLB_FUSED_INTERPRET=1`` sends the non-TPU branch through the
+interpreted kernels instead (kernel-correctness end to end on CPU).
 
-Tiling: the sync kernels hold full-W column blocks in VMEM, so
-``block_d_for`` shrinks the D tile as W grows (W ≲ 16k bf16 / 12k f32 at
-the 128-lane floor — the 10k-cohort target fits; beyond that the
-per-leaf path remains). The async kernel tiles BOTH dims (grid =
-D-tiles × W-tiles, aggregate accumulated over the inner W axis), so its
-cohort size is unbounded; its pending buffer persists padded to the tile
-grid (``pending_shape``) so no per-round pad/slice copies are needed.
+Tiling: each sync kernel sizes its tiles against the chip's scoped VMEM
+(``trust_score.tiles`` / ``trust_agg.tiles``): a full-W column strip at
+the widest D tile that fits, and W tiles as well where even a 128-lane
+strip does not — so every cohort size compiles. Only a W-tiled
+``trust_score`` sweeps the matrix twice (column sums, then statistics).
+The async kernel tiles BOTH dims (grid = D-tiles × W-tiles, aggregate
+accumulated over the inner W axis); its pending buffer persists padded
+to the tile grid (``pending_shape``) so no per-round pad/slice copies are
+needed.
 
 ``streamed_bytes``/``update_passes`` compute the chain's exact HBM
 traffic from the BlockSpec geometry (every index map visits each element
@@ -46,44 +50,18 @@ and uses cost_analysis only for the unfused comparison.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import ref
+from repro.kernels import ref, trust_score
+from repro.kernels.tpu import on_tpu, round_up
 from repro.kernels.trust_agg import trust_agg
 from repro.kernels.trust_score import trust_score_stats
 
-LANE = 128
 SUBLANE = 8
-# VMEM budget for one streamed tile (the pipeline double-buffers on top)
-_VMEM_TILE_BUDGET = 8 * 1024 * 1024
-
-INTERPRET = jax.default_backend() != "tpu"
-# CI smoke knob: force the Pallas bodies through the interpreter instead
-# of the flat-jnp reference dispatch (kernel-correctness end-to-end)
-FORCE_KERNEL = os.environ.get("SDFLB_FUSED_INTERPRET", "") == "1"
-
-
-def _use_kernel() -> bool:
-    return (not INTERPRET) or FORCE_KERNEL
-
-
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
-
-
-def block_d_for(W: int, itemsize: int) -> int:
-    """Lane-aligned D tile for the full-W-block kernels: as wide as the
-    VMEM tile budget allows at this W, capped at 2048 and floored at one
-    lane (the floor can exceed the budget for W ≳ 12k f32 — documented
-    ceiling of the sync kernels)."""
-    lanes = _VMEM_TILE_BUDGET // max(1, W * itemsize * LANE)
-    return int(min(2048, max(LANE, lanes * LANE)))
-
 
 # -- async kernel geometry ----------------------------------------------------
 
@@ -95,8 +73,8 @@ def pending_shape(W: int, D: int) -> tuple:
     """Persistent (W_pad, D_pad) storage shape of the flat async pending
     buffer — padded once at init to the async kernel's tile grid so
     rounds never pad/slice the (W, D) volume."""
-    bw = min(BLOCK_W, _round_up(W, SUBLANE))
-    return (_round_up(W, bw), _round_up(D, BLOCK_D_ASYNC))
+    bw = min(BLOCK_W, round_up(W, SUBLANE))
+    return (round_up(W, bw), round_up(D, BLOCK_D_ASYNC))
 
 
 # -- the async fused kernel ---------------------------------------------------
@@ -178,26 +156,17 @@ def fused_async_agg_kernel(updates, pending, weights, keep, *,
 def fused_stats(updates: jax.Array):
     """Pass 1: (W, D) → (dot (W,), sq_u (W,), sq_c ()) vs the inclusive
     consensus, in one HBM sweep."""
-    if _use_kernel():
-        bd = block_d_for(*_wd_itemsize(updates))
-        return trust_score_stats(updates, block_d=bd, interpret=INTERPRET)
-    return ref.trust_score_ref(updates)
+    return on_tpu(trust_score_stats, ref.trust_score_ref, updates)
 
 
 def fused_agg(updates: jax.Array, weights: jax.Array) -> jax.Array:
     """Pass 2 (sync): (W, D) × (W,) → (D,) f32 weighted aggregate."""
-    if _use_kernel():
-        bd = block_d_for(*_wd_itemsize(updates))
-        return trust_agg(updates, weights, block_d=bd, interpret=INTERPRET)
-    return ref.trust_agg_ref(updates, weights)
+    return on_tpu(trust_agg, ref.trust_agg_ref, updates, weights)
 
 
-def fused_async_agg(updates, pending, weights, keep):
-    """Pass 2 (async): see ``fused_async_agg_kernel``. The flat-jnp
-    dispatch mirrors the padded pending geometry exactly."""
-    if _use_kernel():
-        return fused_async_agg_kernel(updates, pending, weights, keep,
-                                      interpret=INTERPRET)
+def _async_agg_ref(updates, pending, weights, keep):
+    """Flat-jnp twin of ``fused_async_agg_kernel`` on the same padded
+    pending geometry."""
     W, D = updates.shape
     Wp, Dp = pending.shape
     upd = jnp.pad(updates, ((0, Wp - W), (0, Dp - D)))
@@ -207,8 +176,10 @@ def fused_async_agg(updates, pending, weights, keep):
     return agg[:D], newp
 
 
-def _wd_itemsize(updates):
-    return updates.shape[0], jnp.dtype(updates.dtype).itemsize
+def fused_async_agg(updates, pending, weights, keep):
+    """Pass 2 (async): see ``fused_async_agg_kernel``."""
+    return on_tpu(fused_async_agg_kernel, _async_agg_ref,
+                  updates, pending, weights, keep)
 
 
 # -- exact HBM accounting (BlockSpec geometry) --------------------------------
@@ -220,14 +191,16 @@ def streamed_bytes(W: int, D: int, dtype, *, async_mode: bool = False):
     per call). Returns {update_read, other, total} in bytes."""
     isz = jnp.dtype(dtype).itemsize
     upd = W * D * isz
-    stats_out = (2 * W + 1) * 4
+    st = trust_score.tiles(W, D, isz)
+    stats_sweeps = 1 if st.nw == 1 else 2          # W-tiled: sums, then stats
+    n_d = -(-D // st.bd)
+    stats_out = (2 * n_d * st.w_pad + D) * 4      # partial rows, consensus
+    update_read = (stats_sweeps + 1) * upd        # stats sweeps + agg pass
     if async_mode:
         Wp, Dp = pending_shape(W, D)
-        update_read = 2 * upd                     # stats pass + agg pass
         other = (Wp * Dp * 4) * 2 + Dp * 4 \
             + (2 * Wp) * 4 + stats_out            # pending r/w, agg, rows
     else:
-        update_read = 2 * upd
         other = D * 4 + W * 4 + stats_out         # aggregate out, weights
     return {"update_read": float(update_read), "other": float(other),
             "total": float(update_read + other)}

@@ -1,10 +1,13 @@
-"""Jit'd public wrappers around the Pallas kernels.
+"""Public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels run with ``interpret=True`` — the body
-executes in Python on CPU for correctness; on TPU they compile natively.
-``INTERPRET`` flips automatically from the backend.
+Lowered for a TPU the kernels compile natively; lowered for any other
+platform they run with ``interpret=True`` (the body executes on CPU for
+correctness). The platform the caller's program is lowered for decides
+(``tpu.on_tpu``), not the process's default backend.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -16,31 +19,36 @@ from repro.kernels.ssd_scan import ssd_scan as _ssd_scan
 # fused trust-round chain (flat-pack path) — backend-dispatching wrappers
 from repro.kernels.fused_round import (fused_agg, fused_async_agg,  # noqa: F401
                                        fused_stats, pending_shape)
+from repro.kernels.tpu import on_tpu
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def _native_or_interpreted(kernel, *args, **static):
+    return on_tpu(functools.partial(kernel, **static),
+                  functools.partial(kernel, **static, interpret=True), *args)
 
 
-def trust_weighted_aggregate(updates, weights, *, block_d: int = 2048):
+def trust_weighted_aggregate(updates, weights):
     """(W, D) updates × (W,) weights -> (D,) f32 aggregate."""
-    return _trust_agg(updates, weights, block_d=block_d, interpret=INTERPRET)
+    return _native_or_interpreted(_trust_agg, updates, weights)
 
 
-def trust_stats(updates, *, block_d: int = 2048):
+def trust_stats(updates):
     """(W, D) -> (dot (W,), sq_u (W,), sq_c ()) vs consensus mean."""
-    return _trust_score_stats(updates, block_d=block_d, interpret=INTERPRET)
+    return _native_or_interpreted(_trust_score_stats, updates)
 
 
 def sliding_window_decode(q, k_cache, v_cache, cur_index, *, window: int,
                           block_s: int = 512):
     """Single-token sliding-window decode attention (B,H,hd)."""
-    return _swa_decode(q, k_cache, v_cache, cur_index, window=window,
-                       block_s=block_s, interpret=INTERPRET)
+    return _native_or_interpreted(_swa_decode, q, k_cache, v_cache,
+                                  jnp.asarray(cur_index, jnp.int32),
+                                  window=window, block_s=block_s)
 
 
 def ssd_chunk_scan(q, k, v, a, i, *, chunk: int = 256):
     """Fused SSD/decay-attention recurrence (Mamba2/mLSTM hot loop):
     (B,S,H,dk)×(B,S,H,dv) with per-step log-decay a and input gate i."""
-    return _ssd_scan(q, k, v, a, i, chunk=chunk, interpret=INTERPRET)
+    return _native_or_interpreted(_ssd_scan, q, k, v, a, i, chunk=chunk)
 
 
 def aggregate_pytree(updates, weights):

@@ -18,7 +18,6 @@ import traceback
 
 import jax
 
-from repro.compat.xla import normalize_cost_analysis
 from repro.configs.base import FederationConfig
 from repro.configs.registry import ARCH_IDS, INPUT_SHAPES, applicable
 from repro.launch import mesh as meshlib
@@ -181,8 +180,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
         output_size_in_bytes = alias_size_in_bytes = 0
 
     mem = compiled.memory_analysis() or _NoMem()
-    # list-of-dicts on this jaxlib; normalized so .get works everywhere
-    cost = normalize_cost_analysis(compiled.cost_analysis())
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     coll_total, coll_breakdown = collective_bytes(hlo)
 

@@ -25,6 +25,7 @@ from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
 from repro.core import async_sim
 from repro.core.protocol import SDFLBProtocol
 from repro.data.datasets import make_federated_mnist, synthetic_tokens
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_protocol(args):
@@ -68,6 +69,7 @@ def main() -> None:
     ap.add_argument("--json", default="")
     args = ap.parse_args()
     assert args.workers % args.clusters == 0
+    enable_compile_cache()
 
     proto, cfg, fed, tc = build_protocol(args)
     W = args.workers

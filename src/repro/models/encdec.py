@@ -14,7 +14,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
-from repro.models.sharding import barrier, shard_residual
+from repro.models.sharding import shard_residual
 
 
 def _init_block(key, cfg: ModelConfig, tp, dt, cross: bool):
@@ -76,7 +76,7 @@ def encode(params, cfg: ModelConfig, frames, *, remat: bool = False):
     positions = jnp.arange(x.shape[1])
 
     def body(x, lp):
-        x = barrier(x)
+        x = jax.lax.optimization_barrier(x)
         h = _ln(x, lp["ln1"], cfg.norm_eps)
         x = x + L.apply_gqa(lp["attn"], h, num_heads=cfg.num_heads,
                             num_kv_heads=cfg.num_kv_heads,
@@ -103,7 +103,7 @@ def decode_train(params, cfg: ModelConfig, tokens, enc_states, *,
     dt = jnp.dtype(cfg.dtype)
 
     def body(x, lp):
-        x = barrier(x)
+        x = jax.lax.optimization_barrier(x)
         h = _ln(x, lp["ln1"], cfg.norm_eps)
         a = L.apply_gqa(lp["attn"], h, num_heads=cfg.num_heads,
                         num_kv_heads=cfg.num_kv_heads,
@@ -178,7 +178,7 @@ def encdec_decode_step(params, cfg: ModelConfig, cache, tokens, cur_index):
 
     def body(x, inp):
         lp, self_c, cross_c = inp
-        self_c, cross_c = barrier((self_c, cross_c))
+        self_c, cross_c = jax.lax.optimization_barrier((self_c, cross_c))
         h = _ln(x, lp["ln1"], cfg.norm_eps)
         a, new_self = L.apply_gqa(lp["attn"], h, num_heads=cfg.num_heads,
                                   num_kv_heads=cfg.num_kv_heads,

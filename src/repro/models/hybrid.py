@@ -20,7 +20,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import ssm as S
-from repro.models.sharding import barrier, shard_residual
+from repro.models.sharding import shard_residual
 
 
 def _split_layers(cfg: ModelConfig):
@@ -113,7 +113,7 @@ def hybrid_forward(params, cfg: ModelConfig, tokens, *, remat: bool = False,
         return x + S.apply_mamba2(lp["mamba"], h, cfg.ssm), None
 
     def super_body(x, sl):
-        x = barrier(x)
+        x = jax.lax.optimization_barrier(x)
         states = []
         for j in range(k):
             lp = jax.tree.map(lambda a: a[j], sl)
@@ -181,7 +181,7 @@ def hybrid_decode_step(params, cfg: ModelConfig, cache, tokens, cur_index):
 
     def super_body(x, inp):
         sl, ssm_states, attn_cache = inp
-        ssm_states, attn_cache = barrier(
+        ssm_states, attn_cache = jax.lax.optimization_barrier(
             (ssm_states, attn_cache))
         new_states = []
         for j in range(k):

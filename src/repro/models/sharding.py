@@ -1,4 +1,4 @@
-"""Activation-sharding hook + the differentiable optimization barrier.
+"""Activation-sharding hook for the residual stream.
 
 The launch layer installs a NamedSharding for the residual stream
 (B, S, d) — e.g. P(None, "model", None): Megatron-style sequence sharding
@@ -19,52 +19,6 @@ import contextlib
 import jax
 
 _RESIDUAL_SHARDING = None
-
-
-@jax.custom_jvp
-def barrier(x):
-    """``jax.lax.optimization_barrier`` with a differentiation rule.
-
-    The raw primitive has no JVP on this JAX version, so any barriered scan
-    body fails under ``jax.grad`` with NotImplementedError. The barrier only
-    exists to pin XLA's scheduling of the *values* (e.g. stop hoisting an
-    f32 convert of the whole remat checkpoint stack out of the backward
-    loop), so differentiation is identity: barrier the primal, pass the
-    tangent straight through (keeping the tangent map a plain identity also
-    keeps it trivially transposable for reverse mode). Accepts any pytree,
-    like the primitive.
-    """
-    return jax.lax.optimization_barrier(x)
-
-
-@barrier.defjvp
-def _barrier_jvp(primals, tangents):
-    (x,), (dx,) = primals, tangents
-    return barrier(x), dx
-
-
-def _register_barrier_batching() -> None:
-    """This JAX version is also missing the primitive's *batching* rule, so
-    the FL worker ``vmap`` dies the same way ``grad`` did. The barrier is
-    shape-polymorphic — batching is the trivial vectorized rule (bind the
-    batched operands, keep the batch dims) that later JAX versions ship.
-    Registered only when absent; silently skipped if the internals move."""
-    try:
-        from jax._src.interpreters import batching
-        from jax._src.lax import lax as lax_internal
-        prim = lax_internal.optimization_barrier_p
-    except (ImportError, AttributeError):      # pragma: no cover
-        return
-    if prim in batching.primitive_batchers:    # pragma: no cover
-        return
-
-    def _batch_rule(args, dims):
-        return prim.bind(*args), dims
-
-    batching.primitive_batchers[prim] = _batch_rule
-
-
-_register_barrier_batching()
 
 
 @contextlib.contextmanager

@@ -16,7 +16,7 @@ from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import moe as MOE
 from repro.models.layers import maybe, shard_dim
-from repro.models.sharding import barrier, shard_residual
+from repro.models.sharding import shard_residual
 
 
 def _dtype(cfg: ModelConfig):
@@ -94,7 +94,7 @@ def decoder_forward(params, cfg: ModelConfig, tokens, *, patch_embeds=None,
         x, aux = carry
         # barrier: stops XLA hoisting convert(whole checkpoint stack) out of
         # the backward loop (an f32 copy of all saved residuals)
-        x = barrier(x)
+        x = jax.lax.optimization_barrier(x)
         h = L.rms_norm(x, lp["norm1"], cfg.norm_eps)
         kv = None
         if cfg.attn_type == "mla":
@@ -164,7 +164,7 @@ def decoder_decode_step(params, cfg: ModelConfig, cache, tokens, cur_index):
         lp, layer_cache = inp
         # barrier: keep per-layer cache converts inside the loop (XLA would
         # otherwise hoist an f32 copy of the whole stacked cache out)
-        layer_cache = barrier(layer_cache)
+        layer_cache = jax.lax.optimization_barrier(layer_cache)
         h = L.rms_norm(x, lp["norm1"], cfg.norm_eps)
         if cfg.attn_type == "mla":
             a, new_cache = L.apply_mla(

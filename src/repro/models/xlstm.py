@@ -13,7 +13,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import ssm as S
-from repro.models.sharding import barrier, shard_residual
+from repro.models.sharding import shard_residual
 
 
 def _split_layers(cfg: ModelConfig):
@@ -72,7 +72,7 @@ def xlstm_forward(params, cfg: ModelConfig, tokens, *, remat: bool = False,
     prefill = prefill_cache_len > 0
 
     def super_body(x, sl):
-        x = barrier(x)
+        x = jax.lax.optimization_barrier(x)
         mstates = []
         for j in range(n_m):
             lp = jax.tree.map(lambda a: a[j], sl["m"])
@@ -129,7 +129,7 @@ def xlstm_decode_step(params, cfg: ModelConfig, cache, tokens, cur_index):
 
     def super_body(x, inp):
         sl, mstate, sstate = inp
-        mstate, sstate = barrier((mstate, sstate))
+        mstate, sstate = jax.lax.optimization_barrier((mstate, sstate))
         new_m = []
         for j in range(n_m):
             lp = jax.tree.map(lambda a: a[j], sl["m"])

@@ -12,25 +12,18 @@ _SRC = str(pathlib.Path(__file__).resolve().parent.parent / "src")
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
 
-try:
-    import hypothesis
+import hypothesis
 
-    # "ci": fully deterministic property testing for the gate — fixed
-    # example sequence (derandomize), no wall-clock deadline (shared
-    # runners stall unpredictably), and print the falsifying example
-    # verbosely. Selected via HYPOTHESIS_PROFILE=ci in the workflow; local
-    # runs keep hypothesis defaults unless the env var says otherwise.
-    hypothesis.settings.register_profile(
-        "ci", deadline=None, derandomize=True, print_blob=True)
-    _profile = os.environ.get("HYPOTHESIS_PROFILE")
-    if _profile:
-        hypothesis.settings.load_profile(_profile)
-except ModuleNotFoundError:
-    # hermetic containers may lack hypothesis; install the API-compatible
-    # deterministic fallback so property tests still run (the fallback is
-    # always derandomized — examples derive from the test's name)
-    from repro.compat.hypothesis_fallback import install
-    install()
+# "ci": fully deterministic property testing for the gate — fixed
+# example sequence (derandomize), no wall-clock deadline (shared
+# runners stall unpredictably), and print the falsifying example
+# verbosely. Selected via HYPOTHESIS_PROFILE=ci in the workflow; local
+# runs keep hypothesis defaults unless the env var says otherwise.
+hypothesis.settings.register_profile(
+    "ci", deadline=None, derandomize=True, print_blob=True)
+_profile = os.environ.get("HYPOTHESIS_PROFILE")
+if _profile:
+    hypothesis.settings.load_profile(_profile)
 
 
 @pytest.fixture(scope="session")

@@ -14,7 +14,7 @@ from hypothesis import given, settings, strategies as st
 from repro.configs.base import FederationConfig, TrainConfig
 from repro.configs.registry import get_config
 from repro.core import async_agg, fl_step, hierarchy, trust
-from repro.kernels import fused_round, ops, pack, ref
+from repro.kernels import fused_round, ops, pack, ref, tpu, trust_agg, trust_score
 from repro.models import api
 
 jax.config.update("jax_enable_x64", False)
@@ -398,16 +398,29 @@ def test_init_async_state_for_layouts():
 # ---------------------------------------------------------------------------
 
 def test_block_d_for():
+    """The sync kernels' planned tiles are lane-aligned, fit the scoped-VMEM
+    budget, narrow as W grows, and tile W only where a full-W strip cannot
+    fit even at one lane."""
+    for mod in (trust_score, trust_agg):
+        for itemsize in (2, 4):
+            prev = None
+            for W in (16, 256, 1024, 4096, 10240, 40000):
+                t = mod.tiles(W, 21840, itemsize)
+                assert t.bd % tpu.LANE == 0
+                assert tpu.LANE <= t.bd <= tpu.MAX_BLOCK_D
+                assert mod.vmem_bytes(t.bw, t.bd, itemsize) <= tpu.VMEM_BUDGET
+                assert t.w_pad >= W
+                if t.nw > 1:
+                    full = tpu.round_up(W, tpu.LANE)
+                    assert mod.vmem_bytes(full, tpu.LANE, itemsize) \
+                        > tpu.VMEM_BUDGET
+                elif prev is not None:
+                    assert t.bd <= prev
+                prev = t.bd if t.nw == 1 else prev
+    # the 10k-cohort target keeps one full-W strip (one sweep) for the
+    # statistics kernel, at f32 and bf16
     for itemsize in (2, 4):
-        prev = None
-        for W in (16, 256, 1024, 4096, 10240):
-            bd = fused_round.block_d_for(W, itemsize)
-            assert bd % fused_round.LANE == 0 and 128 <= bd <= 2048
-            if prev is not None:
-                assert bd <= prev
-            prev = bd
-    # the 10k-cohort target keeps a full lane tile in budget at f32
-    assert fused_round.block_d_for(10240, 4) >= fused_round.LANE
+        assert trust_score.tiles(10240, 21840, itemsize).nw == 1
 
 
 def test_pending_shape_alignment():

@@ -10,7 +10,6 @@ import pytest
 
 sys.path.insert(0, ".")   # benchmarks package lives at repo root
 from benchmarks import analytic
-from repro.compat.xla import normalize_cost_analysis
 from repro.configs.registry import ARCH_IDS, INPUT_SHAPES, applicable, get_config
 from repro.models import api
 
@@ -39,8 +38,7 @@ def test_cost_analysis_counts_scan_body_once():
         return jax.lax.scan(body, x, ws)[0]
     ws = jax.ShapeDtypeStruct((10, 128, 128), jnp.float32)
     x = jax.ShapeDtypeStruct((128, 128), jnp.float32)
-    cost = normalize_cost_analysis(
-        jax.jit(f_scan).lower(ws, x).compile().cost_analysis())
+    cost = jax.jit(f_scan).lower(ws, x).compile().cost_analysis()
     flops = cost["flops"]
     assert abs(flops - 2 * 128 ** 3) / (2 * 128 ** 3) < 0.01   # body, once
 
