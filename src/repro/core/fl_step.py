@@ -169,8 +169,11 @@ def make_fl_round(cfg: ModelConfig, fed: FederationConfig, tc: TrainConfig,
                 return clip_grads(g, tc.grad_clip), l
             vm = jax.vmap(worker_grad,
                           in_axes=(0, 0, 0 if rngs is not None else None))
-            grads, l_pre = vm(params_w, batch, rngs_w)
-            new_p, new_opt = opt_update(params_w, wsc(grads), opt_state, tc)
+            with jax.named_scope("worker_grad"):
+                grads, l_pre = vm(params_w, batch, rngs_w)
+            with jax.named_scope("optimizer"):
+                new_p, new_opt = opt_update(params_w, wsc(grads), opt_state,
+                                            tc)
             if fed.w_loss > 0:
                 # contribution quality needs a live loss delta: re-evaluate
                 # the SAME batch (and dropout rng — the mask cancels) at the
@@ -182,14 +185,17 @@ def make_fl_round(cfg: ModelConfig, fed: FederationConfig, tc: TrainConfig,
                     return loss_fn(pwsc(p), step_batch, r)[0]
                 vl = jax.vmap(worker_loss,
                               in_axes=(0, 0, 0 if rngs is not None else None))
-                l_post = vl(new_p, batch, rngs_w)
+                with jax.named_scope("w_loss_eval"):
+                    l_post = vl(new_p, batch, rngs_w)
                 losses = jnp.stack([l_pre, l_post], axis=1)
             else:
                 losses = l_pre[:, None]
         else:
             vm = jax.vmap(worker_train,
                           in_axes=(0, 0, 0, 0 if rngs is not None else None))
-            new_p, new_opt, losses = vm(params_w, opt_state, batch, rngs_w)
+            with jax.named_scope("worker_grad"):   # local steps + optimizer
+                new_p, new_opt, losses = vm(params_w, opt_state, batch,
+                                            rngs_w)
         new_p = wsc(new_p)
 
         metrics = {"mean_loss": jnp.mean(losses[:, -1]),
@@ -203,66 +209,69 @@ def make_fl_round(cfg: ModelConfig, fed: FederationConfig, tc: TrainConfig,
             # aggregate. Every aggregation ``mode`` telescopes to the same
             # Σ w·u, so the fused sum is value-identical to the hierarchy.
             spec = pack.pack_spec(global_params)
-            upd_flat = pack.pack_delta(new_p, global_params, spec)
-            stats = trust.update_stats_flat(upd_flat,
-                                            losses[:, 0], losses[:, -1])
-            scores = trust.scores_from_stats(stats, fed)
-            if fed.async_mode:
-                assert async_state is not None and participation is not None
-                weights = async_agg.effective_weights(
-                    scores, participation, async_state.staleness, fed)
-                keep = 1.0 - participation.astype(jnp.float32)
-                agg_flat, new_pending = ops.fused_async_agg(
-                    upd_flat, async_state.pending, weights, keep)
-                new_staleness = jnp.where(participation > 0, 0,
-                                          async_state.staleness + 1)
-                new_async = async_agg.AsyncState(new_staleness, new_pending)
-                metrics["cohort_size"] = jnp.sum(participation > 0)
-                metrics["mean_staleness"] = jnp.mean(
-                    async_state.staleness.astype(jnp.float32))
-            else:
-                weights = trust.trust_weights(scores, fed,
-                                              participation=participation)
-                agg_flat = ops.fused_agg(upd_flat, weights)
-                new_async = async_state
-            agg = pack.unpack_vector(agg_flat, spec)
+            with jax.named_scope("trust"):
+                upd_flat = pack.pack_delta(new_p, global_params, spec)
+                stats = trust.update_stats_flat(upd_flat,
+                                                losses[:, 0], losses[:, -1])
+                scores = trust.scores_from_stats(stats, fed)
+            with jax.named_scope("aggregate"):
+                if fed.async_mode:
+                    assert async_state is not None \
+                        and participation is not None
+                    weights = async_agg.effective_weights(
+                        scores, participation, async_state.staleness, fed)
+                    keep = 1.0 - participation.astype(jnp.float32)
+                    agg_flat, new_pending = ops.fused_async_agg(
+                        upd_flat, async_state.pending, weights, keep)
+                    new_staleness = jnp.where(participation > 0, 0,
+                                              async_state.staleness + 1)
+                    new_async = async_agg.AsyncState(new_staleness,
+                                                     new_pending)
+                else:
+                    weights = trust.trust_weights(
+                        scores, fed, participation=participation)
+                    agg_flat = ops.fused_agg(upd_flat, weights)
+                    new_async = async_state
+                agg = pack.unpack_vector(agg_flat, spec)
         else:
             # per-leaf reference: deltas are stored in the param dtype (bf16
             # deltas carry full *relative* precision; trust stats and
             # aggregation upcast per-leaf)
-            updates = wsc(jax.tree.map(
-                lambda a, g: (a.astype(jnp.float32)
-                              - g.astype(jnp.float32)[None]).astype(a.dtype),
-                new_p, global_params))
-            stats = trust.update_stats(updates, losses[:, 0], losses[:, -1])
-            scores = trust.scores_from_stats(stats, fed)
+            with jax.named_scope("trust"):
+                updates = wsc(jax.tree.map(
+                    lambda a, g: (a.astype(jnp.float32)
+                                  - g.astype(jnp.float32)[None]
+                                  ).astype(a.dtype),
+                    new_p, global_params))
+                stats = trust.update_stats(updates, losses[:, 0],
+                                           losses[:, -1])
+                scores = trust.scores_from_stats(stats, fed)
 
-            if fed.async_mode:
-                # first-class async round variant: staleness-weighted
-                # buffered aggregation over the arrived cohort
-                # (core.async_agg), with the cohort/staleness telemetry the
-                # event-driven node reports
-                assert async_state is not None and participation is not None
-                agg, new_async, weights = async_agg.async_round(
-                    updates, scores, participation, async_state, fed)
-                metrics["cohort_size"] = jnp.sum(participation > 0)
-                metrics["mean_staleness"] = jnp.mean(
-                    async_state.staleness.astype(jnp.float32))
-            else:
-                weights = trust.trust_weights(scores, fed,
-                                              participation=participation)
-                if fed.mode == "head_gather":
-                    agg = hierarchy.aggregate_head_gather(updates, weights,
-                                                          fed)
-                elif fed.mode == "two_stage":
-                    agg = hierarchy.aggregate(updates, weights, fed)
-                else:   # "allreduce": fused (identical value, one collective)
-                    agg = hierarchy.aggregate_fused(updates, weights)
-                new_async = async_state
+            with jax.named_scope("aggregate"):
+                if fed.async_mode:
+                    # first-class async round variant: staleness-weighted
+                    # buffered aggregation over the arrived cohort
+                    # (core.async_agg)
+                    assert async_state is not None \
+                        and participation is not None
+                    agg, new_async, weights = async_agg.async_round(
+                        updates, scores, participation, async_state, fed)
+                else:
+                    weights = trust.trust_weights(
+                        scores, fed, participation=participation)
+                    if fed.mode == "head_gather":
+                        agg = hierarchy.aggregate_head_gather(
+                            updates, weights, fed)
+                    elif fed.mode == "two_stage":
+                        agg = hierarchy.aggregate(updates, weights, fed)
+                    else:   # "allreduce": one collective, same value
+                        agg = hierarchy.aggregate_fused(updates, weights)
+                    new_async = async_state
 
-        new_global = jax.tree.map(
-            lambda g, a: (g.astype(jnp.float32) + a).astype(g.dtype),
-            global_params, agg)
+        with jax.named_scope("aggregate"):
+            new_global = jax.tree.map(
+                lambda g, a: (g.astype(jnp.float32) + a).astype(g.dtype),
+                global_params, agg)
         out = RoundOutput(new_global, new_opt, scores, weights,
                           losses[:, -1], metrics)
         if fed.async_mode:

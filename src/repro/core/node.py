@@ -76,7 +76,7 @@ import queue
 import threading
 import time
 import weakref
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -91,6 +91,7 @@ from repro.core import async_agg, fl_step
 from repro.core.async_sim import AsyncScheduler, WorkerProfile
 from repro.core.gossip import ClusterExchange
 from repro.core.reputation import ReputationBook
+from repro.core.spans import span, trace_gc
 from repro.models import api
 
 
@@ -120,10 +121,10 @@ class RoundRecord:
                                    # the round is settled (pipelined driver)
     heads: List[int]
     model_cid: str                 # "" until settled
-    wall_time: float
     chain_time: float              # chain work charged to the training
                                    # thread during this tick (threaded
-                                   # settler: the queue handoff only)
+                                   # settler: the queue handoff only;
+                                   # spans["sdflb.handoff"])
     participation: Optional[np.ndarray] = None
     staleness: Optional[np.ndarray] = None  # (W,) pre-round staleness of each
                                    # worker's update (event-driven rounds;
@@ -138,7 +139,11 @@ class RoundRecord:
     settled: bool = False
     settle_time: float = 0.0       # host chain work on the settler thread
                                    # (contract + Merkle + IPFS); set when
-                                   # the round settles
+                                   # the round settles (spans["sdflb.settle"])
+    spans: Dict[str, float] = field(default_factory=dict)  # host seconds
+                                   # of each phase of this round, by span
+                                   # name (repro.core.spans); the settler's
+                                   # are written when the round settles
 
 
 @dataclass
@@ -147,6 +152,8 @@ class _PendingRound:
     params: Any                    # round's resulting global params (device);
                                    # None when running without a chain
     scores: np.ndarray
+    finished: float = 0.0          # perf_counter at the end of the round's
+                                   # finish on the driving thread
 
 
 @dataclass
@@ -163,9 +170,9 @@ class _StartedRound:
     host has not yet rotated heads or synced scores."""
     round_index: int
     out: Any
-    t0: float
     participation: Optional[np.ndarray]
     staleness: Optional[np.ndarray] = None   # pre-round host staleness mirror
+    spans: Dict[str, float] = field(default_factory=dict)
 
 
 class ShardWorkerPool:
@@ -810,9 +817,10 @@ class FederatedTask:
         """Dispatch this round's jitted step — async, no barrier. batch
         leaves: (W, B, ...) — a single local step per round (paper's
         setup); reshaped to (W, 1, B, ...) for the step function."""
-        t0 = time.monotonic()
         ridx = len(self.history)
-        batch = {k: jnp.asarray(v)[:, None] for k, v in batch.items()}
+        timings: Dict[str, float] = {}
+        with span("sdflb.batch_h2d", timings):
+            batch = {k: jnp.asarray(v)[:, None] for k, v in batch.items()}
         if self.adversary is not None:
             batch = self.adversary(batch, ridx)
         self.rng, rkey = jax.random.split(self.rng)
@@ -835,9 +843,9 @@ class FederatedTask:
                                  rkey, part)
         self.global_params, self.opt_state = out.global_params, out.opt_state
         out.scores.copy_to_host_async()   # start device→host score copy
-        return _StartedRound(ridx, out, t0, participation, stale)
+        return _StartedRound(ridx, out, participation, stale, timings)
 
-    def _finish_round(self, st: _StartedRound, chain_time: float
+    def _finish_round(self, st: _StartedRound
                       ) -> Tuple[RoundRecord, _PendingRound]:
         """Rotate heads for this round and sync its scores. On-chain
         randomness needs the block that settled this task's round r−1 (and
@@ -848,29 +856,29 @@ class FederatedTask:
         into the queue."""
         head_hash = None
         if self.use_blockchain or self.reputation_leaders:
-            head_hash = self.node._settler.wait_task(self.task_id,
-                                                     st.round_index - 1)
+            with span("sdflb.head_wait", st.spans):
+                head_hash = self.node._settler.wait_task(
+                    self.task_id, st.round_index - 1)
         heads = self._rotate_heads(st.round_index, head_hash)
         # the only training-path sync point: this round's scores
-        scores = np.asarray(st.out.scores)
-        # the tick's settlement handoff ran between dispatch and here —
-        # charge it to chain_time, not the training time
-        train_time = time.monotonic() - st.t0 - chain_time
+        with span("sdflb.score_sync", st.spans):
+            scores = np.asarray(st.out.scores)
+            weights = np.asarray(st.out.weights)
+            losses = np.asarray(st.out.losses)
         rec = RoundRecord(
-            round_index=st.round_index, scores=scores,
-            weights=np.asarray(st.out.weights),
-            losses=np.asarray(st.out.losses),
-            penalties=np.zeros(self.W, np.float64), heads=heads,
-            model_cid="", wall_time=train_time + chain_time,
-            chain_time=chain_time,
+            round_index=st.round_index, scores=scores, weights=weights,
+            losses=losses, penalties=np.zeros(self.W, np.float64),
+            heads=heads, model_cid="",
+            chain_time=st.spans["sdflb.handoff"],
             participation=None if st.participation is None
             else np.asarray(st.participation),
-            staleness=st.staleness)
+            staleness=st.staleness, spans=st.spans)
         # chainless settlement only reads scores — don't pin up to
         # pipeline_depth extra param trees in the queue for nothing
         pending = _PendingRound(
             rec, self.global_params if self.use_blockchain else None, scores)
         self.history.append(rec)
+        pending.finished = time.perf_counter()
         return rec, pending
 
     # -- settle-side hooks (run on the scheduler thread) ----------------------
@@ -880,7 +888,8 @@ class FederatedTask:
         (paper §III.A): one put of the (identical) global tree; every
         cluster head registers the cid for the hash exchange."""
         ridx = p.record.round_index
-        cid = self.node.ipfs.put_tree(p.params, owner=self.task_id)
+        with span("sdflb.ipfs_put", p.record.spans):
+            cid = self.node.ipfs.put_tree(p.params, owner=self.task_id)
         for c in range(self.fed.num_clusters):
             self.exchange.register(ridx, c, cid)
         self.contract.pending.extend(self.exchange.round_transactions(ridx))
@@ -888,9 +897,9 @@ class FederatedTask:
 
     def _post_settle(self, p: _PendingRound,
                      penalties: Optional[np.ndarray], model_cid: str,
-                     t0: float) -> None:
+                     settle: span) -> None:
         """Reputation update + record bookkeeping once the round's block
-        (if any) is sealed."""
+        (if any) is sealed; ends the round's ``sdflb.settle`` span."""
         if self.use_blockchain:
             p.record.model_cid = model_cid
             bad = p.scores < self.contract.T
@@ -906,7 +915,8 @@ class FederatedTask:
         else:
             bad = np.zeros(self.W, bool)
         self.reputation.update(p.scores, penalized=bad)
-        p.record.settle_time = time.monotonic() - t0
+        settle.close()
+        p.record.settle_time = p.record.spans["sdflb.settle"]
         p.record.settled = True
 
     # -- evaluation ------------------------------------------------------------
@@ -997,6 +1007,7 @@ class ChainNode:
         # sealed block + its commit, on the settler thread
         self._seal_listeners: List[Callable] = []
         self._closed = False
+        trace_gc()
 
     # -- task registry --------------------------------------------------------
 
@@ -1123,9 +1134,11 @@ class ChainNode:
         # 2. hand the previous tick's rounds to the settler (threaded: a
         #    queue put; depth 0: settle inline) — either way it overlaps
         #    this tick's device compute
-        tc0 = time.monotonic()
-        self._hand_off_pending()
-        chain_time = time.monotonic() - tc0
+        handoff: Dict[str, float] = {}
+        with span("sdflb.handoff", handoff):
+            self._hand_off_pending()
+        for st in started.values():
+            st.spans.update(handoff)
         # 3. per task: rotate heads (blocking only on the settled head of
         #    its *own* previous round) and sync scores. A task poisoned
         #    mid-tick raises out of its wait — finish every OTHER task
@@ -1137,8 +1150,7 @@ class ChainNode:
         failures: List[BaseException] = []
         for tid in tids:
             try:
-                rec, pending = self.tasks[tid]._finish_round(started[tid],
-                                                             chain_time)
+                rec, pending = self.tasks[tid]._finish_round(started[tid])
             except BaseException as e:
                 failures.append(e)
                 continue
@@ -1227,7 +1239,7 @@ class ChainNode:
         at logical (tick-indexed) time. Returns per-task outcomes
         ``(task_id, round_index, head, error)``; raising is node-fatal."""
         outcomes: list = []
-        live: List[Tuple[FederatedTask, _PendingRound, float]] = []
+        live: List[Tuple[FederatedTask, _PendingRound, span]] = []
         work: List[TaskRoundWork] = []
         for tid, p in tp.entries:
             ridx = p.record.round_index
@@ -1237,17 +1249,19 @@ class ChainNode:
                 outcomes.append((tid, ridx, None, None))
                 continue
             task = self.tasks[tid]
-            t0 = time.monotonic()
+            settle = span("sdflb.settle", p.record.spans).__enter__()
+            p.record.spans["sdflb.settle_queue"] = settle.t0 - p.finished
             if not self.use_blockchain:
-                task._post_settle(p, None, "", t0)
+                task._post_settle(p, None, "", settle)
                 outcomes.append((tid, ridx, None, None))
                 continue
             try:
                 cid = task._pre_settle(p)
             except BaseException as e:
+                settle.close()
                 outcomes.append((tid, ridx, None, e))
                 continue
-            live.append((task, p, t0))
+            live.append((task, p, settle))
             scores, wids = p.scores, None
             stale = p.record.staleness
             if task.contract.sparse_settlement \
@@ -1270,12 +1284,13 @@ class ChainNode:
                 pool=self._shard_pool)
             for listener in self._seal_listeners:
                 listener(blk, self.ledger._commits.get(blk.index))
-            for (task, p, t0), w in zip(live, work):
+            for (task, p, settle), w in zip(live, work):
                 if w.task_id in errors:
+                    settle.close()
                     outcomes.append((w.task_id, w.round_index, None,
                                      errors[w.task_id]))
                 else:
-                    task._post_settle(p, pens[w.task_id], w.model_cid, t0)
+                    task._post_settle(p, pens[w.task_id], w.model_cid, settle)
                     outcomes.append((w.task_id, w.round_index, blk.hash,
                                      None))
         return outcomes

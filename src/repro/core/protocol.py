@@ -29,7 +29,9 @@ jitted step, hands round r−1's host chain work to the node's settler
 reference driver), and blocks only where round r's on-chain randomness
 consumes round r−1's block head. Settled state (ledger blocks, contract
 balances, reputation, per-round ``penalties``/``model_cid``/
-``settle_time``) is written by the settler thread; read it after
+``settle_time`` and the settler's entries of ``spans``:
+``sdflb.settle``, ``sdflb.ipfs_put``, ``sdflb.settle_queue``) is written
+by the settler thread; read it after
 ``flush()`` (idempotent, safe mid-queue), or rely on rounds ≤ r−1 being
 settled once ``run_round(r)`` returns whenever head rotation consumes
 chain heads. Settler exceptions re-raise on the training thread at the

@@ -146,6 +146,7 @@ def fused_async_agg_kernel(updates, pending, weights, keep, *,
             jax.ShapeDtypeStruct((Wp, Dp), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_async_agg_kernel",
     )(w_row, keep_col, upd, pending)
     return agg[0, :D], newp
 

@@ -82,5 +82,6 @@ def trust_agg(updates: jax.Array, weights: jax.Array, *,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, D), jnp.float32),
         interpret=interpret,
+        name="trust_agg",
     )(w_row, updates)
     return out[0]

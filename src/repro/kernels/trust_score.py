@@ -146,6 +146,7 @@ def trust_score_stats(updates: jax.Array, *, block_w: int | None = None,
         ],
         scratch_shapes=[pltpu.VMEM((1, t.bd), jnp.float32)],
         interpret=interpret,
+        name="trust_score_stats",
     )(updates)
     return (jnp.sum(dot[:, 0, :W], axis=0), jnp.sum(squ[:, 0, :W], axis=0),
             jnp.sum(con * con))
