@@ -32,11 +32,49 @@ def init_cnn(key, cfg: ModelConfig, tp: int = 1):
     return params, specs
 
 
-def _conv(x, w, b):
-    out = jax.lax.conv_general_dilated(
+def _conv_plain(x, w):
+    return jax.lax.conv_general_dilated(
         x, w, window_strides=(1, 1), padding="VALID",
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    return out + b
+
+
+@jax.custom_vjp
+def _conv_valid(x, w):
+    return _conv_plain(x, w)
+
+
+def _conv_valid_fwd(x, w):
+    return _conv_plain(x, w), (x, w)
+
+
+def _conv_valid_bwd(res, g):
+    """Input cotangent from the convolution's own transpose; weight
+    cotangent as one contraction of the input's kh*kw shifted windows
+    against ``g`` over every (image, output position). Under a vmap over
+    per-worker kernels that contraction is a dot_general batched over the
+    workers, where autodiff's transpose would be a convolution grouped by
+    worker (one group per worker, each with a handful of channels)."""
+    x, w = res
+    (dx,) = jax.linear_transpose(lambda x_: _conv_plain(x_, w), x)(g)
+    kh, kw, cin, cout = w.shape
+    n, ho, wo, _ = g.shape
+    with jax.named_scope("conv_wgrad"):
+        # channels first, so the contraction over n*ho*wo is the minor
+        # dimension of both operands rather than the few channels
+        xt = jnp.transpose(x, (3, 0, 1, 2))
+        patches = jnp.stack([xt[:, :, i:i + ho, j:j + wo]
+                             for i in range(kh) for j in range(kw)])
+        patches = patches.reshape(kh * kw * cin, n * ho * wo)
+        gt = jnp.transpose(g, (3, 0, 1, 2)).reshape(cout, n * ho * wo)
+        dw = jax.lax.dot_general(patches, gt, (((1,), (1,)), ((), ())))
+    return dx, dw.reshape(w.shape)
+
+
+_conv_valid.defvjp(_conv_valid_fwd, _conv_valid_bwd)
+
+
+def _conv(x, w, b):
+    return _conv_valid(x, w) + b
 
 
 def _maxpool2(x):
