@@ -24,10 +24,26 @@ class MoEConfig:
     router_z_loss: float = 0.001
     capacity_factor: float = 1.25   # GShard-style capacity (tokens dropped
                                     # beyond C = ceil(k·T/E·cf))
+    # DeepSeek-V3 routing ("sigmoid"): experts chosen on sigmoid scores
+    # plus a per-expert selection bias, weighted by the chosen scores
+    # normalised to sum to one and scaled by ``routed_scaling``;
+    # "softmax" is the top-k of the router's softmax
+    scoring: str = "softmax"
+    routed_scaling: float = 1.0
+    shared_gate: bool = True        # Qwen's sigmoid gate on the shared experts
+    # one chip's share of an expert-parallel layer: the router scores all
+    # ``num_experts``, and only experts lo .. lo+n-1 are held and computed,
+    # for every token routed to them (no capacity drop); (0, 0) holds all
+    # experts on the capacity path above
+    experts_held: Tuple[int, int] = (0, 0)
 
     @property
     def enabled(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def held(self) -> bool:
+        return self.experts_held[1] > 0
 
 
 @dataclass(frozen=True)
@@ -47,7 +63,7 @@ class SSMConfig:
 @dataclass(frozen=True)
 class MLAConfig:
     """Multi-head Latent Attention (DeepSeek/MiniCPM3-style) configuration."""
-    q_lora_rank: int = 0
+    q_lora_rank: int = 0            # 0: no query bottleneck, one wq
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
@@ -56,6 +72,28 @@ class MLAConfig:
     @property
     def enabled(self) -> bool:
         return self.kv_lora_rank > 0
+
+
+@dataclass(frozen=True)
+class LoRAConfig:
+    """Adapter-only fine-tuning: rank-``rank`` adapters (PEFT's LoRA,
+    output = x·W + (x·A)·B · alpha/rank) on the named projections are the
+    only trained leaves; the rest of the model is a frozen base drawn from
+    ``base_seed`` (``models.lora.init_frozen``), held once by the task and
+    shared by every worker."""
+    rank: int = 0
+    alpha: float = 0.0
+    targets: Tuple[str, ...] = ()   # attention projections, by their
+                                    # Hugging Face names (q_proj, ...)
+    base_seed: int = 0
+
+    @property
+    def enabled(self) -> bool:
+        return self.rank > 0
+
+    @property
+    def scale(self) -> float:
+        return self.alpha / self.rank
 
 
 @dataclass(frozen=True)
@@ -89,6 +127,10 @@ class ModelConfig:
     moe: MoEConfig = field(default_factory=MoEConfig)
     ssm: SSMConfig = field(default_factory=SSMConfig)
     mla: MLAConfig = field(default_factory=MLAConfig)
+    lora: LoRAConfig = field(default_factory=LoRAConfig)
+    # leading layers with a dense MLP of width ``d_ff`` before the MoE
+    # layers (DeepSeek-V3's first_k_dense_replace)
+    first_dense_layers: int = 0
     # --- hybrid (zamba2): shared attention block every k-th layer ---
     shared_attn_every: int = 0              # 0 => no shared block
     # --- xLSTM: put an sLSTM block every k-th layer (rest mLSTM) ---
@@ -105,6 +147,15 @@ class ModelConfig:
     # --- numerics / citation ---
     dtype: str = "bfloat16"
     source: str = ""                        # citation bracket from the assignment
+
+    def __post_init__(self):
+        # a held expert share's grouped products give the expert weights
+        # no gradient (``moe.grouped_matmul``): only adapters over a frozen
+        # base may train through it
+        if self.moe.held and not self.lora.enabled:
+            raise ValueError(
+                f"{self.name}: moe.experts_held {self.moe.experts_held} "
+                "needs lora (held experts are frozen)")
 
     @property
     def resolved_head_dim(self) -> int:

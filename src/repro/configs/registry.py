@@ -17,9 +17,15 @@ _ARCH_MODULES = {
     "minicpm3-4b":      "repro.configs.minicpm3_4b",
     "h2o-danube-1.8b":  "repro.configs.h2o_danube_1_8b",
     "paper-net":        "repro.configs.paper_net",
+    "moonlight-16b-a3b": "repro.configs.moonlight_16b_a3b",
 }
 
-ARCH_IDS = [a for a in _ARCH_MODULES if a != "paper-net"]
+# the zoo the launchers, dry run and per-architecture tests sweep: models
+# trained whole and served. paper-net (the paper's CNN) and
+# moonlight-16b-a3b (LoRA federation over a frozen base, held expert
+# shares; tests/test_moonlight.py) run through the federated round only.
+ARCH_IDS = [a for a in _ARCH_MODULES
+            if a not in ("paper-net", "moonlight-16b-a3b")]
 
 
 def get_config(arch: str) -> ModelConfig:

@@ -28,6 +28,13 @@ reassembled exactly once for the global update. Both paths share the
 score/weight math in ``core.trust``/``core.async_agg`` and are
 property-tested equivalent (``tests/test_fused_round.py``).
 
+A model with a frozen part (``ModelConfig.lora``) federates only its
+trained leaves: ``global_params``, the optimizer state and the updates are
+the adapters, and the frozen base enters the round once as ``frozen``,
+shared by every worker (closed over by the vmapped step, so not
+broadcast). A model without one passes no ``frozen`` and compiles to the
+program it always did.
+
 Host-level protocol work (contract settlement, ledger blocks, IPFS
 publication, head rotation bookkeeping) happens *between* jitted rounds in
 ``core.protocol``.
@@ -53,6 +60,8 @@ class RoundOutput(NamedTuple):
     weights: jax.Array         # (W,) effective aggregation weights
     losses: jax.Array          # (W,) final local loss per worker
     metrics: dict
+    counters: dict = {}        # the model's device counters over the
+                               # cohort's steps (api.reduce_counters)
 
 
 def num_workers(fed: FederationConfig, *, pods: int = 1) -> int:
@@ -123,14 +132,14 @@ def make_fl_round(cfg: ModelConfig, fed: FederationConfig, tc: TrainConfig,
     constrained = (worker_constraint is not None
                    or param_constraint is not None)
 
-    def worker_train(params, opt, batch, rng):
+    def worker_train(params, opt, batch, rng, frozen):
         """One worker: ``local_steps`` SGD steps on its own data."""
 
         def one_step(carry, step_batch):
             p, o, r = carry
             r, sub = (jax.random.split(r) if r is not None else (None, None))
             (l, m), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                p, step_batch, sub)
+                p, step_batch, sub, frozen)
             grads = clip_grads(grads, tc.grad_clip)
             p, o = opt_update(p, grads, o, tc)
             return (p, o, r), l
@@ -144,9 +153,10 @@ def make_fl_round(cfg: ModelConfig, fed: FederationConfig, tc: TrainConfig,
         return p, o, losses
 
     def fl_round(global_params, opt_state, batch, rngs=None,
-                 participation=None, async_state=None):
+                 participation=None, async_state=None, frozen=None):
         """batch leaves: (W, local_steps, per_worker_batch, ...).
-        participation: optional (W,) 0/1; async_state: async_agg.AsyncState.
+        participation: optional (W,) 0/1; async_state: async_agg.AsyncState;
+        frozen: the model's frozen base (``api.init_frozen``), or None.
         """
         W = jax.tree.leaves(batch)[0].shape[0]
         # trace-time path selection: dtypes/structure only, no data
@@ -163,14 +173,16 @@ def make_fl_round(cfg: ModelConfig, fed: FederationConfig, tc: TrainConfig,
                 step_batch = jax.tree.map(lambda x: x[0], b)
 
                 def loss_c(p_, b_, r_):
-                    return loss_fn(pwsc(p_), b_, r_)
+                    return loss_fn(pwsc(p_), b_, r_, frozen)
                 (l, m), g = jax.value_and_grad(loss_c, has_aux=True)(
                     p, step_batch, r)
-                return clip_grads(g, tc.grad_clip), l
+                return clip_grads(g, tc.grad_clip), l, m.get("counters", {})
             vm = jax.vmap(worker_grad,
                           in_axes=(0, 0, 0 if rngs is not None else None))
             with jax.named_scope("worker_grad"):
-                grads, l_pre = vm(params_w, batch, rngs_w)
+                grads, l_pre, counters = vm(params_w, batch, rngs_w)
+                if counters:
+                    counters = api.reduce_counters(counters)
             with jax.named_scope("optimizer"):
                 new_p, new_opt = opt_update(params_w, wsc(grads), opt_state,
                                             tc)
@@ -182,7 +194,7 @@ def make_fl_round(cfg: ModelConfig, fed: FederationConfig, tc: TrainConfig,
                 # loss-improvement term would silently contribute nothing.
                 def worker_loss(p, b, r):
                     step_batch = jax.tree.map(lambda x: x[0], b)
-                    return loss_fn(pwsc(p), step_batch, r)[0]
+                    return loss_fn(pwsc(p), step_batch, r, frozen)[0]
                 vl = jax.vmap(worker_loss,
                               in_axes=(0, 0, 0 if rngs is not None else None))
                 with jax.named_scope("w_loss_eval"):
@@ -191,11 +203,12 @@ def make_fl_round(cfg: ModelConfig, fed: FederationConfig, tc: TrainConfig,
             else:
                 losses = l_pre[:, None]
         else:
-            vm = jax.vmap(worker_train,
-                          in_axes=(0, 0, 0, 0 if rngs is not None else None))
+            vm = jax.vmap(worker_train, in_axes=(
+                0, 0, 0, 0 if rngs is not None else None, None))
             with jax.named_scope("worker_grad"):   # local steps + optimizer
                 new_p, new_opt, losses = vm(params_w, opt_state, batch,
-                                            rngs_w)
+                                            rngs_w, frozen)
+            counters = {}
         new_p = wsc(new_p)
 
         metrics = {"mean_loss": jnp.mean(losses[:, -1]),
@@ -273,7 +286,7 @@ def make_fl_round(cfg: ModelConfig, fed: FederationConfig, tc: TrainConfig,
                 lambda g, a: (g.astype(jnp.float32) + a).astype(g.dtype),
                 global_params, agg)
         out = RoundOutput(new_global, new_opt, scores, weights,
-                          losses[:, -1], metrics)
+                          losses[:, -1], metrics, counters)
         if fed.async_mode:
             return out, new_async
         return out

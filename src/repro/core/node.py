@@ -144,6 +144,10 @@ class RoundRecord:
                                    # of each phase of this round, by span
                                    # name (repro.core.spans); the settler's
                                    # are written when the round settles
+    counters: Dict[str, float] = field(default_factory=dict)  # the model's
+                                   # device counters over the round's steps
+                                   # (e.g. moe.pairs_held), fetched with
+                                   # its scores
 
 
 @dataclass
@@ -697,13 +701,22 @@ class FederatedTask:
         key, self.rng = jax.random.split(self.rng)
         self.global_params, _ = api.init(cfg, key, tp=1)
         self.opt_state = fl_step.init_worker_opt(self.global_params, fed, tc)
+        # the model's frozen part (LoRA's base), built once on the device
+        # and shared by every worker of every round; never trained,
+        # scored or published
+        self.frozen = None
+        if cfg.lora.enabled:
+            with span("sdflb.base_init"):
+                self.frozen = jax.block_until_ready(
+                    jax.jit(lambda: api.init_frozen(cfg))())
         self._round_fn = jax.jit(fl_step.make_fl_round(cfg, fed, tc))
         # eval fns jitted once here (re-wrapping jax.jit per call would
         # recompile on every invocation)
         loss_fn = api.loss_fn(cfg)
         self._eval_fn = jax.jit(loss_fn)
-        self._eval_per_worker_fn = jax.jit(
-            jax.vmap(lambda p, b: loss_fn(p, b)[1], in_axes=(None, 0)))
+        self._eval_per_worker_fn = jax.jit(jax.vmap(
+            lambda p, b, fz: loss_fn(p, b, None, fz)[1],
+            in_axes=(None, 0, None)))
 
         self.async_state = None
         self.scheduler = None
@@ -809,7 +822,10 @@ class FederatedTask:
         args = (self.global_params, self.opt_state, batch, self.rng, part)
         if self.fed.async_mode:
             args += (self.async_state,)
-        return self._round_fn.lower(*args)
+        return self._round_fn.lower(*args, **self._frozen_kw())
+
+    def _frozen_kw(self) -> dict:
+        return {} if self.frozen is None else {"frozen": self.frozen}
 
     def _dispatch_round(self, batch: Dict[str, np.ndarray],
                         participation: Optional[np.ndarray]
@@ -837,12 +853,14 @@ class FederatedTask:
                     self.staleness, participation)
             out, self.async_state = self._round_fn(
                 self.global_params, self.opt_state, batch, rkey,
-                part, self.async_state)
+                part, self.async_state, **self._frozen_kw())
         else:
             out = self._round_fn(self.global_params, self.opt_state, batch,
-                                 rkey, part)
+                                 rkey, part, **self._frozen_kw())
         self.global_params, self.opt_state = out.global_params, out.opt_state
-        out.scores.copy_to_host_async()   # start device→host score copy
+        # start the device→host copies of the scores and counters
+        for x in (out.scores, *out.counters.values()):
+            x.copy_to_host_async()
         return _StartedRound(ridx, out, participation, stale, timings)
 
     def _finish_round(self, st: _StartedRound
@@ -865,6 +883,7 @@ class FederatedTask:
             scores = np.asarray(st.out.scores)
             weights = np.asarray(st.out.weights)
             losses = np.asarray(st.out.losses)
+            counters = {k: float(v) for k, v in st.out.counters.items()}
         rec = RoundRecord(
             round_index=st.round_index, scores=scores, weights=weights,
             losses=losses, penalties=np.zeros(self.W, np.float64),
@@ -872,7 +891,7 @@ class FederatedTask:
             chain_time=st.spans["sdflb.handoff"],
             participation=None if st.participation is None
             else np.asarray(st.participation),
-            staleness=st.staleness, spans=st.spans)
+            staleness=st.staleness, spans=st.spans, counters=counters)
         # chainless settlement only reads scores — don't pin up to
         # pipeline_depth extra param trees in the queue for nothing
         pending = _PendingRound(
@@ -923,7 +942,8 @@ class FederatedTask:
 
     def evaluate(self, eval_batch: Dict[str, np.ndarray]) -> Dict[str, float]:
         batch = {k: jnp.asarray(v) for k, v in eval_batch.items()}
-        loss, metrics = self._eval_fn(self.global_params, batch)
+        loss, metrics = self._eval_fn(self.global_params, batch, None,
+                                      self.frozen)
         return {k: float(v) for k, v in metrics.items()}
 
     def evaluate_per_worker(self, batch_w: Dict[str, np.ndarray]):
@@ -931,7 +951,7 @@ class FederatedTask:
         local shard (the per-worker curves of Figs. 5/6)."""
         metrics = self._eval_per_worker_fn(
             self.global_params,
-            {k: jnp.asarray(v) for k, v in batch_w.items()})
+            {k: jnp.asarray(v) for k, v in batch_w.items()}, self.frozen)
         return {k: np.asarray(v) for k, v in metrics.items()}
 
     def finalize(self, timestamp: Optional[float] = None

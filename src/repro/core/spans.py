@@ -10,6 +10,8 @@ spans are always on. ``RoundRecord.spans`` holds a round's durations;
 
 Names, in order of a round's life:
 
+- ``sdflb.base_init``: building a model's frozen base on the device, once,
+  when its task is created (set-up, before any round)
 - ``sdflb.batch_h2d``: the batch leaves' hand-over to the device (driving
   thread; the copy itself completes asynchronously, after the span)
 - ``sdflb.handoff``: the tick's queue hand-off to the settler (driving)
@@ -20,7 +22,11 @@ Names, in order of a round's life:
 - ``sdflb.gc``: a garbage collection, on whichever thread triggered it
 
 ``sdflb.settle_queue`` is a counter, not a span: the seconds a finished
-round waited before the settler started on it.
+round waited before the settler started on it. The model's own device
+counters (``moe.pairs_held``, ``moe.max_expert_load``) are in
+``RoundRecord.counters``; named scopes inside the jitted round (``mla``,
+``moe_route``, ``moe_experts``, ``shared_experts``, ``lora``) mark its
+operations' metadata.
 """
 from __future__ import annotations
 

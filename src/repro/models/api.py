@@ -2,7 +2,8 @@
 consume. Dispatches on ``cfg.family``.
 
     init(cfg, key, tp)                  -> (params, param_specs)
-    loss_fn(cfg)(params, batch, rng)    -> (loss, metrics)      # train step unit
+    init_frozen(cfg)                    -> frozen base or None
+    loss_fn(cfg)(params, batch, rng, frozen) -> (loss, metrics) # train step unit
     forward(params, cfg, batch)         -> (logits, aux)        # prefill/full fwd
     cache_shape(cfg, batch, seq)        -> pytree of shapes
     cache_spec(cfg, tp, data_axes)      -> pytree of PartitionSpec
@@ -13,6 +14,10 @@ Batches are dicts:
     vlm                  : + {patch_embeds (B,P,d)}      (stub VQ frontend)
     audio                : + {frames (B,enc_seq,d)}      (stub conv frontend)
     cnn                  : {images (B,28,28,1), labels (B,)}
+
+With ``cfg.lora`` the trained params are the adapters only (``models.lora``)
+and ``frozen`` is the base they adapt, built once by ``init_frozen``; every
+other configuration trains every leaf and has no frozen part.
 """
 from __future__ import annotations
 
@@ -24,11 +29,15 @@ from repro.configs.base import ModelConfig
 from repro.models import cnn as CNN
 from repro.models import encdec as ED
 from repro.models import hybrid as HY
+from repro.models import lora as LORA
+from repro.models import moe as MOE
 from repro.models import transformer as TF
 from repro.models import xlstm as XL
 
 
 def init(cfg: ModelConfig, key, tp: int = 1):
+    if cfg.lora.enabled:
+        return LORA.init_adapters(key, cfg)
     if cfg.family in ("dense", "moe", "vlm"):
         return TF.init_decoder(key, cfg, tp)
     if cfg.family == "hybrid":
@@ -40,6 +49,15 @@ def init(cfg: ModelConfig, key, tp: int = 1):
     if cfg.family == "cnn":
         return CNN.init_cnn(key, cfg, tp)
     raise ValueError(cfg.family)
+
+
+def init_frozen(cfg: ModelConfig):
+    """The frozen base the trained params adapt, or None where every leaf
+    is trained."""
+    return LORA.init_frozen(cfg) if cfg.lora.enabled else None
+
+
+reduce_counters = MOE.reduce_counters   # counters over a leading axis
 
 
 def forward(params, cfg: ModelConfig, batch, *, remat: bool = False,
@@ -149,9 +167,12 @@ def _shifted_targets(labels, total_len: int, offset: int):
 
 
 def loss_fn(cfg: ModelConfig, *, remat: bool = False, kv_chunk: int = 1024):
-    """Returns f(params, batch, rng) -> (loss, metrics)."""
+    """Returns f(params, batch, rng, frozen) -> (loss, metrics). ``frozen``
+    is ``init_frozen``'s base (None where there is none). Held expert
+    layers add ``metrics["counters"]`` (``reduce_counters`` sums them over
+    workers)."""
     if cfg.family == "cnn":
-        def f_cnn(params, batch, rng=None):
+        def f_cnn(params, batch, rng=None, frozen=None):
             logits = CNN.cnn_forward(params, cfg, batch["images"], rng=rng,
                                      train=rng is not None)
             labels = batch["labels"]
@@ -160,17 +181,24 @@ def loss_fn(cfg: ModelConfig, *, remat: bool = False, kv_chunk: int = 1024):
             return loss, {"loss": loss, "accuracy": acc}
         return f_cnn
 
-    def f(params, batch, rng=None):
+    def f(params, batch, rng=None, frozen=None):
         kw = dict(remat=remat, kv_chunk=kv_chunk, return_hidden=True)
+        counters = {}
         if cfg.family in ("dense", "moe"):
-            x, aux = TF.decoder_forward(params, cfg, batch["tokens"], **kw)
+            if frozen is not None:
+                params, lora = frozen, params
+            else:
+                lora = None
+            x, aux, counters = TF.decoder_hidden(
+                params, cfg, batch["tokens"], remat=remat, kv_chunk=kv_chunk,
+                lora=lora)
             head = (params["embed"].T if cfg.tie_embeddings
                     else params["lm_head"])
             offset = 0
         elif cfg.family == "vlm":
-            x, aux = TF.decoder_forward(params, cfg, batch["tokens"],
-                                        patch_embeds=batch["patch_embeds"],
-                                        **kw)
+            x, aux, _ = TF.decoder_hidden(params, cfg, batch["tokens"],
+                                          patch_embeds=batch["patch_embeds"],
+                                          remat=remat, kv_chunk=kv_chunk)
             head = params["lm_head"]
             offset = batch["patch_embeds"].shape[1]
         elif cfg.family == "hybrid":
@@ -187,7 +215,10 @@ def loss_fn(cfg: ModelConfig, *, remat: bool = False, kv_chunk: int = 1024):
             raise ValueError(cfg.family)
         targets = _shifted_targets(batch["labels"], x.shape[1], offset)
         loss = _chunked_xent(x, head, targets) + aux
-        return loss, {"loss": loss, "aux": jnp.asarray(aux, jnp.float32)}
+        metrics = {"loss": loss, "aux": jnp.asarray(aux, jnp.float32)}
+        if counters:
+            metrics["counters"] = counters
+        return loss, metrics
     return f
 
 

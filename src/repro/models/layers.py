@@ -150,6 +150,23 @@ def blocked_attention(q, k, v, *, q_positions, kv_positions, causal: bool = True
         o = jnp.einsum("bkgqs,bskh->bqkgh", p.astype(v.dtype), v,
                        preferred_element_type=jnp.float32)
         return o.reshape(B, Sq, H, hd).astype(q.dtype)
+    if Sq > kv_chunk and Sq % kv_chunk == 0:
+        # queries a block at a time (each recomputed in the backward pass):
+        # the KV scan's saved carries then span one block of queries, not
+        # all of them (16 chunks x 8k queries x 16 heads of f32 would be
+        # 6 GB a worker at MLA's head size)
+        nb = Sq // kv_chunk
+
+        @jax.checkpoint
+        def one_block(args):
+            q_i, pos_i = args
+            return blocked_attention(q_i, k, v, q_positions=pos_i,
+                                     kv_positions=kv_positions, causal=causal,
+                                     window=window, kv_chunk=kv_chunk)
+        o = jax.lax.map(one_block, (
+            jnp.moveaxis(q.reshape(B, nb, kv_chunk, H, hd), 1, 0),
+            q_positions.reshape(nb, kv_chunk)))
+        return jnp.moveaxis(o, 0, 1).reshape(B, Sq, H, hd)
     n_chunks = Skv // kv_chunk
     kc = k.reshape(B, n_chunks, kv_chunk, KV, hd)
     vc = v.reshape(B, n_chunks, kv_chunk, KV, hd)
@@ -314,45 +331,82 @@ def gqa_cache_spec(num_kv_heads: int, tp: int, data_axes):
 def init_mla(key, d_model: int, num_heads: int, mla, tp: int, dtype):
     ks = jax.random.split(key, 8)
     qk_hd = mla.qk_nope_head_dim + mla.qk_rope_head_dim
-    params = {
-        "wq_a": dense_init(ks[0], (d_model, mla.q_lora_rank), d_model, dtype),
-        "q_a_norm": jnp.ones((mla.q_lora_rank,), dtype),
-        "wq_b": dense_init(ks[1], (mla.q_lora_rank, num_heads * qk_hd), mla.q_lora_rank, dtype),
+    h = maybe(shard_dim(num_heads, tp))
+    if mla.q_lora_rank:
+        params = {
+            "wq_a": dense_init(ks[0], (d_model, mla.q_lora_rank), d_model, dtype),
+            "q_a_norm": jnp.ones((mla.q_lora_rank,), dtype),
+            "wq_b": dense_init(ks[1], (mla.q_lora_rank, num_heads * qk_hd), mla.q_lora_rank, dtype),
+        }
+        r = maybe(shard_dim(mla.q_lora_rank, tp))
+        specs = {"wq_a": P(None, r), "q_a_norm": P(r), "wq_b": P(r, h)}
+    else:
+        params = {"wq": dense_init(ks[0], (d_model, num_heads * qk_hd), d_model, dtype)}
+        specs = {"wq": P(None, h)}
+    params.update({
         "wkv_a": dense_init(ks[2], (d_model, mla.kv_lora_rank + mla.qk_rope_head_dim), d_model, dtype),
         "kv_a_norm": jnp.ones((mla.kv_lora_rank,), dtype),
         "wkv_b": dense_init(ks[3], (mla.kv_lora_rank, num_heads * (mla.qk_nope_head_dim + mla.v_head_dim)), mla.kv_lora_rank, dtype),
         "wo": dense_init(ks[4], (num_heads * mla.v_head_dim, d_model), num_heads * mla.v_head_dim, dtype),
-    }
-    h = maybe(shard_dim(num_heads, tp))
-    r = maybe(shard_dim(mla.q_lora_rank, tp))
-    specs = {
-        "wq_a": P(None, r), "q_a_norm": P(r),
-        "wq_b": P(r, h),
-        "wkv_a": P(None, None), "kv_a_norm": P(None),
-        "wkv_b": P(None, h),
-        "wo": P(h, None),
-    }
+    })
+    specs.update({"wkv_a": P(None, None), "kv_a_norm": P(None),
+                  "wkv_b": P(None, h), "wo": P(h, None)})
     return params, specs
+
+
+# the MLA projections by their Hugging Face (DeepSeek) names, which name a
+# LoRA adapter's target
+MLA_TARGETS = {"wq": "q_proj", "wkv_a": "kv_a_proj_with_mqa",
+               "wkv_b": "kv_b_proj", "wo": "o_proj"}
+
+
+def lora_dense(x, w, adapter=None, scale: float = 1.0):
+    """x @ w, plus the LoRA term (x @ a) @ b * scale where an ``adapter``
+    {"a": (in, r), "b": (r, out)} is given. Every product takes operands in
+    x's dtype and accumulates in f32; the f32 adapters are cast to it."""
+    if adapter is None:
+        return x @ w
+    dt = x.dtype
+    y = jnp.dot(x, w, preferred_element_type=jnp.float32)
+    with jax.named_scope("lora"):
+        xa = jnp.dot(x, adapter["a"].astype(dt),
+                     preferred_element_type=jnp.float32).astype(dt)
+        y = y + scale * jnp.dot(xa, adapter["b"].astype(dt),
+                                preferred_element_type=jnp.float32)
+    return y.astype(dt)
 
 
 def apply_mla(params, x, *, num_heads: int, mla, positions, rope_theta: float,
               kv_chunk: int = 1024, cache=None, cur_index=None,
-              return_kv: bool = False):
-    """MLA: queries through a low-rank bottleneck; K/V through a compressed
-    latent (kv_lora_rank) + a decoupled RoPE key shared across heads.
-    The decode cache stores the *latent* (B, S, kv_lora_rank + rope_dim) —
-    the MLA memory win."""
+              return_kv: bool = False, lora=None, lora_scale: float = 1.0):
+    """MLA: queries through a low-rank bottleneck (or one ``wq`` where
+    ``q_lora_rank`` is 0); K/V through a compressed latent (kv_lora_rank)
+    + a decoupled RoPE key shared across heads. The decode cache stores
+    the *latent* (B, S, kv_lora_rank + rope_dim) — the MLA memory win.
+    ``lora``: adapters by target name (``MLA_TARGETS``), training path
+    only. RoPE rotates the two halves of the rope dims; DeepSeek's
+    checkpoints interleave pairs, which is the same up to a permutation
+    of the rope columns of ``wq`` and ``wkv_a``."""
     B, S, _ = x.shape
     nope, rd, vd = mla.qk_nope_head_dim, mla.qk_rope_head_dim, mla.v_head_dim
     qk_hd = nope + rd
+    lora = lora or {}
 
-    q = rms_norm(x @ params["wq_a"], params["q_a_norm"])
-    q = (q @ params["wq_b"]).reshape(B, S, num_heads, qk_hd)
+    def proj(inp, name):
+        return lora_dense(inp, params[name], lora.get(MLA_TARGETS[name]),
+                          lora_scale)
+
+    if mla.q_lora_rank:
+        q = rms_norm(x @ params["wq_a"], params["q_a_norm"])
+        q = q @ params["wq_b"]
+    else:
+        q = proj(x, "wq")
+    q = q.reshape(B, S, num_heads, qk_hd)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     q_rope = apply_rope(q_rope, positions, rope_theta)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
 
-    kv_a = x @ params["wkv_a"]                              # (B,S,rank+rd)
+    kv_a = proj(x, "wkv_a")                                 # (B,S,rank+rd)
     latent, k_rope = kv_a[..., :mla.kv_lora_rank], kv_a[..., mla.kv_lora_rank:]
     latent = rms_norm(latent, params["kv_a_norm"])
     k_rope = apply_rope(k_rope[..., None, :], positions, rope_theta)  # (B,S,1,rd)
@@ -391,7 +445,7 @@ def apply_mla(params, x, *, num_heads: int, mla, positions, rope_theta: float,
                        preferred_element_type=jnp.float32).astype(x.dtype)
         return (o.reshape(B, S, -1) @ params["wo"]), {"latent": lat_cache}
 
-    kv = (latent @ params["wkv_b"]).reshape(B, S, num_heads, nope + vd)
+    kv = proj(latent, "wkv_b").reshape(B, S, num_heads, nope + vd)
     k = jnp.concatenate([kv[..., :nope],
                          jnp.broadcast_to(k_rope, (B, S, num_heads, rd))], axis=-1)
     v = kv[..., nope:]
@@ -399,7 +453,7 @@ def apply_mla(params, x, *, num_heads: int, mla, positions, rope_theta: float,
     o = blocked_attention(q, k, jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, qk_hd - vd))),
                           q_positions=positions, kv_positions=positions,
                           causal=True, kv_chunk=kv_chunk)[..., :vd]
-    out = o.reshape(B, S, -1) @ params["wo"]
+    out = proj(o.reshape(B, S, -1), "wo")
     if return_kv:
         # MLA prefill cache: the compressed latent + decoupled rope key
         return out, {"latent": jnp.concatenate([latent, k_rope[..., 0, :]], axis=-1)}

@@ -113,3 +113,28 @@ def test_paper_net_round_takes_kernels(one_chip, async_mode):
     on_chip = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), args)
     assert "tpu_custom_call" in _compiled_text(fn, *on_chip)
     assert "tpu_custom_call" not in _compiled_text(fn, *args)
+
+
+def test_held_expert_layer_compiles(one_chip):
+    """moonlight-16b-a3b's held expert share (8 of 64 experts, top-6, four
+    silos of 8,192 tokens), forward and input gradient as the round takes
+    them: the grouped products compile to the TPU's ragged-dot kernels,
+    whose time ``moe_roofline.moonlight`` reads by that name."""
+    import dataclasses
+    from repro.configs.registry import get_config
+    from repro.models import moe as MOE
+    cfg = get_config("moonlight-16b-a3b")
+    moe_cfg = dataclasses.replace(cfg.moe, experts_held=(0, 8))
+    p = jax.eval_shape(lambda k: MOE.init_moe(k, cfg.d_model, moe_cfg, 1,
+                                              jnp.bfloat16)[0],
+                       jax.random.PRNGKey(0))
+
+    def step(params, x):
+        def loss(x):
+            out, c = MOE.apply_moe_held(params, x, moe_cfg)
+            return jnp.sum(out.astype(jnp.float32)), c
+        return jax.vmap(jax.grad(loss, has_aux=True))(x)
+    text = _compiled_text(
+        step, jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), p),
+        _sds((4, 1, 8192, cfg.d_model), jnp.bfloat16, one_chip))
+    assert "ragged-dot" in text and "tpu_custom_call" in text
