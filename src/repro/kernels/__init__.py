@@ -49,6 +49,14 @@ Module map
     unsharded flat/CNN trees with one leaf dtype; per-leaf jnp reference
     otherwise).
 
+``attention``
+    Causal self-attention for MLA's training path: the bundled splash
+    (flash) kernel over causal blocks only, v at its own head size, with
+    its dq and dk/dv backward kernels. ``models.layers.apply_mla`` takes it
+    where the program is lowered for a TPU, is not partitioned over
+    several devices and S tiles into blocks; ``blocked_attention``
+    elsewhere.
+
 ``swa_decode`` / ``ssd_scan``
     LLM-zoo hot loops (sliding-window decode attention; Mamba2/mLSTM
     SSD chunk scan) — unrelated to the trust round.

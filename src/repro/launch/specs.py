@@ -165,7 +165,7 @@ def train_setup(arch: str, shape_name: str, mesh, fed: FederationConfig,
         act = NamedSharding(mesh, P(None, "model", None))
 
     def fn(*a, **kw):
-        with activation_sharding(act):
+        with activation_sharding(act, mesh):
             return fl_round(*a, **kw)
     in_shardings = (_named(mesh, param_specs), _named(mesh, opt_specs),
                     _named(mesh, batch_specs))
@@ -214,7 +214,7 @@ def prefill_setup(arch: str, shape_name: str, mesh):
            if cfg.attn_type == "mla" and sh.seq_len % tp == 0 else None)
 
     def fn(params, batch):
-        with activation_sharding(act):
+        with activation_sharding(act, mesh):
             return api.prefill(params, cfg, batch, sh.seq_len)
 
     logits_spec = P(dp, None, None)   # logits replicated over model

@@ -8,7 +8,9 @@ replicated where a dim doesn't divide the axis size).
 Attention never materializes the (Sq, Skv) score matrix for long sequences:
 ``blocked_attention`` runs an online-softmax scan over KV chunks
 (flash-attention structure, pure JAX — the Pallas ``swa_decode`` kernel in
-``repro.kernels`` is the TPU-tiled decode variant).
+``repro.kernels`` is the TPU-tiled decode variant). MLA's causal training
+attention runs ``repro.kernels.attention`` where the program is lowered for
+a TPU.
 """
 from __future__ import annotations
 
@@ -18,6 +20,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from repro.kernels import attention
+from repro.kernels.tpu import on_tpu
+from repro.models.sharding import partitioned
 
 # The TP mesh axis name used by every spec in the zoo.
 TP_AXIS = "model"
@@ -130,12 +136,12 @@ def blocked_attention(q, k, v, *, q_positions, kv_positions, causal: bool = True
                       window: int = 0, kv_chunk: int = 1024):
     """Online-softmax attention over KV chunks — O(Sq·chunk) live memory.
 
-    q: (B, Sq, H, hd); k, v: (B, Skv, KV, hd) with H % KV == 0.
-    window > 0 => sliding-window mask (q_pos - kv_pos < window).
-    Returns (B, Sq, H, hd).
+    q: (B, Sq, H, hd); k: (B, Skv, KV, hd), v: (B, Skv, KV, vd) with
+    H % KV == 0. window > 0 => sliding-window mask (q_pos - kv_pos < window).
+    Returns (B, Sq, H, vd).
     """
     B, Sq, H, hd = q.shape
-    Skv, KV = k.shape[1], k.shape[2]
+    Skv, KV, vd = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV
     scale = 1.0 / math.sqrt(hd)
     qs = q.reshape(B, Sq, KV, G, hd) * scale
@@ -149,7 +155,7 @@ def blocked_attention(q, k, v, *, q_positions, kv_positions, causal: bool = True
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bkgqs,bskh->bqkgh", p.astype(v.dtype), v,
                        preferred_element_type=jnp.float32)
-        return o.reshape(B, Sq, H, hd).astype(q.dtype)
+        return o.reshape(B, Sq, H, vd).astype(q.dtype)
     if Sq > kv_chunk and Sq % kv_chunk == 0:
         # queries a block at a time (each recomputed in the backward pass):
         # the KV scan's saved carries then span one block of queries, not
@@ -166,10 +172,10 @@ def blocked_attention(q, k, v, *, q_positions, kv_positions, causal: bool = True
         o = jax.lax.map(one_block, (
             jnp.moveaxis(q.reshape(B, nb, kv_chunk, H, hd), 1, 0),
             q_positions.reshape(nb, kv_chunk)))
-        return jnp.moveaxis(o, 0, 1).reshape(B, Sq, H, hd)
+        return jnp.moveaxis(o, 0, 1).reshape(B, Sq, H, vd)
     n_chunks = Skv // kv_chunk
     kc = k.reshape(B, n_chunks, kv_chunk, KV, hd)
-    vc = v.reshape(B, n_chunks, kv_chunk, KV, hd)
+    vc = v.reshape(B, n_chunks, kv_chunk, KV, vd)
     kvpos = kv_positions.reshape(n_chunks, kv_chunk)
 
     # remat the chunk body: backward recomputes per-chunk scores instead of
@@ -192,12 +198,12 @@ def blocked_attention(q, k, v, *, q_positions, kv_positions, causal: bool = True
 
     m0 = jnp.full((B, KV, G, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, KV, G, Sq), jnp.float32)
-    a0 = jnp.zeros((B, KV, G, Sq, hd), jnp.float32)
+    a0 = jnp.zeros((B, KV, G, Sq, vd), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         body, (m0, l0, a0),
         (jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0), kvpos))
-    o = acc / jnp.maximum(l, 1e-30)[..., None]              # (B,KV,G,Sq,hd)
-    return jnp.moveaxis(o, 3, 1).reshape(B, Sq, H, hd).astype(q.dtype)
+    o = acc / jnp.maximum(l, 1e-30)[..., None]              # (B,KV,G,Sq,vd)
+    return jnp.moveaxis(o, 3, 1).reshape(B, Sq, H, vd).astype(q.dtype)
 
 
 def _attn_mask(q_pos, kv_pos, causal: bool, window: int):
@@ -384,9 +390,12 @@ def apply_mla(params, x, *, num_heads: int, mla, positions, rope_theta: float,
     + a decoupled RoPE key shared across heads. The decode cache stores
     the *latent* (B, S, kv_lora_rank + rope_dim) — the MLA memory win.
     ``lora``: adapters by target name (``MLA_TARGETS``), training path
-    only. RoPE rotates the two halves of the rope dims; DeepSeek's
-    checkpoints interleave pairs, which is the same up to a permutation
-    of the rope columns of ``wq`` and ``wkv_a``."""
+    only. Without a cache, ``positions`` is ``arange(S)``: the training
+    and prefill path is causal self-attention, the splash kernel
+    (``repro.kernels.attention``) where the program is lowered for a TPU
+    and not partitioned over several devices. RoPE rotates the two halves
+    of the rope dims; DeepSeek's checkpoints interleave pairs, which is the
+    same up to a permutation of the rope columns of ``wq`` and ``wkv_a``."""
     B, S, _ = x.shape
     nope, rd, vd = mla.qk_nope_head_dim, mla.qk_rope_head_dim, mla.v_head_dim
     qk_hd = nope + rd
@@ -449,10 +458,12 @@ def apply_mla(params, x, *, num_heads: int, mla, positions, rope_theta: float,
     k = jnp.concatenate([kv[..., :nope],
                          jnp.broadcast_to(k_rope, (B, S, num_heads, rd))], axis=-1)
     v = kv[..., nope:]
-    # pad v to qk head dim for the shared blocked core, then slice back
-    o = blocked_attention(q, k, jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, qk_hd - vd))),
-                          q_positions=positions, kv_positions=positions,
-                          causal=True, kv_chunk=kv_chunk)[..., :vd]
+    blocked = partial(blocked_attention, q_positions=positions,
+                      kv_positions=positions, causal=True, kv_chunk=kv_chunk)
+    if attention.fits(S) and not partitioned():
+        o = on_tpu(attention.causal_attention, blocked, q, k, v)
+    else:
+        o = blocked(q, k, v)
     out = proj(o.reshape(B, S, -1), "wo")
     if return_kv:
         # MLA prefill cache: the compressed latent + decoupled rope key

@@ -11,6 +11,11 @@ Why: without this, GSPMD may keep the remat checkpoint stack
 (L, B, S, d) fully replicated across the model axis — 48-96 GiB/device for
 the 34B config. Sequence-sharding the carry makes the saved activations
 1/TP of that, at the cost of an all-gather per layer on recompute.
+
+The same hook records the mesh the launch layer partitions the program
+over: ``partitioned()`` tells model code that the partitioner will split
+it across devices, where a Mosaic kernel (which the partitioner cannot
+split) must give way to its jnp reference.
 """
 from __future__ import annotations
 
@@ -19,18 +24,27 @@ import contextlib
 import jax
 
 _RESIDUAL_SHARDING = None
+_MESH = None
 
 
 @contextlib.contextmanager
-def activation_sharding(sharding):
-    """sharding: NamedSharding for per-worker (B, S, d) activations."""
-    global _RESIDUAL_SHARDING
-    prev = _RESIDUAL_SHARDING
+def activation_sharding(sharding, mesh=None):
+    """sharding: NamedSharding for per-worker (B, S, d) activations, or
+    None; mesh: the mesh the program is partitioned over."""
+    global _RESIDUAL_SHARDING, _MESH
+    prev = _RESIDUAL_SHARDING, _MESH
     _RESIDUAL_SHARDING = sharding
+    _MESH = mesh
     try:
         yield
     finally:
-        _RESIDUAL_SHARDING = prev
+        _RESIDUAL_SHARDING, _MESH = prev
+
+
+def partitioned() -> bool:
+    """Whether the program being traced is split over more than one device
+    by the partitioner (the mesh installed by ``activation_sharding``)."""
+    return _MESH is not None and _MESH.size > 1
 
 
 def shard_residual(x):

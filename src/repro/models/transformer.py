@@ -92,6 +92,10 @@ def embed_tokens(params, cfg: ModelConfig, tokens, patch_embeds=None):
     return x
 
 
+def _stack_depth(stack) -> int:
+    return jax.tree.leaves(stack)[0].shape[0]
+
+
 def _decoder_stack(params, cfg: ModelConfig, tokens, *, patch_embeds=None,
                    remat: bool = False, kv_chunk: int = 1024,
                    prefill_cache_len: int = 0, lora=None):
@@ -107,7 +111,7 @@ def _decoder_stack(params, cfg: ModelConfig, tokens, *, patch_embeds=None,
         raise NotImplementedError("prefill of leading dense layers")
     lora_scale = cfg.lora.scale if lora is not None else 1.0
 
-    def make_body(moe: bool):
+    def make_body(moe: bool, n_layers: int):
         def body(carry, inp):
             x, aux, cnt = carry
             lp, ad = inp
@@ -149,7 +153,10 @@ def _decoder_stack(params, cfg: ModelConfig, tokens, *, patch_embeds=None,
             return (shard_residual(x + m), aux + aux_l, cnt), kv
 
         if remat:
-            return jax.checkpoint(body, prevent_cse=False)
+            # the scan's loop keeps XLA from merging the recomputed forward
+            # with the first one; a one-layer scan is inlined, so there the
+            # checkpoint keeps them apart itself
+            return jax.checkpoint(body, prevent_cse=n_layers == 1)
         return body
 
     def adapters(name):
@@ -157,11 +164,11 @@ def _decoder_stack(params, cfg: ModelConfig, tokens, *, patch_embeds=None,
 
     carry = (x, 0.0, MOE.zero_counters() if cfg.moe.held else {})
     if "dense_layers" in params:
-        carry, _ = jax.lax.scan(make_body(False), carry,
-                                (params["dense_layers"],
-                                 adapters("dense_layers")))
+        carry, _ = jax.lax.scan(
+            make_body(False, _stack_depth(params["dense_layers"])), carry,
+            (params["dense_layers"], adapters("dense_layers")))
     (x, aux, cnt), cache = jax.lax.scan(
-        make_body(cfg.moe.enabled), carry,
+        make_body(cfg.moe.enabled, _stack_depth(params["layers"])), carry,
         (params["layers"], adapters("layers")))
     return L.rms_norm(x, params["final_norm"], cfg.norm_eps), aux, cnt, cache
 

@@ -5,7 +5,7 @@ window masks, RoPE properties."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.configs.base import MoEConfig
 from repro.models import layers as L
@@ -19,7 +19,7 @@ from repro.models import ssm as S
 
 def _naive_attention(q, k, v, causal=True, window=0):
     B, Sq, H, hd = q.shape
-    KV = k.shape[2]
+    KV, vd = k.shape[2], v.shape[-1]
     G = H // KV
     qs = q.reshape(B, Sq, KV, G, hd).astype(jnp.float32) / np.sqrt(hd)
     s = jnp.einsum("bqkgh,bskh->bkgqs", qs, k.astype(jnp.float32))
@@ -32,18 +32,21 @@ def _naive_attention(q, k, v, causal=True, window=0):
     s = jnp.where(mask[None, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bskh->bqkgh", p, v.astype(jnp.float32))
-    return o.reshape(B, Sq, H, hd)
+    return o.reshape(B, Sq, H, vd)
 
 
 @settings(max_examples=10, deadline=None)
 @given(seq=st.sampled_from([128, 256]), kv_chunk=st.sampled_from([32, 64]),
-       window=st.sampled_from([0, 48]), seed=st.integers(0, 100))
-def test_blocked_attention_matches_naive(seq, kv_chunk, window, seed):
+       window=st.sampled_from([0, 48]), seed=st.integers(0, 100),
+       vd=st.sampled_from([32, 16]))
+@example(seq=128, kv_chunk=32, window=0, seed=3, vd=16)     # v narrower
+@example(seq=128, kv_chunk=128, window=0, seed=4, vd=16)    # than q and k
+def test_blocked_attention_matches_naive(seq, kv_chunk, window, seed, vd):
     key = jax.random.PRNGKey(seed)
     B, H, KV, hd = 2, 4, 2, 32
     q = jax.random.normal(key, (B, seq, H, hd))
     k = jax.random.normal(jax.random.fold_in(key, 1), (B, seq, KV, hd))
-    v = jax.random.normal(jax.random.fold_in(key, 2), (B, seq, KV, hd))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (B, seq, KV, vd))
     pos = jnp.arange(seq)
     out = L.blocked_attention(q, k, v, q_positions=pos, kv_positions=pos,
                               causal=True, window=window, kv_chunk=kv_chunk)

@@ -291,3 +291,68 @@ def test_paper_net_round_takes_no_frozen_input():
     main = next(line for line in plain.splitlines()
                 if "func.func public @main(" in line)
     assert main.count("%arg") == len(jax.tree.leaves(args))
+
+
+@pytest.mark.parametrize("seq,kernel", [(S, False), (256, True)],
+                         ids=["reference", "kernel"])
+def test_mla_attention_without_padded_v(monkeypatch, seq, kernel):
+    """MLA's training attention, at this file's widths (q and k heads of
+    24, v of 16), equals what it gave when v was padded to q's head size
+    for the blocked scan and the output sliced back: on the reference path
+    at S = 32, and through the causal kernel (interpreted) at S = 256,
+    where it engages. float32 throughout, so they agree to rounding."""
+    from repro.models import layers as L
+    cfg = model_config(small_config(), "float32")
+    p, _ = L.init_mla(jax.random.PRNGKey(0), cfg.d_model, cfg.num_heads,
+                      cfg.mla, 1, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, seq, cfg.d_model))
+
+    def mla(x):
+        return L.apply_mla(p, x, num_heads=cfg.num_heads, mla=cfg.mla,
+                           positions=jnp.arange(seq),
+                           rope_theta=cfg.rope_theta, kv_chunk=64)
+    if kernel:
+        monkeypatch.setenv("SDFLB_FUSED_INTERPRET", "1")
+    assert ("pallas_call" in str(jax.make_jaxpr(mla)(x))) is kernel
+    new = jax.jit(mla)(x)
+    monkeypatch.delenv("SDFLB_FUSED_INTERPRET", raising=False)
+    blocked = L.blocked_attention
+
+    def padded(q, k, v, **kw):
+        pad = q.shape[-1] - v.shape[-1]
+        return blocked(q, k, jnp.pad(v, ((0, 0),) * 3 + ((0, pad),)),
+                       **kw)[..., :v.shape[-1]]
+    monkeypatch.setattr(L, "blocked_attention", padded)
+    monkeypatch.setattr(L.attention, "fits", lambda S: False)
+    old = jax.jit(mla)(x)
+    np.testing.assert_allclose(np.asarray(new), np.asarray(old),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_mla_attention_partitioned_keeps_blocked_scan(monkeypatch):
+    """Where the launch layer partitions the program over several devices
+    (a mesh in ``activation_sharding``), MLA's training attention stays
+    the blocked scan: the partitioner cannot split a Mosaic kernel. On one
+    device the same call takes the kernel."""
+    from jax.sharding import AbstractMesh
+    from repro.models import layers as L
+    from repro.models.sharding import activation_sharding
+    cfg = model_config(small_config(), "float32")
+    p, _ = L.init_mla(jax.random.PRNGKey(0), cfg.d_model, cfg.num_heads,
+                      cfg.mla, 1, jnp.float32)
+    x = jax.ShapeDtypeStruct((2, 256, cfg.d_model), jnp.float32)
+
+    def kernel_taken():
+        # a new function each time: a cached trace would not see the hook
+        return "pallas_call" in str(jax.make_jaxpr(lambda x: L.apply_mla(
+            p, x, num_heads=cfg.num_heads, mla=cfg.mla,
+            positions=jnp.arange(256), rope_theta=cfg.rope_theta,
+            kv_chunk=64))(x))
+    monkeypatch.setenv("SDFLB_FUSED_INTERPRET", "1")
+    assert kernel_taken()
+    for mesh in (AbstractMesh((1, 4), ("data", "model")),
+                 AbstractMesh((2, 2), ("data", "model"))):
+        with activation_sharding(None, mesh):
+            assert not kernel_taken()
+    with activation_sharding(None, AbstractMesh((1, 1), ("data", "model"))):
+        assert kernel_taken()

@@ -138,3 +138,74 @@ def test_held_expert_layer_compiles(one_chip):
         step, jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), p),
         _sds((4, 1, 8192, cfg.d_model), jnp.bfloat16, one_chip))
     assert "ragged-dot" in text and "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("arch,silos,S", [
+    ("moonlight-16b-a3b", 4, 8192), ("minicpm3-4b", 1, 4096)])
+def test_mla_training_attention_compiles(one_chip, arch, silos, S):
+    """MLA's training attention, forward and gradient through
+    ``apply_mla``, at the moonlight cell's shapes (four silos, 8,192
+    tokens, 16 heads, q and k heads of 192, v of 128) and at minicpm3-4b's
+    heads (40 heads, q and k 96, v 64, 4,096 tokens): the causal splash
+    kernels, forward, dq and dk/dv, compile for the chip (VMEM and
+    tiling), and ``attn_roofline.moonlight`` reads their time by these
+    names."""
+    from repro.models import layers as L
+    cfg = get_config(arch)
+    p = jax.eval_shape(lambda k: L.init_mla(k, cfg.d_model, cfg.num_heads,
+                                            cfg.mla, 1, jnp.bfloat16)[0],
+                       jax.random.PRNGKey(0))
+
+    def step(params, x):
+        def loss(x):
+            o = L.apply_mla(params, x, num_heads=cfg.num_heads, mla=cfg.mla,
+                            positions=jnp.arange(S),
+                            rope_theta=cfg.rope_theta, kv_chunk=512)
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.vmap(jax.grad(loss))(x)
+    text = _compiled_text(
+        step, jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), p),
+        _sds((silos, 1, S, cfg.d_model), jnp.bfloat16, one_chip))
+    for kernel in ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv"):
+        assert f"%{kernel}" in text
+
+
+@pytest.mark.parametrize("act", [None, "seq"], ids=["heads", "heads-seq"])
+def test_mla_training_attention_compiles_partitioned(topo, act):
+    """The same attention jitted over a described four-chip mesh, heads
+    sharded over ``model`` as ``launch.specs`` shards them (and, in the
+    second case, the residual stream sharded over the sequence as the MLA
+    prefill policy does), under the launch layer's
+    ``activation_sharding``: it compiles, on the blocked scan, since the
+    partitioner cannot split the splash kernel."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.models import layers as L
+    from repro.models.sharding import activation_sharding
+    cfg = get_config("moonlight-16b-a3b")
+    S, tp = 2048, 4
+    mesh = Mesh(np.array(topo.devices).reshape(1, tp), ("data", "model"))
+    specs = {}
+
+    def init(k):
+        params, spec = L.init_mla(k, cfg.d_model, cfg.num_heads, cfg.mla, tp,
+                                  jnp.bfloat16)
+        specs.update(spec)
+        return params
+    p = jax.eval_shape(init, jax.random.PRNGKey(0))
+    sh = lambda spec: NamedSharding(mesh, spec)
+    residual = sh(P(None, "model", None)) if act else None
+
+    def step(params, x):
+        def loss(x):
+            o = L.apply_mla(params, x, num_heads=cfg.num_heads, mla=cfg.mla,
+                            positions=jnp.arange(S),
+                            rope_theta=cfg.rope_theta, kv_chunk=512)
+            return jnp.sum(o.astype(jnp.float32))
+        with activation_sharding(residual, mesh):
+            return jax.grad(loss)(x)
+    params = jax.tree.map(lambda s, spec: _sds(s.shape, s.dtype, sh(spec)),
+                          p, specs, is_leaf=lambda x: isinstance(x, P))
+    x = _sds((1, S, cfg.d_model), jnp.bfloat16, sh(P()))
+    text = jax.jit(step).lower(params, x).compile().as_text()
+    assert "splash_mha" not in text
