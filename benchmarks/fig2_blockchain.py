@@ -93,13 +93,13 @@ def run_pipeline_depths(depths=(0, 1, 2, 4), rounds: int = 20,
     return out
 
 
-def run_sharded_pipeline(shard_counts=(1, 2, 4), rounds: int = 12,
+def run_settler_pipeline(depths=(1, 2, 4), rounds: int = 12,
                          samples: int = 768, seed: int = 0):
-    """End-to-end settler-pool sweep on the paper protocol: every
-    (pipeline_depth > 0, settlement_shards) combination seals the
-    byte-identical chain as the serial unsharded driver — the shard pool
-    changes who computes, never what is decided — while the training
-    thread keeps paying only the queue handoff."""
+    """End-to-end settler sweep on the paper protocol at 2 clusters × 3
+    workers: every pipelined depth seals the byte-identical chain as the
+    serial (inline, depth 0) driver — the settler thread changes when the
+    chain work runs, never what is decided — while the training thread
+    pays only the queue handoff."""
     import dataclasses
 
     from repro.configs.base import FederationConfig
@@ -111,23 +111,21 @@ def run_sharded_pipeline(shard_counts=(1, 2, 4), rounds: int = 12,
     base = FederationConfig(num_clusters=2, workers_per_cluster=3,
                             trust_threshold=0.2, merkle_chunk_size=1)
     chains, out = {}, {}
-    configs = [("serial", 0, 1)] + [(f"s{S}", 2, S) for S in shard_counts]
-    for name, depth, S in configs:
+    for depth in (0, *depths):
         ds = make_federated_mnist(6, samples=samples, seed=seed)
-        fed = dataclasses.replace(base, pipeline_depth=depth,
-                                  settlement_shards=S)
+        fed = dataclasses.replace(base, pipeline_depth=depth)
         proto = SDFLBProtocol(get_config("paper-net"), fed, PAPER_TC,
                               use_blockchain=True, seed=seed)
         for _ in range(rounds):
             proto.run_round(ds.round_batches(32))
         proto.finalize()
-        chains[name] = [b.hash for b in proto.ledger.blocks]
+        chains[depth] = [b.hash for b in proto.ledger.blocks]
         handoff = float(np.mean([r.chain_time for r in proto.history]))
-        out[name] = handoff
-        csv_row(f"fig2_sharded_pipeline_{name}", handoff * 1e6,
-                f"depth={depth} shards={S}")
-    assert all(c == chains["serial"] for c in chains.values()), \
-        "settler-pool chains must be byte-identical to the serial driver"
+        out[depth] = handoff
+        csv_row(f"fig2_settler_pipeline_depth{depth}", handoff * 1e6,
+                "serial" if depth == 0 else "pipelined")
+    assert all(c == chains[0] for c in chains.values()), \
+        "pipelined chains must be byte-identical to the serial driver"
     return out
 
 
@@ -175,5 +173,5 @@ if __name__ == "__main__":
     import json
     run_settlement_paths()
     run_pipeline_depths()
-    run_sharded_pipeline()
+    run_settler_pipeline()
     print(json.dumps(run()["with"][-1], indent=1))

@@ -87,125 +87,6 @@ def run_merkle_chunk_sweep(worker_count: int = 100_000,
     return t_commit
 
 
-def run_sharded_settlement(worker_count: int = 100_000,
-                           shard_counts=(1, 4, 8), rounds: int = 7,
-                           chunk_sizes=(64, 256, 4096), pool_size: int = 0,
-                           seed: int = 0,
-                           json_name: str = "sharded_settlement"):
-    """Sharded settlement sweep at fixed W: a full Algorithm 1 round
-    (slice settlement + per-shard subtree hashing + super-root block seal)
-    per (chunk size k, shard count S), shards fanned out to a
-    ``ShardWorkerPool``.
-
-    Claims pinned: (1) every (k, S) seals the *byte-identical* chain per k
-    — the subtree-aligned super-root makes shard count a node-local
-    execution detail, not a consensus change; (2) at a parallel-friendly
-    chunk size (leaves >= ``MIN_PARALLEL_LEAF_BYTES``, where each leaf
-    hash's GIL-released window amortizes the acquire/release handoff)
-    wall-time improves measurably at S >= 4 versus the serial S=1 settle;
-    (3) at the small default leaves (k=64) the contract *refuses* to fan
-    out — concurrent micro-hashing convoys on the GIL — so the pool never
-    regresses the default path (pooled ≈ serial, asserted with slack).
-    Writes ``BENCH_<json_name>.json`` for the perf trajectory."""
-    import os
-
-    from repro.chain.contract import MIN_PARALLEL_LEAF_BYTES, TrustContract
-    from repro.chain.ledger import Ledger
-    from repro.core.protocol import ShardWorkerPool
-
-    W = worker_count
-    rng = np.random.default_rng(seed)
-    score_mat = rng.random((rounds, W))
-    pool = ShardWorkerPool(pool_size or min(max(shard_counts),
-                                            os.cpu_count() or 1))
-    from repro.chain.contract import _RECORD_DTYPE
-    record_size = _RECORD_DTYPE.itemsize  # tracks the on-chain record layout
-    t_settle = {}
-    try:
-        for k in chunk_sizes:
-            chains = {}
-            for S in shard_counts:
-                led = Ledger()
-                c = TrustContract(led, requester_deposit=1e6,
-                                  worker_stake=10.0, penalty_pct=50.0,
-                                  trust_threshold=0.5,
-                                  top_k=max(W // 100, 1),
-                                  merkle_chunk_size=k, settlement_shards=S)
-                c.join_batch(W)
-                times = []
-                for r in range(rounds):
-                    t0 = time.monotonic()
-                    c.settle_round_batch(r, score_mat[r],
-                                         timestamp=float(r + 1),
-                                         pool=pool if S > 1 else None)
-                    times.append(time.monotonic() - t0)
-                t_settle[(k, S)] = float(np.median(times[1:] or times))
-                chains[S] = [b.hash for b in led.blocks]
-                assert led.verify_chain(deep=True)
-                fanout = led.num_shards(1) > 1 and \
-                    k * record_size >= MIN_PARALLEL_LEAF_BYTES and S > 1
-                csv_row(f"fig3_sharded_settle_w{W}_k{k}_s{S}",
-                        t_settle[(k, S)] * 1e6,
-                        f"shards={led.num_shards(1)} "
-                        f"{'parallel' if fanout else 'inline'} "
-                        f"per_worker_us={t_settle[(k, S)] / W * 1e6:.3f}")
-            # consensus is shard-count independent: byte-identical chains
-            first = shard_counts[0]
-            assert all(chains[S] == chains[first] for S in shard_counts), \
-                f"sharded chains must be byte-identical across S (k={k})"
-    finally:
-        pool.stop()
-    payload = {"worker_count": W, "rounds": rounds,
-               "record_size": record_size,
-               "min_parallel_leaf_bytes": MIN_PARALLEL_LEAF_BYTES,
-               "settle_s": {f"k{k}_s{S}": t for (k, S), t
-                            in t_settle.items()},
-               "cpu_count": os.cpu_count()}
-    out = {"settle_s": t_settle, "chains_identical": True}
-    parallel_ks = [k for k in chunk_sizes
-                   if k * record_size >= MIN_PARALLEL_LEAF_BYTES]
-    if 1 in shard_counts and parallel_ks:
-        # strict-win gate only at the LARGEST parallel leaves: with the
-        # retuned 4 KiB threshold, mid-size leaves (k=256 -> 10 KiB) are
-        # *allowed* to fan out — each leaf hash clears hashlib's 2 KiB
-        # GIL-release floor — but their win is runner-dependent, so they
-        # only carry a no-regress bound below
-        k = max(parallel_ks)
-        serial = t_settle[(k, 1)]
-        best = min(t_settle[(k, S)] for S in shard_counts if S >= 4)
-        payload["parallel_speedup"] = {"chunk_size": k,
-                                       "serial_s": serial, "best_s": best,
-                                       "speedup": serial / best}
-        csv_row(f"fig3_sharded_speedup_w{W}_k{k}", 0.0,
-                f"best_S>=4_vs_serial={serial / best:.2f}x")
-        # the win must be measurable (not asserting a large factor: CI
-        # runners may expose as few as 2 often-throttled cores; a 1-core
-        # box has no parallelism to win with, so only the no-regress
-        # bounds apply there)
-        if (os.cpu_count() or 1) >= 2:
-            assert best < 0.95 * serial, \
-                f"S>=4 settlement must beat serial at k={k}: {t_settle}"
-        out["parallel_speedup"] = serial / best
-        for k2 in parallel_ks:
-            if k2 == k:
-                continue
-            worst = max(t_settle[(k2, S)] for S in shard_counts)
-            assert worst < 1.5 * t_settle[(k2, 1)], \
-                f"newly-parallel k={k2} must not regress serial: {t_settle}"
-    small_ks = [k for k in chunk_sizes
-                if k * record_size < MIN_PARALLEL_LEAF_BYTES]
-    if 1 in shard_counts and small_ks:
-        k = small_ks[0]
-        worst = max(t_settle[(k, S)] for S in shard_counts)
-        # below the leaf threshold the pool must not engage — sharded
-        # settle stays within noise of serial instead of convoying
-        assert worst < 1.5 * t_settle[(k, 1)], \
-            f"gated fan-out must not regress small-leaf settles: {t_settle}"
-    bench_json(json_name, payload)
-    out["payload"] = payload
-    return out
-
-
 def run_sparse_settlement(worker_count: int = 1_000_000,
                           active_frac: float = 0.10, rounds: int = 6,
                           chunk_size: int = 64,
@@ -368,120 +249,76 @@ def run_sparse_settlement(worker_count: int = 1_000_000,
 
 
 def run_multi_task_node(worker_count: int = 100_000,
-                        task_counts=(1, 2, 4), shards: int = 4,
-                        chunk_size: int = 4096, rounds: int = 7,
-                        pool_size: int = 0, seed: int = 0,
-                        perf_gate: bool = True,
-                        json_name: str = "multi_task_node"):
+                        task_counts=(1, 2, 4), chunk_size: int = 4096,
+                        rounds: int = 7, seed: int = 0):
     """Multi-tenant settlement sweep at fixed *total* W: N co-tenant tasks
     (worker_count // N workers each) settle every round into ONE
-    multi-task block through the shared shard-worker pool, versus the
-    N=1 single-task serial path on the same total record count.
+    multi-task block, timed per settled record.
 
     Claims pinned: (1) determinism — re-driving the same score stream
-    seals byte-identical chains (the round-robin cross-task schedule is
-    seed-reproducible); (2) per-task super-roots are co-tenancy
+    seals byte-identical chains; (2) per-task roots are co-tenancy
     independent — bit-identical to each task settling alone on its own
-    ledger; (3) the perf gate (``perf_gate``, skip at smoke W where fixed
-    per-task overheads dominate a sub-ms round) — shared-pool multi-task
-    settlement throughput at N > 1 never regresses below the N=1 serial
-    path: the node re-plans each task's shard fan-out against the pool
-    budget, so cross-task parallelism replaces within-task parallelism as
-    N grows. Writes ``BENCH_<json_name>.json`` for the perf trajectory."""
-    import os
-
+    ledger."""
     from repro.chain.contract import TrustContract
     from repro.chain.ledger import Ledger
-    from repro.core.node import (ShardWorkerPool, TaskRoundWork,
-                                 settle_tasks_block)
+    from repro.core.node import TaskRoundWork, settle_tasks_block
 
     def make_contract(led, tid, Wt):
         c = TrustContract(led, requester_deposit=1e6, worker_stake=10.0,
                           penalty_pct=50.0, trust_threshold=0.5,
                           top_k=max(Wt // 100, 1),
-                          merkle_chunk_size=chunk_size,
-                          settlement_shards=shards, task_id=tid)
+                          merkle_chunk_size=chunk_size, task_id=tid)
         c.join_batch(Wt)
         return c
 
-    pool = ShardWorkerPool(pool_size or min(shards, os.cpu_count() or 1))
-    t_settle, t_record, tput = {}, {}, {}
-    try:
-        for N in task_counts:
-            Wt = worker_count // N           # N*Wt records actually settle
-                                             # per tick (exact, not W, when
-                                             # N does not divide W)
-            tids = [f"task-{i:02d}" for i in range(N)]
-            scores = np.random.default_rng(seed).random((rounds, N, Wt))
+    t_settle, tput = {}, {}
+    for N in task_counts:
+        # N*Wt records actually settle per tick (exact, not W, when N does
+        # not divide W)
+        Wt = worker_count // N
+        tids = [f"task-{i:02d}" for i in range(N)]
+        scores = np.random.default_rng(seed).random((rounds, N, Wt))
 
-            def drive():
-                led = Ledger()
-                cs = {tid: make_contract(led, tid, Wt) for tid in tids}
-                times, roots = [], []
-                for r in range(rounds):
-                    work = [TaskRoundWork(tid, cs[tid], r, scores[r, i])
-                            for i, tid in enumerate(tids)]
-                    t0 = time.monotonic()
-                    blk, _, errors = settle_tasks_block(
-                        led, work, timestamp=float(r + 1),
-                        pool=pool if N > 1 else None)
-                    times.append(time.monotonic() - t0)
-                    assert not errors
-                    roots.append(led.task_roots(blk.index))
-                assert led.verify_chain(deep=True)
-                return led, times, roots
+        def drive():
+            led = Ledger()
+            cs = {tid: make_contract(led, tid, Wt) for tid in tids}
+            times, roots = [], []
+            for r in range(rounds):
+                work = [TaskRoundWork(tid, cs[tid], r, scores[r, i])
+                        for i, tid in enumerate(tids)]
+                t0 = time.monotonic()
+                blk, _, errors = settle_tasks_block(led, work,
+                                                    timestamp=float(r + 1))
+                times.append(time.monotonic() - t0)
+                assert not errors
+                roots.append(led.task_roots(blk.index))
+            assert led.verify_chain(deep=True)
+            return led, times, roots
 
-            led, times, roots = drive()
-            # determinism: the same stream seals byte-identical chains
-            led2, times2, _ = drive()
-            assert [b.hash for b in led.blocks] \
-                == [b.hash for b in led2.blocks], \
-                f"multi-task chains must be reproducible (N={N})"
-            # steady-state capability: min over both drives' post-warmup
-            # rounds — shared 2-vCPU runners show intermittent 3-5x
-            # scheduling spikes that a 4-sample median does not absorb
-            samples = (times[1:] or times) + (times2[1:] or times2)
-            t_settle[N] = float(min(samples))
-            t_record[N] = t_settle[N] / (N * Wt)
-            tput[N] = 1.0 / t_record[N]
-            # per-task commits are co-tenancy independent: spot-check two
-            # tasks against standalone single-tenant runs
-            for i, tid in enumerate(tids[:2]):
-                solo_led = Ledger()
-                solo = make_contract(solo_led, tid, Wt)
-                for r in range(rounds):
-                    solo.settle_round_batch(r, scores[r, i],
-                                            timestamp=float(r + 1))
-                assert [roots[r][tid] for r in range(rounds)] \
-                    == [b.records_root for b in solo_led.blocks[1:]], \
-                    f"task {tid} super-roots must be co-tenancy independent"
-            csv_row(f"fig3_multi_task_node_w{worker_count}_n{N}",
-                    t_settle[N] * 1e6,
-                    f"tasks={N} shards={shards} k={chunk_size} "
-                    f"records_per_s={tput[N] / 1e6:.2f}M "
-                    f"{'shared-pool' if N > 1 else 'serial'}")
-    finally:
-        pool.stop()
-    serial = t_record.get(1)
-    if perf_gate and serial is not None:
-        for N in task_counts:
-            if N > 1:
-                # the gate, per settled record (exact for any task_counts):
-                # multi-tenancy through the shared pool must not regress
-                # below the single-task serial path. The slack absorbs
-                # shared-2-vCPU jitter (sporadic 30% drift between the N
-                # segments even on min-of-rounds); the failure mode this
-                # pins — N·S micro-thunks convoying on the GIL before
-                # shard re-planning — measured 1.85-2x, well outside it
-                assert t_record[N] < 1.5 * serial, \
-                    f"N={N} shared-pool settle must not regress below " \
-                    f"the N=1 serial path (per-record): {t_record}"
-    bench_json(json_name,
-               {"worker_count": worker_count, "rounds": rounds,
-                "chunk_size": chunk_size, "shards": shards,
-                "settle_s": {f"n{N}": t for N, t in t_settle.items()},
-                "records_per_s": {f"n{N}": t for N, t in tput.items()},
-                "cpu_count": os.cpu_count()})
+        led, times, roots = drive()
+        # determinism: the same stream seals byte-identical chains
+        led2, times2, _ = drive()
+        assert [b.hash for b in led.blocks] \
+            == [b.hash for b in led2.blocks], \
+            f"multi-task chains must be reproducible (N={N})"
+        samples = (times[1:] or times) + (times2[1:] or times2)
+        t_settle[N] = float(min(samples))
+        tput[N] = N * Wt / t_settle[N]
+        # per-task commits are co-tenancy independent: spot-check two
+        # tasks against standalone single-tenant runs
+        for i, tid in enumerate(tids[:2]):
+            solo_led = Ledger()
+            solo = make_contract(solo_led, tid, Wt)
+            for r in range(rounds):
+                solo.settle_round_batch(r, scores[r, i],
+                                        timestamp=float(r + 1))
+            assert [roots[r][tid] for r in range(rounds)] \
+                == [b.records_root for b in solo_led.blocks[1:]], \
+                f"task {tid} roots must be co-tenancy independent"
+        csv_row(f"fig3_multi_task_node_w{worker_count}_n{N}",
+                t_settle[N] * 1e6,
+                f"tasks={N} k={chunk_size} "
+                f"records_per_s={tput[N] / 1e6:.2f}M")
     return {"settle_s": t_settle, "records_per_s": tput}
 
 
@@ -579,6 +416,5 @@ if __name__ == "__main__":
     run_merkle_chunk_sweep()
     run_chain_scaling()
     run_sparse_settlement()
-    run_sharded_settlement()
     run_multi_task_node()
     run(rounds=30, samples=2048)

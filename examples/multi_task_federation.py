@@ -3,11 +3,11 @@
 The paper's blockchain layer is shared infrastructure — many collaborative
 learning tasks settle on the same chain. Here one ``ChainNode`` serves
 three heterogeneous MNIST federations (different worker counts, Merkle
-chunk sizes, shard counts, and round cadences). Ticks where several tasks
-fire seal ONE multi-task block committing the canonical
-``task_id → super-root`` map; solo ticks seal the classic single-task
-layout. Settlement proofs are three-level (chunk-in-shard, shard-in-task,
-task-in-block) and a failing task would abort only its own round.
+chunk sizes, and round cadences). Ticks where several tasks fire seal ONE
+multi-task block committing the canonical ``task_id → root`` map; solo
+ticks seal the classic single-task layout. Settlement proofs are
+two-level (chunk-in-task, task-in-block) and a failing task would abort
+only its own round.
 
     PYTHONPATH=src python examples/multi_task_federation.py
 """
@@ -22,11 +22,11 @@ def main() -> None:
     cfg = get_config("paper-net")
     node = ChainNode(pipeline_depth=2)
 
-    # three tenants: W=6 sharded task, W=4 two-cluster task, W=2 small task
+    # three tenants: W=6 task, W=4 two-cluster task, W=2 small task
     feds = {
         "hospital-fl": FederationConfig(
             num_clusters=2, workers_per_cluster=3, trust_threshold=0.3,
-            top_k_rewarded=3, merkle_chunk_size=2, settlement_shards=2),
+            top_k_rewarded=3, merkle_chunk_size=2),
         "bank-fl": FederationConfig(
             num_clusters=2, workers_per_cluster=2, trust_threshold=0.4,
             top_k_rewarded=2, merkle_chunk_size=1),
@@ -56,14 +56,14 @@ def main() -> None:
           f"(e.g. block {multi[0].index} commits "
           f"{sorted(multi[0].task_roots)})")
 
-    # a light client audits a co-tenant block's three-level proof without
+    # a light client audits a co-tenant block's two-level proof without
     # trusting the node: synced headers + a batched proof fetch
     from repro.serve import LightClient
     auditor = LightClient(node.read_server())
     auditor.sync()
     batch = auditor.fetch_proofs("hospital-fl", list(range(6)),
                                  round_index=0)
-    print(f"3-level proofs for all 6 hospital-fl workers, round 0: "
+    print(f"2-level proofs for all 6 hospital-fl workers, round 0: "
           f"{batch.num_digests} shared siblings, "
           f"verifies={auditor.verify_batch(batch)}, "
           f"worker 0 record={batch.decoded(0)}")

@@ -25,31 +25,20 @@ instead of ~2·W while balances stay fully auditable — per-worker via
 O(log(W/k) + k) proofs (``settlement_proof``: the record's chunk plus the
 node path) rather than per-worker embedded transactions.
 
-Sharded settlement (``settlement_shards`` > 1): a round is partitioned
-into contiguous slices of the struct-of-arrays state — each shard's
-``settle_shard`` computes its slice's BadWorkers mask, penalties and
-chunked Merkle subtree *without mutating contract state*, so slices run
-concurrently on a settler pool. A deterministic merge (shard order ==
-worker-id order) then applies the state transition from the concatenated
-per-shard results and seals the block over the cross-shard super-root.
-Shard boundaries are subtree-aligned (``plan_shard_bounds``), making the
-super-root — and hence every block hash, proof, election and penalty —
-bit-identical across shard counts and to the unsharded path; and because
-no state is touched until every shard succeeded, a failing shard leaves
-the contract and chain exactly as before the round (no half-settled
-super-root is ever committed).
-
 Multi-tenant settlement (``task_id``): several ``TrustContract`` tasks can
-share one ledger on a chain node. The round settlement is split into three
-composable phases so a node can co-commit many tasks' rounds into one
-multi-task block: ``prepare_round_batch`` (validation + per-shard compute
-thunks, pure), ``finish_round_batch`` (the deterministic merge — state
-transition + transactions + commit parts), and ``note_block`` (audit
-bookkeeping once the block is sealed). ``settle_round_batch`` composes the
-three over a single-task block exactly as before, so the single-tenant
-path is bit-identical. Proofs are task-scoped: ``settlement_proof`` walks
-chunk-in-shard, shard-in-task, and task-in-block levels (the last empty on
-single-task blocks) and verifies against the block's combined root.
+share one ledger on a chain node. A round settles in two steps so a node
+can co-commit many tasks' rounds into one multi-task block:
+``compute_round`` is the pure computation of the whole round (validation,
+BadWorkers mask, stake-capped and staleness-discounted penalties, the
+canonical records, and the chunked Merkle tree or the delta change set)
+and mutates nothing; ``apply_round`` then applies the state transition,
+drains the transactions and returns the round's commit. A round whose
+computation raises therefore leaves the contract and chain exactly as
+before. ``note_block`` records which block committed the round.
+``settle_round_batch`` composes the steps over a single-task block.
+Proofs are task-scoped: ``proof`` walks the chunk-in-task and
+task-in-block levels (the last empty on single-task blocks) and verifies
+against the block's combined root.
 
 Sparse settlement (``sparse_settlement=True``): the million-worker path.
 The contract keeps a persistent full-population record buffer (every
@@ -91,34 +80,19 @@ dict-shaped wrappers emitting bit-identical proofs.
 """
 from __future__ import annotations
 
-import os
 import warnings
-from dataclasses import dataclass, field
-from typing import Callable, Dict, Iterator, List, Mapping, Optional, Tuple
+from dataclasses import dataclass
+from typing import Dict, Iterator, List, Mapping, Optional, Tuple
 
 import numpy as np
 
-from repro.chain.ledger import (DeltaCommit, Ledger, MerkleTree, RecordBatch,
-                                gathered_leaf_digests, plan_shard_bounds)
+from repro.chain.ledger import (AnyCommit, DeltaCommit, DenseCommit, Ledger,
+                                RecordBatch, gathered_leaf_digests)
 from repro.chain.proofs import SettlementProof, build_settlement_proof
 
 
 class ContractError(RuntimeError):
     pass
-
-
-# GIL economics of parallel settlement: a leaf hash releases the GIL only
-# for updates of >= 2048 bytes (CPython's HASHLIB_GIL_MINSIZE — below that
-# pure-CPython parallel hashing is architecturally impossible), and each
-# release/acquire handoff costs more than a small leaf's hash. The framed
-# batched hasher (``chain.ledger.batch_leaf_digests``) issues exactly one
-# C call per leaf, halving the handoffs of the old two-``update`` path and
-# dropping the measured pooled-fanout crossover from ~32 KiB to ~4 KiB per
-# leaf on a 2-core host. Below the gate the sharded commit still runs
-# (same bytes, same root), just on the calling thread. Env-overridable
-# fallback for unusual hosts: SDFLB_MIN_PARALLEL_LEAF_BYTES.
-MIN_PARALLEL_LEAF_BYTES = int(
-    os.environ.get("SDFLB_MIN_PARALLEL_LEAF_BYTES", 4096))
 
 
 _RECORD_DTYPE = np.dtype([("round", "<i8"), ("worker", "<i8"),
@@ -159,30 +133,18 @@ def decode_settlement_record(leaf: bytes) -> Dict[str, float]:
 
 
 @dataclass
-class ShardSettlement:
-    """One shard's slice of a round, computed by ``settle_shard`` without
-    mutating contract state: the merge barrier applies mutations only after
-    every shard of the round succeeded."""
-    start: int                     # slice [start, stop) of the round's ids
-    stop: int
-    penalties: np.ndarray          # (stop-start,) Pen(w), stake-capped
-    stake_after: np.ndarray        # (stop-start,) post-penalty stakes
-    records: RecordBatch           # canonical encodings of this slice
-    tree: Optional[MerkleTree]     # chunked Merkle subtree over the slice
-    #                                (None on the sparse path — the delta
-    #                                commit re-hashes dirty chunks instead)
-
-
-@dataclass
-class RoundPrep:
-    """Validated inputs + per-shard compute thunks for one round — the
-    pure (no state mutation) first phase of a settlement, so a multi-task
-    node can fan many tasks' shard thunks out through one shared pool."""
+class RoundSettlement:
+    """One round as ``compute_round`` computed it, with no contract state
+    touched: ``apply_round`` applies it only once it exists."""
     round_index: int
-    ids: np.ndarray                # participating worker ids, id order
+    ids: np.ndarray                # settled worker ids, record order
     scores: np.ndarray             # aligned scores, float64
-    thunks: List[Callable[[], ShardSettlement]] = field(default_factory=list)
-    sparse: bool = False           # settle as a delta commit
+    penalties: np.ndarray          # Pen(w), stake-capped, aligned with ids
+    stake_after: np.ndarray        # post-penalty stakes, aligned with ids
+    records: RecordBatch           # canonical encodings, aligned with ids
+    commit: Optional[DenseCommit]  # the round's tree (None on the sparse
+    #                                path — its delta commit is built when
+    #                                applied — and for an empty round)
     # permutation s.t. ids == original_ids[order] when sparse settlement
     # had to sort the caller's ids into canonical record order (None when
     # they already were); penalties are unpermuted back before returning
@@ -192,16 +154,12 @@ class RoundPrep:
 
 @dataclass
 class RoundSeal:
-    """The deterministic merge's output — everything a block needs from
-    one task's round: drained transactions, per-shard commit parts (dense
-    path) or the prebuilt incremental commit (sparse path), and the
-    penalty vector. State has already transitioned when this exists."""
+    """What a block needs from one task's applied round: the drained
+    transactions, the round's commit (dense or delta; None when nothing
+    was settled) and the penalty vector in the caller's order."""
     txs: List[dict]
-    shards: List[RecordBatch]
-    trees: List[MerkleTree]
-    chunk_size: int
+    commit: Optional[AnyCommit]
     penalties: np.ndarray
-    delta: Optional[DeltaCommit] = None
 
 
 class WorkerAccount:
@@ -290,7 +248,6 @@ class TrustContract:
                  worker_stake: float, penalty_pct: float,
                  trust_threshold: float, top_k: int,
                  merkle_chunk_size: int = 64,
-                 settlement_shards: int = 1,
                  sparse_settlement: bool = False,
                  sparse_rebase_every: int = 0,
                  staleness_alpha: float = 0.0,
@@ -299,8 +256,6 @@ class TrustContract:
             raise ContractError("deployment requires a positive deposit")
         if merkle_chunk_size < 1:
             raise ContractError("merkle_chunk_size must be >= 1")
-        if settlement_shards < 1:
-            raise ContractError("settlement_shards must be >= 1")
         if sparse_rebase_every < 0:
             raise ContractError("sparse_rebase_every must be >= 0")
         if staleness_alpha < 0:
@@ -318,10 +273,8 @@ class TrustContract:
         # sync path) is bit-identical to staleness-unaware settlement.
         self.staleness_alpha = float(staleness_alpha)
         self.merkle_chunk_size = merkle_chunk_size
-        self.settlement_shards = settlement_shards
         self.sparse_settlement = bool(sparse_settlement)
         self.sparse_rebase_every = int(sparse_rebase_every)
-        self.min_parallel_leaf_bytes = MIN_PARALLEL_LEAF_BYTES
         # sparse-path state: the persistent full-population record buffer
         # (every worker's latest settlement record, genesis rows for the
         # never-settled), the chain's latest commit to overlay against,
@@ -428,82 +381,25 @@ class TrustContract:
 
     # -- per-round settlement (Alg. 1 steps 3-7), batch path ------------------
 
-    def shard_bounds(self, num_records: int,
-                     shards: Optional[int] = None) -> List[int]:
-        """Subtree-aligned record boundaries splitting a round of
-        ``num_records`` settlements into ≤ ``shards`` slices (default:
-        this contract's ``settlement_shards``). Because boundaries are
-        subtree-aligned, the committed super-root — and every proof and
-        block hash — is identical for every shard count: callers (e.g. a
-        multi-tenant node balancing N tasks over one pool) may re-plan
-        execution granularity freely."""
-        return plan_shard_bounds(num_records, self.merkle_chunk_size,
-                                 self.settlement_shards
-                                 if shards is None else shards)
-
-    def parallel_fanout_possible(self) -> bool:
-        """Whether ``settle_round_batch`` could ever hand shards to a pool:
-        more than one shard configured AND chunk leaves clear the GIL
-        threshold. Lets callers skip spawning worker threads that the gate
-        would never feed."""
-        return self.settlement_shards > 1 and self.parallel_leaf_ok()
-
-    def settle_shard(self, round_index: int, ids: np.ndarray, s: np.ndarray,
-                     start: int, stop: int, build_tree: bool = True,
-                     staleness: Optional[np.ndarray] = None
-                     ) -> ShardSettlement:
-        """Compute one contract shard's slice [start, stop) of a round —
-        BadWorkers mask, stake-capped penalties, canonical records, chunked
-        Merkle subtree — reading the struct-of-arrays state but mutating
-        nothing, so shards of one round run concurrently on a settler pool
-        (their id slices are disjoint, and the merge applies all mutations
-        afterwards on one thread). The sparse path passes
-        ``build_tree=False``: the slice's records become the *changed set*
-        of a delta commit, whose incremental update replaces the per-slice
-        subtree. ``staleness`` (aligned with ``ids``) makes penalties
-        staleness-discounted and is committed in the records, so the
-        event-driven node's economics are auditable on-chain."""
-        sl_ids = ids[start:stop]
-        sl_s = s[start:stop]
-        bad = sl_s < self.T                               # BadWorkers
-        stake_sel = self.stake[sl_ids]
-        full_pen = self.F * self.P / 100.0
-        sl_st = None
-        if staleness is not None:
-            sl_st = staleness[start:stop]
-            if self.staleness_alpha:
-                # a stale update was honest work against an old global —
-                # penalize it at its (discounted) evidentiary weight
-                full_pen = full_pen * self._staleness_discount(sl_st)
-        pen = np.where(bad, np.minimum(full_pen, stake_sel),
-                       0.0)                               # Pen(w), stake-capped
-        stake_after = stake_sel - pen
-        records = encode_settlement_records(round_index, sl_ids, sl_s, pen,
-                                            stake_after, staleness=sl_st)
-        return ShardSettlement(start, stop, pen, stake_after, records,
-                               MerkleTree(records, self.merkle_chunk_size)
-                               if build_tree else None)
-
     def _staleness_discount(self, staleness: np.ndarray) -> np.ndarray:
         """(1+s)^-alpha — the same discount ``core.trust.staleness_discount``
         applies inside the jitted round, here on the settlement side."""
         return (1.0 + staleness.astype(np.float64)) ** (-self.staleness_alpha)
 
-    def prepare_round_batch(self, round_index: int, scores: np.ndarray,
-                            worker_ids: Optional[np.ndarray] = None,
-                            shards: Optional[int] = None,
-                            staleness: Optional[np.ndarray] = None
-                            ) -> RoundPrep:
-        """Phase 1 of a settlement: validate inputs and build the per-shard
-        compute thunks (pure — no contract state is touched until
-        ``finish_round_batch``), so a multi-tenant node can interleave many
-        tasks' thunks through one shared worker pool. ``shards`` overrides
-        the execution granularity (consensus-invisible: subtree-aligned
-        boundaries commit the identical root for every shard count).
-        ``staleness`` (aligned with ``scores``) is recorded on-chain and —
-        with ``staleness_alpha > 0`` — discounts penalties and payout
-        credit. A failure here, or in any thunk, aborts the round with
-        nothing applied and nothing committed."""
+    def compute_round(self, round_index: int, scores: np.ndarray,
+                      worker_ids: Optional[np.ndarray] = None,
+                      staleness: Optional[np.ndarray] = None
+                      ) -> RoundSettlement:
+        """Step 1 of a settlement, pure (no contract state is touched until
+        ``apply_round``): validate inputs, then compute the BadWorkers
+        mask, stake-capped penalties, canonical records and — on the dense
+        path — the round's chunked Merkle tree. The sparse path sorts the
+        ids into record order, and its records become the *changed set*
+        of the delta commit ``apply_round`` builds. ``staleness`` (aligned
+        with ``scores``) is recorded on-chain and — with
+        ``staleness_alpha > 0`` — discounts penalties and payout credit. A
+        failure here aborts the round with nothing applied and nothing
+        committed."""
         if self.closed:
             raise ContractError("task closed")
         s = np.asarray(scores, np.float64).reshape(-1)
@@ -529,66 +425,49 @@ class TrustContract:
                 raise ContractError("staleness/scores length mismatch")
             if len(st) and st.min() < 0:
                 raise ContractError("staleness must be >= 0")
-        if self.sparse_settlement:
+        order = None
+        if self.sparse_settlement and worker_ids is not None \
+                and len(ids) > 1 and (np.diff(ids) < 0).any():
             # canonical record order is id order (record index == worker
             # id in the population commit); remember the permutation so
             # penalties return aligned with the caller's score order
-            order = None
-            if worker_ids is not None and len(ids) > 1 \
-                    and (np.diff(ids) < 0).any():
-                order = np.argsort(ids, kind="stable")
-                ids, s = ids[order], s[order]
-                if st is not None:
-                    st = st[order]
-            # one slice: the delta commit replaces the per-shard subtrees,
-            # so there is no per-slice tree to fan out
-            bounds = [0, len(ids)] if len(ids) else [0]
-            kw = {} if st is None else {"staleness": st}
-            thunks = [lambda a=a, b=b: self.settle_shard(
-                round_index, ids, s, a, b, build_tree=False, **kw)
-                for a, b in zip(bounds, bounds[1:])]
-            return RoundPrep(round_index, ids, s, thunks, sparse=True,
-                             order=order, staleness=st)
-        bounds = self.shard_bounds(len(ids), shards)
-        # staleness rides as a kwarg only when present: the sync path keeps
-        # the legacy settle_shard call signature
-        kw = {} if st is None else {"staleness": st}
-        thunks = [lambda a=a, b=b: self.settle_shard(round_index, ids, s,
-                                                     a, b, **kw)
-                  for a, b in zip(bounds, bounds[1:])]
-        return RoundPrep(round_index, ids, s, thunks, staleness=st)
+            order = np.argsort(ids, kind="stable")
+            ids, s = ids[order], s[order]
+            if st is not None:
+                st = st[order]
+        bad = s < self.T                                  # BadWorkers
+        stake_sel = self.stake[ids]
+        full_pen = self.F * self.P / 100.0
+        if st is not None and self.staleness_alpha:
+            # a stale update was honest work against an old global —
+            # penalize it at its (discounted) evidentiary weight
+            full_pen = full_pen * self._staleness_discount(st)
+        pen = np.where(bad, np.minimum(full_pen, stake_sel),
+                       0.0)                               # Pen(w), stake-capped
+        stake_after = stake_sel - pen
+        records = encode_settlement_records(round_index, ids, s, pen,
+                                            stake_after, staleness=st)
+        commit = None
+        if not self.sparse_settlement and len(ids):
+            commit = DenseCommit(records, self.merkle_chunk_size)
+        return RoundSettlement(round_index, ids, s, pen, stake_after,
+                               records, commit, order=order, staleness=st)
 
-    def parallel_leaf_ok(self) -> bool:
-        """The GIL gate for this contract's leaves: fan shard thunks out to
-        a pool only when one chunk leaf amortizes the release/acquire
-        handoff (see ``MIN_PARALLEL_LEAF_BYTES``)."""
-        return (self.merkle_chunk_size * _RECORD_DTYPE.itemsize
-                >= self.min_parallel_leaf_bytes)
-
-    def finish_round_batch(self, prep: RoundPrep,
-                           results: List[ShardSettlement],
-                           model_cid: str = "") -> RoundSeal:
-        """Phase 2: the deterministic merge. Applies the state transition
-        from the concatenated per-shard results (shard order == id order,
-        so every reduction is bit-identical to the unsharded path), drains
-        the pending transactions, and returns the block commit parts. Runs
-        only after *every* shard of the round succeeded."""
-        ids, s = prep.ids, prep.scores
-        round_index = prep.round_index
+    def apply_round(self, rs: RoundSettlement,
+                    model_cid: str = "") -> RoundSeal:
+        """Step 2: apply a computed round's state transition, drain the
+        pending transactions, and return the round's block commit (the
+        dense tree, or the delta commit built here from the change set)."""
+        ids, s, pen = rs.ids, rs.scores, rs.penalties
+        round_index = rs.round_index
         bad = s < self.T
-        if results:
-            pen = np.concatenate([r.penalties for r in results])
-            stake_after = np.concatenate([r.stake_after for r in results])
-        else:
-            pen = np.zeros(0, np.float64)
-            stake_after = np.zeros(0, np.float64)
-        self.stake[ids] = stake_after
+        self.stake[ids] = rs.stake_after
         self.penalized_rounds[ids] += bad
         self.requester_balance += float(pen.sum())        # step 7
-        if prep.staleness is not None and self.staleness_alpha:
+        if rs.staleness is not None and self.staleness_alpha:
             # stale contributions earn payout credit at the same
             # (1+s)^-alpha discount the aggregation gave their update
-            self.score_sum[ids] += s * self._staleness_discount(prep.staleness)
+            self.score_sum[ids] += s * self._staleness_discount(rs.staleness)
         else:
             self.score_sum[ids] += s
         self.score_count[ids] += 1
@@ -602,22 +481,18 @@ class TrustContract:
         if model_cid:
             txs.append({"type": "model", "round": round_index,
                         "cid": model_cid})
-        if prep.sparse:
-            self._round_full_cover[round_index] = True
-            delta = self._sparse_commit(round_index, ids, results)
-            pen_out = pen
-            if prep.order is not None:      # back to the caller's order
-                pen_out = np.empty_like(pen)
-                pen_out[prep.order] = pen
-            return RoundSeal(txs, [], [], self.merkle_chunk_size, pen_out,
-                             delta=delta)
-        return RoundSeal(txs, [r.records for r in results],
-                         [r.tree for r in results],
-                         self.merkle_chunk_size, pen)
+        if not self.sparse_settlement:
+            return RoundSeal(txs, rs.commit, pen)
+        self._round_full_cover[round_index] = True
+        delta = self._sparse_commit(ids, rs.records)
+        if rs.order is not None:            # back to the caller's order
+            unsorted = np.empty_like(pen)
+            unsorted[rs.order] = pen
+            pen = unsorted
+        return RoundSeal(txs, delta, pen)
 
-    def _sparse_commit(self, round_index: int, ids: np.ndarray,
-                       results: List[ShardSettlement]
-                       ) -> Optional[DeltaCommit]:
+    def _sparse_commit(self, ids: np.ndarray,
+                       records: RecordBatch) -> Optional[DeltaCommit]:
         """Fold this round's changed records into the persistent
         full-population buffer and commit: a dense anchor
         (``DeltaCommit.full``) on the first round, after enrollment grew
@@ -646,12 +521,7 @@ class TrustContract:
             self._pop_records = pop
             rebase = True
         pop = self._pop_records
-        if results:
-            new_rows = np.concatenate(
-                [np.frombuffer(r.records.buf, _RECORD_DTYPE)
-                 for r in results])
-        else:
-            new_rows = np.empty(0, dtype=_RECORD_DTYPE)
+        new_rows = np.frombuffer(records.buf, _RECORD_DTYPE)
         pop[ids] = new_rows                 # scatter this round's records
         self._rounds_since_base += 1
         if (self._last_commit is None or rebase or len(ids) == W
@@ -674,8 +544,8 @@ class TrustContract:
 
     def note_block(self, round_index: int, ids: np.ndarray,
                    block_index: int) -> None:
-        """Phase 3: audit bookkeeping once the round's block is sealed —
-        keys ``settlement_proof`` to the block that committed it."""
+        """Audit bookkeeping once the round's block is sealed — keys
+        ``settlement_proof`` to the block that committed it."""
         self._round_blocks[round_index] = block_index
         self._round_ids[round_index] = ids
 
@@ -683,7 +553,6 @@ class TrustContract:
                            worker_ids: Optional[np.ndarray] = None,
                            model_cid: str = "",
                            timestamp: Optional[float] = None,
-                           pool=None,
                            staleness: Optional[np.ndarray] = None
                            ) -> np.ndarray:
         """Vectorized settlement: BadWorkers mask, stake-capped penalties,
@@ -692,30 +561,16 @@ class TrustContract:
         common full-participation round). ``timestamp`` lets the protocol
         seal blocks at logical (round-indexed) time so every node — and the
         threaded vs serial drivers — computes identical block hashes.
-        ``pool`` (any object with ``map(list_of_thunks)``, e.g.
-        ``repro.core.node.ShardWorkerPool``) runs the per-shard slices
-        concurrently; the result is bit-identical with or without it.
-        Composes prepare → shard fan-out → merge → seal over a single-task
-        block, which is exactly the pre-multi-tenant settlement path.
-        Returns the (len(scores),) penalty vector aligned with ``scores``."""
-        prep = self.prepare_round_batch(round_index, scores, worker_ids,
-                                        staleness=staleness)
-        # fan the round out across contract shards (pure compute, no state
-        # mutation — a shard failure aborts the round with nothing applied
-        # and nothing committed)
-        if pool is not None and len(prep.thunks) > 1 \
-                and self.parallel_leaf_ok():
-            results: List[ShardSettlement] = pool.map(prep.thunks)
-        else:
-            results = [t() for t in prep.thunks]
-        seal = self.finish_round_batch(prep, results, model_cid=model_cid)
-        blk = self.ledger.append_block(
-            seal.txs, timestamp=timestamp,
-            record_shards=seal.shards or None,
-            shard_trees=seal.trees or None,
-            record_delta=seal.delta,
-            chunk_size=seal.chunk_size, task_id=self.task_id)
-        self.note_block(round_index, prep.ids, blk.index)
+        Composes ``compute_round`` → ``apply_round`` → seal over a
+        single-task block. Returns the (len(scores),) penalty vector
+        aligned with ``scores``."""
+        rs = self.compute_round(round_index, scores, worker_ids,
+                                staleness=staleness)
+        seal = self.apply_round(rs, model_cid=model_cid)
+        blk = self.ledger.append_block(seal.txs, timestamp=timestamp,
+                                       commit=seal.commit,
+                                       task_id=self.task_id)
+        self.note_block(round_index, rs.ids, blk.index)
         return seal.penalties
 
     def settle_round(self, round_index: int, scores: Dict[str, float],
@@ -776,18 +631,16 @@ class TrustContract:
             reward[top] = share
             self.balance += reward
             self.reward_pool = 0.0
-        ids = np.arange(W)
-        records = encode_settlement_records(-1, ids, np.zeros(W), -refund,
-                                            np.zeros(W)) if W else None
+        commit = DenseCommit(encode_settlement_records(
+            -1, np.arange(W), np.zeros(W), -refund, np.zeros(W)),
+            self.merkle_chunk_size) if W else None
         txs = self.pending
         self.pending = []
         txs.append({"type": "finalize_batch", "workers": W,
                     "refund_total": float(refund.sum()),
                     "reward_total": float(reward.sum()),
                     "top_k": int(min(self.k, W)) if W else 0})
-        self.ledger.append_block(txs, timestamp=timestamp,
-                                 record_batch=records,
-                                 chunk_size=self.merkle_chunk_size,
+        self.ledger.append_block(txs, timestamp=timestamp, commit=commit,
                                  task_id=self.task_id)
         payout = refund + reward
         return {self._names[i]: float(payout[i]) for i in range(W)}
@@ -809,9 +662,9 @@ class TrustContract:
         """O(log(W/k) + k) typed proof that worker ``worker`` (id or name)
         was settled as recorded in ``round_index``'s block: the record's
         chunk (the k records sharing its Merkle leaf, ``offset`` locating
-        the record within it), the node path to the block root —
-        chunk-in-shard, shard-in-task, and (on multi-task blocks)
-        task-in-block levels concatenated — and the decoded record view.
+        the record within it), the node path to the block root — the
+        chunk-in-task and (on multi-task blocks) task-in-block levels
+        concatenated — and the decoded record view.
         Verify with ``proof.verify(head)`` against any trusted head (a
         ``Block``, a light client's ``BlockHeader``, or a root string)."""
         wid = worker if isinstance(worker, (int, np.integer)) \

@@ -27,38 +27,28 @@ O(log(W/k)) node path; ``chunk_size=1`` reproduces the per-record tree
 bit-for-bit. ``work_units`` counts the batched cost model: 1 + |txs| per
 block plus the ~2·ceil(n/k)−1 Merkle hashes of an n-record commit.
 
-Sharded commits: a block may commit S per-shard record batches at once
-(``ShardedCommit``). Shard boundaries produced by ``plan_shard_bounds``
-are *subtree-aligned* — every shard but the last covers exactly 2^m chunk
-leaves — so the cross-shard super-root (shard subtree roots combined
-pairwise bottom-up with the same interior-node rule) is bit-identical to
-the flat tree over the concatenated records, for every shard count.
-Sharding is therefore a node-local execution detail (subtrees build in
-parallel on a settler pool) rather than a consensus-visible change: S=1,
-S=4 and the unsharded commit all seal byte-identical blocks, and a
-record's ``merkle_proof`` — its chunk path inside the shard followed by
-the shard path to the super-root — is the same ``(side, digest)`` list
-the flat tree emits, verified by the unchanged ``MerkleTree.verify``.
-``verify_chain(deep=True)`` recurses through shards, rebuilding every
-subtree and the super-root from the stored batches.
+Dense commits: a round's records are committed as one chunked Merkle tree
+(``DenseCommit``) over its ``RecordBatch``; a record's ``merkle_proof`` is
+its chunk path to the task's root, verified by ``MerkleTree.verify``.
+``verify_chain(deep=True)`` rebuilds every tree from the stored batches.
 
 Multi-task commits (the multi-tenant chain layout): one chain node may
 serve N concurrent federated tasks, and a block may commit several tasks'
-rounds at once. ``MultiTaskCommit`` layers a third Merkle level over the
+rounds at once. ``MultiTaskCommit`` layers a second Merkle level over the
 per-task commit roots — task roots combine pairwise in canonical (sorted
 ``task_id``) order with the same interior-node rule into the block root,
 and multi-task blocks additionally carry the canonical
-``task_id → super-root`` map (``Block.task_roots``, part of the block
-hash). A settlement proof is then three-level — chunk path in shard,
-shard path in task, task path in block — still one ``(side, digest)``
-list consumed by the unchanged ``MerkleTree.verify``. With a single task
-the task level is a lone root: the block root equals the task's
-super-root, the task path is empty, and ``task_roots`` is omitted from
-the hashed body, so single-task blocks are bit-identical to the
-pre-multi-tenant layout. ``verify_chain(deep=True)`` recurses through
-every task's shards and the task level, and corrupting one task's stored
-records never invalidates another task's proofs (its sibling digests are
-the stored task roots, not the corrupted bytes).
+``task_id → root`` map (``Block.task_roots``, part of the block hash). A
+settlement proof is then two-level — chunk path in the task's tree, task
+path in the block — still one ``(side, digest)`` list consumed by the
+unchanged ``MerkleTree.verify``. With a single task the task level is a
+lone root: the block root equals the task's root, the task path is
+empty, and ``task_roots`` is omitted from the hashed body, so
+single-task blocks are bit-identical to the pre-multi-tenant layout.
+``verify_chain(deep=True)`` rebuilds every task's tree and the task
+level, and corrupting one task's stored records never invalidates
+another task's proofs (its sibling digests are the stored task roots,
+not the corrupted bytes).
 
 Two commit paths — dense and delta. Everything above describes the
 *dense* path: a block commits a fresh tree over every record the round
@@ -88,16 +78,13 @@ domain-separation prefix byte before each chunk's records, laid out
 contiguously) and issuing one ``hashlib.sha256`` call per leaf over the
 framed row — byte-identical digests to the incremental two-``update``
 path, but a single C call per leaf that releases the GIL once instead of
-twice. This both speeds up serial hashing (~1.15x at small chunk sizes)
-and lowers the chunk-size floor at which pooled shard fan-out wins (see
-``MIN_PARALLEL_LEAF_BYTES`` in ``chain.contract``).
+twice (~1.15x faster serial hashing at small chunk sizes).
 """
 from __future__ import annotations
 
 import hashlib
 import json
 import time
-from bisect import bisect_right
 from dataclasses import dataclass
 from typing import (Any, Dict, List, Mapping, Optional, Sequence, Tuple,
                     Union)
@@ -241,8 +228,8 @@ def gathered_leaf_digests(batch: RecordBatch, chunk_size: int,
 def _combine(level: List[bytes]) -> Tuple[List[bytes], int]:
     """One level of pairwise interior hashing; the odd node is promoted
     unpaired. Returns (next level, interior hashes performed). Shared by
-    the in-shard tree and the cross-shard super-root so there is exactly
-    one hashing rule."""
+    the record tree and the cross-task level so there is exactly one
+    hashing rule."""
     nxt = [hashlib.sha256(_NODE_PREFIX + level[i] + level[i + 1]).digest()
            for i in range(0, len(level) - 1, 2)]
     ops = len(nxt)
@@ -381,149 +368,65 @@ class MerkleTree:
         return h.hex() == root
 
 
-# -- sharded (two-level) commits ----------------------------------------------
+# -- dense commits ------------------------------------------------------------
 
 
-def plan_shard_bounds(num_records: int, chunk_size: int,
-                      shards: int) -> List[int]:
-    """Record-index boundaries splitting ``num_records`` into at most
-    ``shards`` contiguous ranges whose edges land on whole subtrees: every
-    shard but the last covers exactly 2^m chunk leaves (the last takes the
-    remainder), with m the smallest exponent giving ≤ ``shards`` ranges.
-    This alignment is what makes the per-shard subtree roots combine to
-    exactly the flat tree's root (see ``ShardedCommit``)."""
-    if num_records < 0 or chunk_size < 1 or shards < 1:
-        raise ValueError("need num_records >= 0, chunk_size/shards >= 1")
-    if num_records == 0:
-        return [0]
-    leaves = -(-num_records // chunk_size)
-    shards = min(shards, leaves)
-    m = 0
-    while (1 << m) * shards < leaves:      # smallest m: ceil(L/2^m) <= shards
-        m += 1
-    step = (1 << m) * chunk_size
-    return list(range(0, num_records, step)) + [num_records]
+class DenseCommit(Sequence):
+    """One round's chunked Merkle commit over its records: the records
+    (kept for audit) and the ``MerkleTree`` over them, behind the accessor
+    surface every commit kind shares. Indexing is over the records."""
 
+    __slots__ = ("records", "chunk_size", "tree")
 
-class ShardedCommit(Sequence):
-    """Two-level Merkle commitment over per-shard record batches.
-
-    Level one: each shard's records get their own chunked subtree (built
-    independently — in parallel on a settler pool when one is supplied).
-    Level two: the shard subtree roots combine pairwise bottom-up with the
-    same interior-node rule into the cross-shard *super-root*, which is
-    what the block commits to. With subtree-aligned shard boundaries
-    (``plan_shard_bounds``) the super-root and every record's proof are
-    bit-identical to the flat single-tree commit, so shard count never
-    changes block hashes — only who hashes which records.
-
-    Indexing is over the concatenated record sequence, so the ledger's
-    per-record audit surface is shard-agnostic.
-    """
-
-    __slots__ = ("shards", "trees", "chunk_size", "bounds", "super_levels",
-                 "hash_ops")
-
-    def __init__(self, shards: Sequence[Records], chunk_size: int = 1,
-                 trees: Optional[Sequence[MerkleTree]] = None) -> None:
-        if not shards or any(not len(s) for s in shards):
-            raise ValueError("ShardedCommit needs non-empty shards")
-        self.shards: List[Records] = list(shards)
+    def __init__(self, records: Records, chunk_size: int = 1) -> None:
+        self.records = records
         self.chunk_size = chunk_size
-        if trees is None:
-            trees = [MerkleTree(s, chunk_size) for s in self.shards]
-        self.trees: List[MerkleTree] = list(trees)
-        if len(self.trees) != len(self.shards):
-            raise ValueError("one precomputed tree per shard required")
-        bounds = [0]
-        for s in self.shards:
-            bounds.append(bounds[-1] + len(s))
-        self.bounds = bounds
-        level = [t.levels[-1][0] for t in self.trees]   # shard root digests
-        self.super_levels: List[List[bytes]] = [level]
-        super_ops = 0
-        while len(level) > 1:
-            level, ops = _combine(level)
-            super_ops += ops
-            self.super_levels.append(level)
-        self.hash_ops = sum(t.hash_ops for t in self.trees) + super_ops
-
-    # -- concatenated-record view --------------------------------------------
+        self.tree = MerkleTree(records, chunk_size)
 
     def __len__(self) -> int:
-        return self.bounds[-1]
-
-    def _locate(self, record_index: int) -> Tuple[int, int]:
-        if not 0 <= record_index < len(self):
-            raise IndexError(f"record index {record_index} out of range")
-        s = bisect_right(self.bounds, record_index) - 1
-        return s, record_index - self.bounds[s]
+        return len(self.records)
 
     def __getitem__(self, i):
-        if isinstance(i, slice):
-            return [self[j] for j in range(*i.indices(len(self)))]
-        if i < 0:
-            i += len(self)
-        s, local = self._locate(i)
-        return self.shards[s][local]
-
-    @property
-    def num_shards(self) -> int:
-        return len(self.shards)
+        return self.records[i]
 
     @property
     def root(self) -> str:
-        return self.super_levels[-1][0].hex()
+        return self.tree.root
 
     @property
     def root_digest(self) -> bytes:
-        """Raw super-root digest — the task-level leaf of a multi-task
-        commit (shared accessor across commit kinds)."""
-        return self.super_levels[-1][0]
+        """Raw root digest — the task-level leaf of a multi-task commit."""
+        return self.tree.levels[-1][0]
 
-    def shard_roots(self) -> List[str]:
-        return [t.root for t in self.trees]
-
-    # -- two-level proofs -----------------------------------------------------
-
-    def shard_path(self, shard_index: int) -> List[Tuple[str, str]]:
-        """Sibling path from shard ``shard_index``'s subtree root to the
-        super-root — the cross-shard half of a settlement proof."""
-        if not 0 <= shard_index < self.num_shards:
-            raise IndexError(f"shard index {shard_index} out of range")
-        return _path_through(self.super_levels[:-1], shard_index)
+    @property
+    def hash_ops(self) -> int:
+        return self.tree.hash_ops
 
     def record_proof(self, record_index: int) -> List[Tuple[str, str]]:
-        """Chunk path inside the record's shard + the shard path to the
-        super-root. ``MerkleTree.verify`` consumes it unchanged (both
-        halves are the same ``(side, digest)`` encoding), and with aligned
-        shards the concatenation is byte-equal to the flat tree's proof."""
-        s, local = self._locate(record_index)
-        return self.trees[s].record_proof(local) + self.shard_path(s)
+        return self.tree.record_proof(record_index)
 
     def record_chunk(self, record_index: int) -> Tuple[List[bytes], int]:
-        """The record's leaf chunk (within its shard) and its offset."""
-        s, local = self._locate(record_index)
+        """The record's leaf chunk and its offset within it."""
+        if not 0 <= record_index < len(self):
+            raise IndexError(f"record index {record_index} out of range")
         k = self.chunk_size
-        start = (local // k) * k
-        stop = min(start + k, len(self.shards[s]))
-        return [bytes(self.shards[s][i]) for i in range(start, stop)], \
-            local - start
+        start = (record_index // k) * k
+        stop = min(start + k, len(self))
+        return [bytes(self.records[i]) for i in range(start, stop)], \
+            record_index - start
 
     def tamper(self, record_index: int, leaf: bytes) -> None:
         """Test hook: corrupt one stored record in place."""
-        s, local = self._locate(record_index)
-        if isinstance(self.shards[s], RecordBatch):
-            self.shards[s] = list(self.shards[s])
-        self.shards[s][local] = leaf
+        if isinstance(self.records, RecordBatch):
+            self.records = list(self.records)
+        self.records[record_index] = leaf
 
-    def rebuild(self) -> "ShardedCommit":
-        """Fresh commit rebuilt from the stored batches."""
-        return ShardedCommit(self.shards, self.chunk_size)
+    def rebuild(self) -> "DenseCommit":
+        """Fresh commit rebuilt from the stored records."""
+        return DenseCommit(self.records, self.chunk_size)
 
     def recompute_root(self) -> str:
-        """Root rebuilt from the stored batches (deep verification —
-        recurses through every shard subtree and the super levels)."""
+        """Root rebuilt from the stored records (deep verification)."""
         return self.rebuild().root
 
 
@@ -547,8 +450,7 @@ class DeltaCommit(Sequence):
     predecessor's, down to the base), so proofs and audits cover *idle*
     workers too — every block commits every worker's latest record, and
     ``record_proof``/``record_chunk``/``MerkleTree.verify`` behave exactly
-    as on a single-shard dense commit (the tree is flat, so the proof is
-    the flat tree's ``(side, digest)`` path).
+    as on a ``DenseCommit`` over the same records.
 
     ``hash_ops`` counts only the hashing this commit actually performed
     (all leaves + interiors for a base; dirty leaves + dirty interiors for
@@ -646,10 +548,6 @@ class DeltaCommit(Sequence):
         raise IndexError(i)                   # unreachable
 
     @property
-    def num_shards(self) -> int:
-        return 1
-
-    @property
     def root(self) -> str:
         return self.tree.root
 
@@ -657,15 +555,12 @@ class DeltaCommit(Sequence):
     def root_digest(self) -> bytes:
         return self.tree.levels[-1][0]
 
-    def shard_roots(self) -> List[str]:
-        return [self.root]
-
     # -- proofs / audit (flat-tree semantics) --------------------------------
 
     def record_proof(self, record_index: int) -> List[Tuple[str, str]]:
-        """Flat-tree node path for the chunk committing ``record_index`` —
-        the same ``(side, digest)`` list a dense single-shard commit
-        emits, valid for idle and active records alike."""
+        """Node path for the chunk committing ``record_index`` — the same
+        ``(side, digest)`` list a ``DenseCommit`` emits, valid for idle and
+        active records alike."""
         return self.tree.record_proof(record_index)
 
     def record_chunk(self, record_index: int) -> Tuple[List[bytes], int]:
@@ -723,23 +618,23 @@ class DeltaCommit(Sequence):
         return MerkleTree(self.materialize(), self.chunk_size).root
 
 
-AnyCommit = Union[ShardedCommit, DeltaCommit]
+AnyCommit = Union[DenseCommit, DeltaCommit]
 
 
-# -- multi-task (three-level) commits -----------------------------------------
+# -- multi-task (two-level) commits -------------------------------------------
 
 
 class MultiTaskCommit:
-    """Third Merkle level over per-task commit roots.
+    """Task-level Merkle layer over per-task commit roots.
 
     ``commits`` maps ``task_id`` (an arbitrary string; ``None`` names the
-    anonymous single-task legacy path) to that task's commit — a dense
-    ``ShardedCommit`` or an incremental ``DeltaCommit`` (tenants may mix
+    anonymous single-task legacy path) to that task's commit — a
+    ``DenseCommit`` or an incremental ``DeltaCommit`` (tenants may mix
     freely; the task level only consumes each commit's ``root_digest``).
     Task roots combine pairwise bottom-up in canonical (sorted task id)
     order with the interior-node rule into the block root. A record proof
     is the task's own proof followed by the task path — with a single
-    task the root equals the task's super-root and the task path is empty,
+    task the root equals the task's root and the task path is empty,
     so single-task commits are bit-identical to a bare commit. Each
     task's chunk size may differ (heterogeneous tenants)."""
 
@@ -773,7 +668,7 @@ class MultiTaskCommit:
         return self.task_levels[-1][0].hex()
 
     def task_roots(self) -> Dict[Optional[str], str]:
-        """The canonical ``task_id → super-root`` map this commit binds."""
+        """The canonical ``task_id → root`` map this commit binds."""
         return {t: c.root for t, c in self.commits.items()}
 
     def _resolve(self, task_id: Optional[str]) -> Optional[str]:
@@ -793,16 +688,16 @@ class MultiTaskCommit:
 
     def task_path(self, task_id: Optional[str] = None
                   ) -> List[Tuple[str, str]]:
-        """Sibling path from a task's super-root to the block root — the
-        cross-task (third) level of a settlement proof."""
+        """Sibling path from a task's root to the block root — the
+        cross-task level of a settlement proof."""
         tid = self._resolve(task_id)
         return _path_through(self.task_levels[:-1], self.task_ids.index(tid))
 
     def record_proof(self, record_index: int,
                      task_id: Optional[str] = None) -> List[Tuple[str, str]]:
-        """Three-level node path: chunk path inside the record's shard, the
-        shard path to the task's super-root, then the task path to the
-        block root. ``MerkleTree.verify`` consumes it unchanged."""
+        """Two-level node path: chunk path inside the task's tree, then
+        the task path to the block root. ``MerkleTree.verify`` consumes it
+        unchanged."""
         tid = self._resolve(task_id)
         return self.commits[tid].record_proof(record_index) \
             + self.task_path(tid)
@@ -819,9 +714,9 @@ class MultiTaskCommit:
 
     def recompute_root(self) -> str:
         """Block root rebuilt from every task's stored records (deep
-        verification — rebuilds each task's commit from scratch, its
-        super levels, and the cross-task task level; delta commits
-        materialize their overlay chain back to the base first)."""
+        verification — rebuilds each task's commit from scratch and the
+        cross-task level; delta commits materialize their overlay chain
+        back to the base first)."""
         rebuilt = {t: c.rebuild() for t, c in self.commits.items()}
         return MultiTaskCommit(rebuilt).root
 
@@ -833,7 +728,7 @@ class Block:
     transactions: List[dict]
     timestamp: float
     records_root: str = ""    # Merkle root of the batch commit ("" if none)
-    # canonical task_id → super-root map of a multi-task block; None when
+    # canonical task_id → root map of a multi-task block; None when
     # the block commits at most one task (single-task hashes stay stable)
     task_roots: Optional[Dict[str, str]] = None
     hash: str = ""
@@ -858,39 +753,13 @@ class Ledger:
         genesis.hash = genesis.compute_hash()
         self.blocks: List[Block] = [genesis]
         self.work_units: int = 0          # hashing/verification operations done
-        # off-chain data availability: per-block multi-task commit (per-task
-        # batches + shard subtrees + super levels + the task level);
-        # single-task single-shard commits additionally mirror their tree
-        # into _record_trees (the pre-sharding introspection API)
+        # off-chain data availability: per-block multi-task commit (each
+        # task's records + tree, and the task level)
         self._commits: Dict[int, MultiTaskCommit] = {}
-        self._record_trees: Dict[int, MerkleTree] = {}
 
     @property
     def head(self) -> Block:
         return self.blocks[-1]
-
-    @staticmethod
-    def _build_commit(record_batch: Optional[Records],
-                      record_shards: Optional[Sequence[Records]],
-                      shard_trees: Optional[Sequence[MerkleTree]],
-                      chunk_size: int) -> Optional[ShardedCommit]:
-        """One task's sharded commit from either a flat batch or per-shard
-        batches (with optional prebuilt trees); None when empty."""
-        if record_shards is not None:
-            if shard_trees is not None and \
-                    len(shard_trees) != len(record_shards):
-                raise ValueError("one precomputed tree per shard required")
-            # drop empty shards and their trees in lockstep so the
-            # shard↔tree pairing survives the filter
-            keep = [i for i, s in enumerate(record_shards) if len(s)]
-            if keep:
-                return ShardedCommit(
-                    [record_shards[i] for i in keep], chunk_size,
-                    trees=None if shard_trees is None
-                    else [shard_trees[i] for i in keep])
-        elif record_batch is not None and len(record_batch):
-            return ShardedCommit([record_batch], chunk_size)
-        return None
 
     def _seal(self, transactions: List[dict], timestamp: Optional[float],
               commit: Optional[MultiTaskCommit]) -> Block:
@@ -913,38 +782,22 @@ class Ledger:
             # can always resolve block i's proofs without taking any lock,
             # and never makes the settler thread wait.
             self._commits[blk.index] = commit
-            if commit.num_tasks == 1:
-                only = commit.commit_for()
-                if isinstance(only, ShardedCommit) and only.num_shards == 1:
-                    self._record_trees[blk.index] = only.trees[0]
         self.blocks.append(blk)
         return blk
 
     def append_block(self, transactions: List[dict],
                      timestamp: Optional[float] = None,
-                     record_batch: Optional[Records] = None,
-                     chunk_size: int = 1,
-                     record_shards: Optional[Sequence[Records]] = None,
-                     shard_trees: Optional[Sequence[MerkleTree]] = None,
-                     record_delta: Optional[DeltaCommit] = None,
+                     commit: Optional[AnyCommit] = None,
                      task_id: Optional[str] = None) -> Block:
-        """Seal a single-task block. Canonically-encoded per-worker
-        settlement records are Merkle-committed into the block hash via
-        ``records_root`` with ``chunk_size`` records per leaf; the records
+        """Seal a single-task block. A ``commit`` (a ``DenseCommit`` over
+        the round's canonically-encoded per-worker records, or an
+        incremental ``DeltaCommit`` — the sparse path, whose block commits
+        the full population's root while only the dirty paths were
+        hashed) enters the block hash via ``records_root``; the records
         themselves stay off-chain but per-record auditable
-        (``merkle_proof`` / ``record_chunk``). Pass either ``record_batch``
-        (one flat batch), ``record_shards`` (per-shard batches, optionally
-        with their ``shard_trees`` prebuilt in parallel by a settler pool —
-        with subtree-aligned shards both commit the identical root), or
-        ``record_delta`` (a prebuilt incremental ``DeltaCommit`` — the
-        sparse path; the block commits the full population's root while
-        only the dirty paths were hashed). ``task_id`` names the
+        (``merkle_proof`` / ``record_chunk``). ``task_id`` names the
         committing task on a multi-tenant node; block hashes are task-id
         independent for single-task blocks."""
-        commit: Optional[AnyCommit] = record_delta
-        if commit is None:
-            commit = self._build_commit(record_batch, record_shards,
-                                        shard_trees, chunk_size)
         return self._seal(transactions, timestamp,
                           MultiTaskCommit({task_id: commit})
                           if commit is not None else None)
@@ -953,20 +806,19 @@ class Ledger:
                            timestamp: Optional[float],
                            task_commits: Dict[str, AnyCommit]) -> Block:
         """Seal a multi-task block committing several tasks' rounds at
-        once: the canonical ``task_id → super-root`` map enters the block
-        hash (``task_roots``) and the ``records_root`` is the cross-task
+        once: the canonical ``task_id → root`` map enters the block hash
+        (``task_roots``) and the ``records_root`` is the cross-task
         combined root. With exactly one task this is bit-identical to
-        ``append_block`` — co-tenancy, like shard count, only becomes
-        consensus-visible when a block genuinely carries several tasks."""
+        ``append_block`` — co-tenancy only becomes consensus-visible when
+        a block genuinely carries several tasks."""
         commits = {t: c for t, c in task_commits.items() if c is not None}
         return self._seal(transactions, timestamp,
                           MultiTaskCommit(commits) if commits else None)
 
     def verify_chain(self, deep: bool = False) -> bool:
-        """Hash-chain integrity; ``deep=True`` additionally recurses through
-        every stored commit — rebuilding each task's shard subtrees, its
-        cross-shard super-root, and the cross-task task level — against the
-        block commitment (including the ``task_roots`` map)."""
+        """Hash-chain integrity; ``deep=True`` additionally rebuilds every
+        stored commit — each task's tree and the cross-task level — against
+        the block commitment (including the ``task_roots`` map)."""
         prev = self.GENESIS_HASH
         for blk in self.blocks:
             if blk.prev_hash != prev or blk.hash != blk.compute_hash():
@@ -998,7 +850,6 @@ class Ledger:
         removed = self.blocks[block_index + 1:]
         for blk in removed:
             self._commits.pop(blk.index, None)
-            self._record_trees.pop(blk.index, None)
         del self.blocks[block_index + 1:]
         self.work_units += len(removed)
         return removed
@@ -1011,7 +862,7 @@ class Ledger:
         continuity, ``prev_hash`` linkage, full hash recomputation, and —
         when the block commits records — that the shipped commit really
         re-hashes to the block's ``records_root``/``task_roots`` (the
-        tampered-super-root check; ``verify_commit=False`` downgrades it
+        tampered-root check; ``verify_commit=False`` downgrades it
         to a root-equality check for commits already verified upstream).
         Raises ``ValueError`` on any mismatch with nothing applied."""
         if block.index != len(self.blocks):
@@ -1045,10 +896,6 @@ class Ledger:
             # same publication order as _seal: commit registered before
             # the block becomes visible (lock-free read-path contract)
             self._commits[block.index] = commit
-            if commit.num_tasks == 1:
-                only = commit.commit_for()
-                if isinstance(only, ShardedCommit) and only.num_shards == 1:
-                    self._record_trees[block.index] = only.trees[0]
         self.blocks.append(block)
         return block
 
@@ -1076,39 +923,28 @@ class Ledger:
         return list(self._commits[block_index].task_ids)
 
     def task_roots(self, block_index: int) -> Dict[Optional[str], str]:
-        """The block's canonical ``task_id → super-root`` map."""
+        """The block's canonical ``task_id → root`` map."""
         return self._commits[block_index].task_roots()
 
     def record_batch(self, block_index: int,
                      task_id: Optional[str] = None) -> Records:
-        """One task's committed records as one concatenated sequence
-        (shard-agnostic view; single-shard commits return the batch; delta
-        commits return the population-wide overlay view)."""
+        """One task's committed records (delta commits return the
+        population-wide overlay view)."""
         commit = self._commits[block_index].commit_for(task_id)
         if isinstance(commit, DeltaCommit):
             return commit
-        return commit.shards[0] if commit.num_shards == 1 else commit
+        return commit.records
 
     def record_chunk_size(self, block_index: int,
                           task_id: Optional[str] = None) -> int:
         return self._commits[block_index].commit_for(task_id).chunk_size
 
-    def num_shards(self, block_index: int,
-                   task_id: Optional[str] = None) -> int:
-        return self._commits[block_index].commit_for(task_id).num_shards
-
-    def shard_roots(self, block_index: int,
-                    task_id: Optional[str] = None) -> List[str]:
-        """Per-shard subtree roots under a task's super-root."""
-        return self._commits[block_index].commit_for(task_id).shard_roots()
-
     def merkle_proof(self, block_index: int, record_index: int,
                      task_id: Optional[str] = None) -> List[Tuple[str, str]]:
-        """O(log(n/k)) three-level node path — the chunk path inside the
-        record's shard, the shard path to its task's super-root, and the
-        task path to the block root (empty for single-task blocks) — for
-        one settlement record of a batched block; auditing worker w never
-        rehashes the round.
+        """O(log(n/k)) two-level node path — the chunk path inside the
+        task's tree and the task path to the block root (empty for
+        single-task blocks) — for one settlement record of a batched
+        block; auditing worker w never rehashes the round.
 
         Deprecated thin wrapper: the bare path is one field of the typed
         ``settlement_proof`` (property-tested identical to

@@ -9,16 +9,15 @@ typed objects:
 
 ``SettlementProof``
     One record's claim against one block: the leaf chunk, the record's
-    offset within it, the three-level ``(side, digest)`` node path
-    (chunk-in-shard, shard-in-task, task-in-block — exactly the encoding
-    every commit flavor emits), and the committed root. ``verify(head)``
-    checks the whole claim against a trusted head (a ``Block``, a light
-    client's ``BlockHeader``, or a bare root hex string) for every block
-    flavor — dense, ``ShardedCommit``, ``DeltaCommit``, and
-    ``MultiTaskCommit`` blocks all produce the same path encoding. The
-    legacy dict/``verify_settlement`` shapes round-trip losslessly
-    (``as_legacy_dict``/``from_legacy``), so the deprecated wrappers emit
-    bit-identical proofs.
+    offset within it, the two-level ``(side, digest)`` node path
+    (chunk-in-task, task-in-block — exactly the encoding every commit
+    flavor emits), and the committed root. ``verify(head)`` checks the
+    whole claim against a trusted head (a ``Block``, a light client's
+    ``BlockHeader``, or a bare root hex string) for every block flavor —
+    ``DenseCommit``, ``DeltaCommit`` and multi-task blocks all produce the
+    same path encoding. The legacy dict/``verify_settlement`` shapes
+    round-trip losslessly (``as_legacy_dict``/``from_legacy``), so the
+    deprecated wrappers emit bit-identical proofs.
 
 ``ProofBatch``
     A batched multiproof for many records of one task in one block,
@@ -36,16 +35,16 @@ typed objects:
     on.
 
 Wire model: a batch names interior nodes with small structural keys —
-``("S", shard, level, pos)`` inside a shard subtree, ``("U", level, pos)``
-on the cross-shard super levels, ``("T", level, pos)`` on the cross-task
-level, and ``ROOT_KEY`` for the block root. ``plan`` is an ordered list
-of levels whose entries are either ``("h", parent, left, right)`` (hash
-two children) or ``("p", parent, child)`` (odd-node promotion / stage
-alias). The verifier executes the plan level by level; because a node
-value may never be redefined and parent links are only created by actual
-hash/promotion steps, the recomputed root is fully determined by the
-shipped chunks and siblings — there is no way to splice a forged record
-into a verifying batch without a SHA-256 collision.
+``("N", level, pos)`` inside the task's record tree, ``("T", level, pos)``
+on the cross-task level, and ``ROOT_KEY`` for the block root. ``plan`` is
+an ordered list of levels whose entries are either
+``("h", parent, left, right)`` (hash two children) or
+``("p", parent, child)`` (odd-node promotion / stage alias). The verifier
+executes the plan level by level; because a node value may never be
+redefined and parent links are only created by actual hash/promotion
+steps, the recomputed root is fully determined by the shipped chunks and
+siblings — there is no way to splice a forged record into a verifying
+batch without a SHA-256 collision.
 """
 from __future__ import annotations
 
@@ -54,9 +53,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
-from repro.chain.ledger import (_LEAF_PREFIX, _NODE_PREFIX, Block,
-                                DeltaCommit, Ledger, MerkleTree,
-                                RecordBatch, _framed_digests)
+from repro.chain.ledger import (_LEAF_PREFIX, _NODE_PREFIX, Block, Ledger,
+                                MerkleTree, RecordBatch, _framed_digests)
 
 __all__ = ["BlockHeader", "SettlementProof", "ProofBatch", "ROOT_KEY",
            "build_proof_batch", "verify_proof_batch", "header_of",
@@ -137,9 +135,9 @@ class SettlementProof:
         """Check the whole claim against a trusted ``head``: the decoded
         ``record`` view (when present) must match the leaf bytes, the
         chunk must hash to ``root`` through ``path`` (one hashing rule —
-        ``MerkleTree.verify`` — for dense/sharded/delta/multi-task
-        blocks), and ``root`` must equal the head's commitment (with the
-        head's block index matching, when it carries one). Malformed
+        ``MerkleTree.verify`` — for dense/delta/multi-task blocks), and
+        ``root`` must equal the head's commitment (with the head's block
+        index matching, when it carries one). Malformed
         proofs are rejected, never raised on."""
         try:
             if not (isinstance(self.offset, int)
@@ -202,7 +200,7 @@ def build_settlement_proof(ledger: Ledger, block_index: int,
                            task_id: Optional[str] = None,
                            decode=None) -> SettlementProof:
     """The canonical single-record proof builder every wrapper delegates
-    to: chunk + offset + three-level path + committed root, straight off
+    to: chunk + offset + two-level path + committed root, straight off
     the block's stored commit. ``decode`` (optional ``leaf → dict``)
     attaches the decoded record view to the claim."""
     commit = ledger.commit(block_index)
@@ -221,7 +219,7 @@ def build_settlement_proof(ledger: Ledger, block_index: int,
 
 ROOT_KEY: Tuple = ("R",)
 
-NodeKey = Tuple  # ("S", shard, lvl, pos) | ("U", lvl, pos) | ("T", lvl, pos)
+NodeKey = Tuple  # ("N", lvl, pos) | ("T", lvl, pos) | ROOT_KEY
 
 
 @dataclass
@@ -280,7 +278,7 @@ def _walk_levels(levels: Sequence[List[bytes]], active: Dict[int, NodeKey],
     plan: List[List[Tuple]] = []
     cur = dict(active)
     if len(levels) == 1:
-        # single-node stage (one leaf / one shard / one task): the stage's
+        # single-node stage (one leaf / one task): the stage's
         # only node IS its top — alias it so the next stage can consume it
         plan.append([("p", top_key, cur[0])])
         return plan
@@ -319,70 +317,45 @@ def build_proof_batch(ledger: Ledger, block_index: int,
                       worker_ids: Optional[Sequence[int]] = None,
                       round_index: Optional[int] = None) -> ProofBatch:
     """Build one task's deduplicated multiproof for ``record_indices`` in
-    block ``block_index``, resolving through whichever commit flavor the
-    block stored (dense/sharded single tree, incremental ``DeltaCommit``
-    overlay, multi-task third level). Read-only over sealed state — safe
-    to call from reader threads while the settler appends new blocks."""
+    block ``block_index``: the task's record tree (a ``DenseCommit``'s, or
+    a ``DeltaCommit``'s over its population overlay), then the task level.
+    Read-only over sealed state — safe to call from reader threads while
+    the settler appends new blocks."""
     mtc = ledger.commit(block_index)
     blk = ledger.blocks[block_index]
     tid = mtc._resolve(task_id)
     commit = mtc.commits[tid]
     k = commit.chunk_size
-    if isinstance(commit, DeltaCommit):
-        trees = {0: commit.tree}
-        sup: Sequence[List[bytes]] = [[commit.root_digest]]
-
-        def locate(ri: int) -> Tuple[int, int]:
-            if not 0 <= ri < commit.num_records:
-                raise IndexError(f"record index {ri} out of range")
-            return 0, ri
-    else:
-        trees = dict(enumerate(commit.trees))
-        sup = commit.super_levels
-        locate = commit._locate
-
-    shards = getattr(commit, "shards", None)
+    stored = getattr(commit, "records", None)
     chunks: Dict[NodeKey, bytes] = {}
     records: List[Tuple[int, NodeKey, int]] = []
-    by_shard: Dict[int, Dict[int, NodeKey]] = {}
+    leaves: Dict[int, NodeKey] = {}
     record_size = 0
     for ri in record_indices:
         ri = int(ri)
-        s, local = locate(ri)
-        leaf_pos = local // k
-        key = ("S", s, 0, leaf_pos)
+        if not 0 <= ri < len(commit):
+            raise IndexError(f"record index {ri} out of range")
+        leaf_pos = ri // k
+        key = ("N", 0, leaf_pos)
         if key not in chunks:
-            shard = None if shards is None else shards[s]
-            if isinstance(shard, RecordBatch):
+            if isinstance(stored, RecordBatch):
                 # fixed-width contiguous storage: the whole leaf chunk is
                 # one zero-copy buffer slice (the batched-build fast path)
-                stop = min(leaf_pos * k + k, len(shard))
-                chunks[key] = bytes(shard.chunk_bytes(leaf_pos * k, stop))
-                record_size = record_size or shard.itemsize
+                stop = min(leaf_pos * k + k, len(stored))
+                chunks[key] = bytes(stored.chunk_bytes(leaf_pos * k, stop))
+                record_size = record_size or stored.itemsize
             else:
                 chunk_list, off = commit.record_chunk(ri)
                 chunks[key] = b"".join(chunk_list)
                 record_size = record_size or len(chunk_list[off])
-        records.append((ri, key, local % k))
-        by_shard.setdefault(s, {})[leaf_pos] = key
+        records.append((ri, key, ri % k))
+        leaves[leaf_pos] = key
 
     siblings: Dict[NodeKey, str] = {}
-    # shard stages merge level-aligned: level l of every involved shard
-    # lands in one plan level (they are independent, and the verifier
-    # hashes each plan level in a single framed pass)
-    plan: List[List[Tuple]] = []
-    for s in sorted(by_shard):
-        stage = _walk_levels(trees[s].levels, by_shard[s],
-                             lambda lvl, pos, s=s: ("S", s, lvl, pos),
-                             ("U", 0, s), siblings)
-        for i, entries in enumerate(stage):
-            if i == len(plan):
-                plan.append([])
-            plan[i].extend(entries)
     tpos = mtc.task_ids.index(tid)
-    plan += _walk_levels(sup, {s: ("U", 0, s) for s in by_shard},
-                         lambda lvl, pos: ("U", lvl, pos),
-                         ("T", 0, tpos), siblings)
+    plan = _walk_levels(commit.tree.levels, leaves,
+                        lambda lvl, pos: ("N", lvl, pos),
+                        ("T", 0, tpos), siblings)
     plan += _walk_levels(mtc.task_levels, {tpos: ("T", 0, tpos)},
                          lambda lvl, pos: ("T", lvl, pos),
                          ROOT_KEY, siblings)

@@ -251,23 +251,6 @@ class FederationConfig:
     pipeline_depth: int = 2                 # pending rounds the background
                                             # settler may hold (0 = settle
                                             # inline on the training thread)
-    settlement_shards: int = 1              # contract shards per round: slices
-                                            # settle + hash their own Merkle
-                                            # subtree in parallel under one
-                                            # cross-shard super-root (subtree-
-                                            # aligned, so block hashes are
-                                            # shard-count independent)
-    settler_pool_size: int = 0              # shard-worker threads draining the
-                                            # per-shard queues (0 = auto:
-                                            # min(settlement_shards, cpus),
-                                            # spawned only when the leaf-size
-                                            # gate could feed them; an explicit
-                                            # size forces the spawn; effective
-                                            # only with pipeline_depth > 0 and
-                                            # shards > 1). On a multi-tenant
-                                            # ChainNode the pool is shared:
-                                            # node-level sizing takes the max
-                                            # shard count across tasks
     sparse_settlement: bool = False         # settle rounds as incremental
                                             # DeltaCommits over the full
                                             # population: only the round's

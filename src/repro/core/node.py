@@ -6,8 +6,7 @@ infrastructure: many collaborative learning tasks settle on the same
 chain. This module is that substrate, split into two layers:
 
 ``ChainNode`` owns the chain-side singletons — the ``Ledger``, the
-``IPFSStore``, one shared ``ShardWorkerPool`` of shard-hashing threads,
-and the cross-task settlement scheduler (``_SettlerPool``). A per-task
+``IPFSStore``, and the settler thread (``_Settler``). A per-task
 ``FederatedTask`` handle owns everything task-scoped: model/optimizer
 state, the jitted round function, its ``TrustContract`` (deployed on the
 node's ledger under its ``task_id``), reputation, cluster exchange, and
@@ -18,28 +17,28 @@ Ticks and blocks. The node is driven in *ticks*: ``run_tick(batches)``
 runs one round for every task that fires this tick (tasks may run at
 independent, asynchronous cadences — simply omit a task from a tick), and
 all rounds of one tick settle into ONE block committing the canonical
-``task_id → super-root`` map (``MultiTaskCommit`` in ``chain.ledger``).
-Settlement proofs are three-level — chunk-in-shard, shard-in-task,
-task-in-block — and ``verify_chain(deep=True)`` recurses through tasks.
-A tick in which a single task fires seals a bit-identical block to the
-single-tenant driver (no ``task_roots`` in the hashed body, no ``task``
-tag on transactions), so an N=1 node reproduces the PR-3 sharded driver's
-chain byte for byte (property-tested).
+``task_id → root`` map (``MultiTaskCommit`` in ``chain.ledger``).
+Settlement proofs are two-level — chunk-in-task, task-in-block — and
+``verify_chain(deep=True)`` recurses through tasks. A tick in which a
+single task fires seals a bit-identical block to the single-tenant driver
+(no ``task_roots`` in the hashed body, no ``task`` tag on transactions),
+so an N=1 node reproduces the single-task chain byte for byte
+(property-tested).
 
-Fairness and determinism. Within a tick, tasks are processed in canonical
-(sorted ``task_id``) order and their contract-shard thunks are interleaved
-round-robin — shard 0 of every task, then shard 1, … — through the shared
-pool, so no task's settlement starves behind a bigger co-tenant. Ticks
-drain FIFO through a bounded queue (``pipeline_depth``), so every
-submitted round settles within its tick: ordering is seed-reproducible
-and starvation-free by construction. Each task's round-r head rotation
+Determinism. The settler thread settles each tick in one pass: every
+firing task's round is computed in canonical (sorted ``task_id``) order,
+the survivors are applied, and one block is sealed. Ticks drain FIFO
+through a bounded queue (``pipeline_depth``), so every submitted round
+settles within its tick: ordering is seed-reproducible and
+starvation-free by construction. Each task's round-r head rotation
 consumes the head of the block that settled *its own* round r−1
 (published per (task, round) by the scheduler), never the racy live
 chain head.
 
-Failure isolation. A failing shard aborts only its own task's round:
-shard thunks are pure, so the failing task's state and commit are simply
-excluded from the tick's block while co-tenant tasks settle normally.
+Failure isolation. A task whose round computation fails loses only that
+round: the computation is pure, so the failing task's state and commit
+are simply excluded from the tick's block while co-tenant tasks settle
+normally.
 The failure is sticky *per task* — the task's later queued rounds are
 drained and discarded, and every subsequent interaction with that task
 raises a ``TaskSettlementError`` carrying the failing ``task_id`` and
@@ -71,7 +70,6 @@ elections).
 from __future__ import annotations
 
 import heapq
-import os
 import queue
 import threading
 import time
@@ -83,7 +81,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.chain.contract import RoundPrep, ShardSettlement, TrustContract
+from repro.chain.contract import RoundSettlement, TrustContract
 from repro.chain.ipfs import IPFSStore
 from repro.chain.ledger import Ledger
 from repro.configs.base import FederationConfig, ModelConfig, TrainConfig
@@ -179,112 +177,6 @@ class _StartedRound:
     spans: Dict[str, float] = field(default_factory=dict)
 
 
-class ShardWorkerPool:
-    """N shard-worker threads, each draining its own task queue.
-
-    ``map`` fans one batch of shard thunks out — thunk i always lands on
-    queue i mod N, so with the node's round-robin interleave consecutive
-    thunks (= different tasks' shards) spread across workers and a given
-    slot stays FIFO across rounds — and blocks at the merge barrier until
-    every thunk finished, then re-raises the lowest-index failure
-    (deterministic, whichever thread hit it first). ``map_collect``
-    returns per-thunk ``("ok", value)`` / ``("err", exc)`` outcomes
-    instead of raising, which is what lets a multi-task node fail one
-    task's shards without discarding its co-tenants' results. Thunks must
-    be pure compute (the contract's ``settle_shard`` mutates nothing), so
-    dropping a failed task's sibling results is safe.
-
-    Workers hold only a weak reference to the pool and wake periodically
-    while idle, so an abandoned (never-finalized) node's shard threads
-    exit instead of living for the rest of the process."""
-
-    _IDLE_POLL_S = 2.0
-
-    def __init__(self, num_threads: int) -> None:
-        self.num_threads = max(1, int(num_threads))
-        self._queues: List["queue.Queue"] = [queue.Queue()
-                                             for _ in range(self.num_threads)]
-        self._stopped = False
-        ref = weakref.ref(self)
-        self._threads = [
-            threading.Thread(target=self._work, args=(q, ref), daemon=True,
-                             name=f"sdflb-shard-worker-{i}")
-            for i, q in enumerate(self._queues)]
-        for t in self._threads:
-            t.start()
-
-    @staticmethod
-    def _work(q: "queue.Queue", pool_ref: "weakref.ref") -> None:
-        while True:
-            try:
-                item = q.get(timeout=ShardWorkerPool._IDLE_POLL_S)
-            except queue.Empty:
-                if pool_ref() is None:         # owner got collected
-                    return
-                continue
-            if item is None:                   # stop sentinel
-                return
-            fn, i, out, cv, remaining = item
-            try:
-                out[i] = ("ok", fn())
-            except BaseException as e:
-                out[i] = ("err", e)
-            finally:
-                del fn, item                   # don't pin results while idle
-                with cv:
-                    remaining[0] -= 1
-                    cv.notify_all()
-
-    def start_collect(self, thunks):
-        """Enqueue ``thunks[i]`` on worker i mod N and return immediately
-        with a handle for ``finish_collect`` — lets the caller overlap its
-        own work with the pool's."""
-        if self._stopped:
-            raise RuntimeError("shard pool already stopped")
-        thunks = list(thunks)
-        out: list = [None] * len(thunks)
-        cv = threading.Condition()
-        remaining = [len(thunks)]
-        for i, fn in enumerate(thunks):
-            self._queues[i % self.num_threads].put((fn, i, out, cv,
-                                                    remaining))
-        return out, cv, remaining
-
-    @staticmethod
-    def finish_collect(handle) -> list:
-        """Block at the merge barrier of a ``start_collect`` handle; return
-        the in-order list of per-thunk outcomes ``("ok", value)`` /
-        ``("err", exception)`` (never raises for a thunk failure)."""
-        out, cv, remaining = handle
-        with cv:
-            cv.wait_for(lambda: remaining[0] == 0)
-        return out
-
-    def map_collect(self, thunks) -> list:
-        """``start_collect`` + ``finish_collect`` in one call."""
-        return self.finish_collect(self.start_collect(thunks))
-
-    def map(self, thunks) -> list:
-        """Like ``map_collect`` but returns the bare results, raising the
-        first (by index) failure after all thunks finished."""
-        out = self.map_collect(thunks)
-        for tag, val in out:
-            if tag == "err":
-                raise val
-        return [val for _, val in out]
-
-    def stop(self) -> None:
-        """Terminate the workers (idempotent); outstanding queue items run
-        first since the sentinel sits behind them."""
-        if self._stopped:
-            return
-        self._stopped = True
-        for q in self._queues:
-            q.put(None)
-        for t in self._threads:
-            t.join()
-
-
 # -- cross-task block settlement ----------------------------------------------
 
 
@@ -301,148 +193,52 @@ class TaskRoundWork:
     staleness: Optional[np.ndarray] = None   # aligned with scores
 
 
-def _interleave_shard_thunks(task_order: List[str],
-                             preps: Dict[str, RoundPrep]
-                             ) -> List[Tuple[str, int, Callable]]:
-    """Round-robin schedule across tasks: shard 0 of every task (in
-    canonical task order), then shard 1, … — the fairness rule that keeps
-    a small task's settlement from starving behind a big co-tenant."""
-    sched: List[Tuple[str, int, Callable]] = []
-    depth = 0
-    while True:
-        layer = [(tid, depth, preps[tid].thunks[depth])
-                 for tid in task_order if depth < len(preps[tid].thunks)]
-        if not layer:
-            return sched
-        sched.extend(layer)
-        depth += 1
-
-
 def settle_tasks_block(ledger: Ledger, work: List[TaskRoundWork],
-                       timestamp: Optional[float] = None,
-                       pool: Optional[ShardWorkerPool] = None
+                       timestamp: Optional[float] = None
                        ) -> Tuple[Optional[Any], Dict[str, np.ndarray],
                                   Dict[str, BaseException]]:
-    """Settle several tasks' rounds into ONE multi-task block.
+    """Settle several tasks' rounds into ONE multi-task block, in one pass:
+    compute every task's round (canonical task order), apply the
+    survivors, and seal one block committing each surviving task's root
+    under the canonical ``task_id → root`` map.
 
-    Per task: prepare (validation + pure shard thunks) → shard fan-out →
-    deterministic merge → one shared block seal committing every surviving
-    task's super-root under the canonical ``task_id → super-root`` map.
-    Shard thunks of tasks whose leaves clear the contract's GIL gate are
-    interleaved round-robin through the shared ``pool`` (deterministic
-    results either way — the pool only changes who hashes); the rest run
-    inline on the calling thread.
-
-    Shard re-planning: the node owns the fan-out budget. When N tasks
-    share the pool, each pooled task's shard count is re-planned to
-    ``min(its settlement_shards, ceil(2·pool_threads / N))`` so the total
-    thunk count stays matched to the pool — cross-task parallelism
-    replaces within-task parallelism as N grows, instead of N·S micro
-    thunks convoying on the GIL. This is consensus-invisible: shard
-    boundaries are subtree-aligned, so the committed super-roots, proofs,
-    and block hashes are identical for every execution granularity
-    (property-tested).
-
-    Failure isolation: a task failing in prepare or in any of its shard
-    thunks is excluded from the block with *nothing* of it applied or
-    committed (shard thunks are pure; its merge never runs), while the
-    surviving tasks settle normally. Returns ``(block, penalties_by_task,
-    errors_by_task)`` — ``block`` is None when no task survived. With one
-    task in ``work`` the sealed block is bit-identical to that task's
-    ``settle_round_batch``. Only a failure of the shared seal itself
-    raises (node-fatal)."""
+    Failure isolation: a task whose round computation raises is excluded
+    from the block with *nothing* of it applied or committed (the
+    computation is pure), while the surviving tasks settle normally.
+    Returns ``(block, penalties_by_task, errors_by_task)`` — ``block`` is
+    None when no task survived. With one task in ``work`` the sealed
+    block is bit-identical to that task's ``settle_round_batch``. Only a
+    failure of the shared seal itself raises (node-fatal)."""
     work = sorted(work, key=lambda w: w.task_id)
     if len({w.task_id for w in work}) != len(work):
         raise ValueError("duplicate task_id in one settlement block")
     errors: Dict[str, BaseException] = {}
-    preps: Dict[str, RoundPrep] = {}
-    results: Dict[str, List[ShardSettlement]] = {}
-    pooled: List[str] = []
-    inline: List[str] = []
-    # fan-out budget: tasks that want the pool split ~2 thunks per worker
-    # thread between them (consensus-invisible — see the docstring)
-    pool_wanting = [w.task_id for w in work
-                    if pool is not None
-                    and w.contract.settlement_shards > 1
-                    and w.contract.parallel_leaf_ok()]
-    eff_shards: Dict[str, int] = {}
-    if pool_wanting:
-        per = max(1, -(-2 * pool.num_threads // len(pool_wanting)))
-        for w in work:
-            if w.task_id in pool_wanting:
-                eff_shards[w.task_id] = min(w.contract.settlement_shards,
-                                            per)
+    computed: Dict[str, RoundSettlement] = {}
     for w in work:
         try:
-            preps[w.task_id] = w.contract.prepare_round_batch(
-                w.round_index, w.scores, w.worker_ids,
-                shards=eff_shards.get(w.task_id),
-                staleness=w.staleness)
+            computed[w.task_id] = w.contract.compute_round(
+                w.round_index, w.scores, w.worker_ids, staleness=w.staleness)
         except BaseException as e:
             errors[w.task_id] = e
-            continue
-        if w.task_id in eff_shards:
-            pooled.append(w.task_id)   # even a 1-thunk task: parallel
-        else:                          # ACROSS tasks through the pool
-            inline.append(w.task_id)
 
-    # enqueue the pooled fan-out first, run the inline tasks' thunks on
-    # the calling thread while the workers hash, then collect at the merge
-    # barrier: tick latency is max(pool, inline), not their sum
-    sched = _interleave_shard_thunks(pooled, preps) if pooled else []
-    handle = pool.start_collect([t for _, _, t in sched]) if sched else None
-    for tid in inline:
-        try:
-            results[tid] = [t() for t in preps[tid].thunks]
-        except BaseException as e:
-            errors[tid] = e
-    if handle is not None:
-        out = pool.finish_collect(handle)
-        shard_res: Dict[str, List[Optional[ShardSettlement]]] = {
-            tid: [None] * len(preps[tid].thunks) for tid in pooled}
-        shard_err: Dict[str, Tuple[int, BaseException]] = {}
-        for (tid, i, _), (tag, val) in zip(sched, out):
-            if tag == "ok":
-                shard_res[tid][i] = val
-            elif tid not in shard_err or i < shard_err[tid][0]:
-                shard_err[tid] = (i, val)      # lowest-shard-index failure
-        for tid in pooled:
-            if tid in shard_err:
-                errors[tid] = shard_err[tid][1]
-            else:
-                results[tid] = shard_res[tid]
-
-    survivors = [w for w in work if w.task_id in results]
-    penalties: Dict[str, np.ndarray] = {}
-    seals = {}
-    for w in survivors:
-        seal = w.contract.finish_round_batch(
-            preps[w.task_id], results[w.task_id], model_cid=w.model_cid)
-        seals[w.task_id] = seal
-        penalties[w.task_id] = seal.penalties
+    survivors = [w for w in work if w.task_id in computed]
+    seals = {w.task_id: w.contract.apply_round(computed[w.task_id],
+                                               model_cid=w.model_cid)
+             for w in survivors}
+    penalties = {tid: seal.penalties for tid, seal in seals.items()}
     if not seals:
         return None, penalties, errors
     if len(seals) == 1:
         # single-task tick: the exact single-tenant block layout (no task
         # tags, no task_roots map) — bit-identical to settle_round_batch
         (tid, seal), = seals.items()
-        blk = ledger.append_block(
-            seal.txs, timestamp=timestamp,
-            record_shards=seal.shards or None,
-            shard_trees=seal.trees or None,
-            record_delta=seal.delta,
-            chunk_size=seal.chunk_size, task_id=tid)
+        blk = ledger.append_block(seal.txs, timestamp=timestamp,
+                                  commit=seal.commit, task_id=tid)
     else:
         txs = [{**tx, "task": tid}
                for tid, seal in seals.items() for tx in seal.txs]
-        # a sparse task contributes its prebuilt incremental commit;
-        # dense co-tenants build theirs from the shard parts as before
-        commits = {tid: seal.delta if seal.delta is not None
-                   else Ledger._build_commit(None, seal.shards or None,
-                                             seal.trees or None,
-                                             seal.chunk_size)
-                   for tid, seal in seals.items()}
-        blk = ledger.append_multi_block(txs, timestamp, commits)
+        blk = ledger.append_multi_block(
+            txs, timestamp, {tid: seal.commit for tid, seal in seals.items()})
     # O(1) integrity check of the block just sealed (linkage + recomputed
     # hash) — a full verify_chain here would be O(R^2) over a run
     if blk.prev_hash != ledger.blocks[blk.index - 1].hash \
@@ -450,7 +246,8 @@ def settle_tasks_block(ledger: Ledger, work: List[TaskRoundWork],
         raise RuntimeError(f"block {blk.index} failed verification "
                            f"after sealing tick settlement")
     for w in survivors:
-        w.contract.note_block(w.round_index, preps[w.task_id].ids, blk.index)
+        w.contract.note_block(w.round_index, computed[w.task_id].ids,
+                              blk.index)
     return blk, penalties, errors
 
 
@@ -461,12 +258,11 @@ _FATAL_NOTE = ("chain node settlement failed; the settler has stopped "
                "(unsettled rounds were discarded)")
 
 
-class _SettlerPool:
-    """Background cross-task settlement scheduler: a coordinator daemon
-    thread consuming a bounded FIFO queue of pending *ticks*, settling
-    each tick's tasks through ``ChainNode._settle_tick`` (which fans every
-    task's contract shards round-robin through the shared
-    ``ShardWorkerPool`` and seals one block at the merge barrier), and
+class _Settler:
+    """Background cross-task settlement scheduler: one daemon thread
+    consuming a bounded FIFO queue of pending *ticks*, settling each
+    tick's tasks through ``ChainNode._settle_tick`` (one pass: every
+    task's round computed, the survivors applied, one block sealed), and
     publishing the resulting chain head per (task, round).
 
     The training thread interacts through ``submit`` (the queue handoff —
@@ -508,7 +304,7 @@ class _SettlerPool:
         if self._threaded:
             self._q: "queue.Queue" = queue.Queue(maxsize=depth)
             self._thread = threading.Thread(target=self._loop, daemon=True,
-                                            name="sdflb-settler-coordinator")
+                                            name="sdflb-settler")
             self._thread.start()
 
     def register_task(self, task_id: str,
@@ -660,7 +456,7 @@ class _SettlerPool:
 
     def stop(self) -> None:
         """Drain best-effort (never raises), then terminate the
-        coordinator (idempotent)."""
+        thread (idempotent)."""
         with self._cv:
             self._cv.wait_for(lambda: self._settled_tick
                               >= self._submitted_tick
@@ -751,7 +547,6 @@ class FederatedTask:
                 worker_stake=fed.worker_stake, penalty_pct=fed.penalty_pct,
                 trust_threshold=fed.trust_threshold, top_k=fed.top_k_rewarded,
                 merkle_chunk_size=fed.merkle_chunk_size,
-                settlement_shards=fed.settlement_shards,
                 sparse_settlement=fed.sparse_settlement,
                 sparse_rebase_every=fed.sparse_rebase_every,
                 staleness_alpha=(fed.staleness_alpha if fed.async_mode
@@ -973,12 +768,12 @@ class FederatedTask:
 class ChainNode:
     """One chain node serving N concurrent federated tasks on one ledger.
 
-    Owns the shared chain substrate — ``Ledger``, ``IPFSStore``, one
-    ``ShardWorkerPool``, and the cross-task settlement scheduler — while
+    Owns the shared chain substrate — ``Ledger``, ``IPFSStore``, and the
+    cross-task settlement scheduler — while
     per-task state lives in ``FederatedTask`` handles registered through
     ``create_task``. Drive with ``run_tick({task_id: batch, ...})``; tasks
     run at independent cadences by simply not firing every tick. See the
-    module docstring for the tick/block layout, fairness, and failure
+    module docstring for the tick/block layout, determinism, and failure
     isolation rules.
 
     Read path (``read_server()``): proof serving is lock-free by design,
@@ -994,11 +789,9 @@ class ChainNode:
 
     def __init__(self, *, use_blockchain: bool = True,
                  pipeline_depth: int = 2,
-                 settler_pool_size: int = 0,
                  ipfs_owner_quota_bytes: int = 0) -> None:
         self.use_blockchain = use_blockchain
         self.pipeline_depth = pipeline_depth
-        self.settler_pool_size = settler_pool_size
         self.ledger = Ledger() if use_blockchain else None
         # per-owner (task) byte quota on the shared artifact store: a
         # tenant publishing past it fails its own rounds (QuotaExceeded
@@ -1015,14 +808,7 @@ class ChainNode:
         # re-draws an event
         self._event_frontier: Dict[
             str, Tuple[float, np.ndarray, np.ndarray]] = {}
-        # shard workers spawn lazily at task registration, only when some
-        # task's settlement is sharded, the driver is threaded, and the
-        # contract's leaf-size gate could ever feed them (an explicit
-        # settler_pool_size forces the spawn) — the shard *partition* (and
-        # hence every block hash) is identical either way, the pool only
-        # changes who hashes it
-        self._shard_pool: Optional[ShardWorkerPool] = None
-        self._settler = _SettlerPool(self._settle_tick, pipeline_depth)
+        self._settler = _Settler(self._settle_tick, pipeline_depth)
         # seal-broadcast hooks (repro.net): called with each freshly
         # sealed block + its commit, on the settler thread
         self._seal_listeners: List[Callable] = []
@@ -1058,27 +844,7 @@ class ChainNode:
         self._settler.register_task(
             task_id, self.ledger.head.hash if self.ledger is not None
             else None)
-        self._maybe_spawn_pool(task)
         return task
-
-    def _maybe_spawn_pool(self, task: FederatedTask) -> None:
-        if self.pipeline_depth <= 0 or task.contract is None \
-                or task.fed.settlement_shards <= 1:
-            return
-        size = self.settler_pool_size or min(
-            max(t.fed.settlement_shards for t in self.tasks.values()),
-            os.cpu_count() or 1)
-        if size <= 1 or not (self.settler_pool_size > 0
-                             or task.contract.parallel_fanout_possible()):
-            return
-        if self._shard_pool is None or self._shard_pool.num_threads < size:
-            # drain in-flight ticks before swapping the pool the scheduler
-            # reads (cheap: no-op unless a later task registration grows it
-            # mid-run)
-            self._settler.flush(check=None)
-            old, self._shard_pool = self._shard_pool, ShardWorkerPool(size)
-            if old is not None:
-                old.stop()
 
     @property
     def task_errors(self) -> Dict[str, Tuple[int, BaseException]]:
@@ -1103,7 +869,7 @@ class ChainNode:
         maps block index → ``MultiTaskCommit`` for blocks that commit
         records (shipped alongside the block over the wire). Each block
         is verified on receipt by ``Ledger.adopt_block`` (linkage, hash
-        recomputation, commit super-root). Returns how many blocks were
+        recomputation, commit root). Returns how many blocks were
         adopted. Per-contract account state is *not* replayed here —
         that is ``repro.net.SettlementNode``'s job; this hook is for
         proof-serving replicas that track a remote chain."""
@@ -1124,7 +890,7 @@ class ChainNode:
         handshakes, batched settlement-proof fetch, and checkpoint
         streaming for light clients, served lock-free off the published
         chain state (see the class docstring's read-path invariants) while
-        the ``_SettlerPool`` keeps sealing."""
+        the ``_Settler`` keeps sealing."""
         from repro.serve import ChainReadServer
         return ChainReadServer(self, **kwargs)
 
@@ -1300,8 +1066,7 @@ class ChainNode:
             # logical timestamp: every node (and the serial reference
             # driver) seals byte-identical blocks for the same tick
             blk, pens, errors = settle_tasks_block(
-                self.ledger, work, timestamp=float(tp.tick + 1),
-                pool=self._shard_pool)
+                self.ledger, work, timestamp=float(tp.tick + 1))
             for listener in self._seal_listeners:
                 listener(blk, self.ledger._commits.get(blk.index))
             for (task, p, settle), w in zip(live, work):
@@ -1358,10 +1123,7 @@ class ChainNode:
         return payouts
 
     def close(self) -> None:
-        """Stop the scheduler and shard workers (drains best-effort,
-        never raises; idempotent)."""
+        """Stop the scheduler (drains best-effort, never raises;
+        idempotent)."""
         self._closed = True
         self._settler.stop()
-        if self._shard_pool is not None:
-            self._shard_pool.stop()
-            self._shard_pool = None

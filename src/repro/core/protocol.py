@@ -5,10 +5,10 @@ lives).
 Historically this module held the whole host-level driver: enrollment +
 staking, the jitted ``fl_step`` dispatch, trust scoring + on-chain
 settlement, IPFS publication, head rotation from on-chain randomness,
-the background settler pool, and the sharded Merkle commits. The
+the background settler, and the chunked Merkle commits. The
 multi-tenant refactor carved that into two layers — ``ChainNode`` (the
-shared chain substrate: ledger, IPFS store, shard worker pool, cross-task
-settlement scheduler) and ``FederatedTask`` (everything task-scoped) —
+shared chain substrate: ledger, IPFS store, the settler thread) and
+``FederatedTask`` (everything task-scoped) —
 because the paper's blockchain is shared infrastructure: many federated
 tasks settle on one chain.
 
@@ -16,10 +16,10 @@ tasks settle on one chain.
 private node with exactly one task: ``run_round`` is a one-task
 ``run_tick``, and every attribute of the old protocol (``ledger``,
 ``contract``, ``history``, ``heads``, ``reputation``, ``global_params``,
-``_shard_pool``, …) resolves onto the task or the node. With one task,
-every block hash, proof, election, penalty, and payout is bit-identical
-to the pre-refactor sharded driver — the single-task tick seals the exact
-single-tenant block layout (property-tested in
+``ipfs``, …) resolves onto the task or the node. With one task, every
+block hash, proof, election, penalty, and payout is bit-identical to the
+pre-refactor driver — the single-task tick seals the exact single-tenant
+block layout (property-tested in
 ``tests/test_multi_task_node.py`` and pinned by the serial-vs-threaded
 equivalence tests).
 
@@ -61,12 +61,10 @@ import numpy as np
 from repro.configs.base import FederationConfig, ModelConfig, TrainConfig
 # re-exports: these classes lived here before the multi-tenant refactor
 from repro.core.node import (ChainNode, FederatedTask, RoundRecord,
-                             ShardWorkerPool, TaskSettlementError,
-                             _PendingRound, _SettlerPool)
+                             TaskSettlementError)
 
 __all__ = ["SDFLBProtocol", "ChainNode", "FederatedTask", "RoundRecord",
-           "ShardWorkerPool", "TaskSettlementError", "_PendingRound",
-           "_SettlerPool"]
+           "TaskSettlementError"]
 
 
 class SDFLBProtocol:
@@ -83,7 +81,6 @@ class SDFLBProtocol:
                  arrival_profiles=None) -> None:
         self._node = ChainNode(use_blockchain=use_blockchain,
                                pipeline_depth=fed.pipeline_depth,
-                               settler_pool_size=fed.settler_pool_size,
                                ipfs_owner_quota_bytes=ipfs_owner_quota_bytes)
         self._task = self._node.create_task(
             fed.task_id, cfg, fed, tc, seed=seed, adversary=adversary,
@@ -92,7 +89,7 @@ class SDFLBProtocol:
 
     # everything the old monolithic protocol exposed lives on the task
     # (model/contract/history/reputation/...) or the node (ledger/ipfs/
-    # _shard_pool/...) — resolve attribute reads AND writes there, task
+    # ...) — resolve attribute reads AND writes there, task
     # first, so post-construction tweaks like `proto.fed = replace(...)`
     # or `proto.adversary = fn` keep reaching the state the driver reads
     def __getattr__(self, name: str):
@@ -166,5 +163,5 @@ class SDFLBProtocol:
     def finalize(self) -> Dict[str, float]:
         payouts = self._task.finalize(
             timestamp=float(len(self._task.history) + 1))
-        self._node.close()         # stops the settler and shard workers
+        self._node.close()         # stops the settler thread
         return payouts

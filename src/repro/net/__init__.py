@@ -4,7 +4,7 @@ The paper's semi-decentralized layer, made multi-*node*: several chain
 replicas (one per cluster head) gossip scores, cluster aggregates, and
 sealed blocks over a deterministic simulated transport, agree via
 longest-valid-chain fork choice with a cumulative-trust tiebreak, and
-punish head misbehavior (equivocation, tampered super-roots) with
+punish head misbehavior (equivocation, tampered record roots) with
 on-chain evidence and stake slashes.
 
 Layers:

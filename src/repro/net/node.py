@@ -45,7 +45,7 @@ Determinism: scores come from a seeded per-round generator shared by
 all honest nodes, blocks are sealed at logical timestamps
 (``float(round+1)``), and non-proposers apply settlement records with
 the *same vectorized numpy ops in the same id order* as the proposer's
-``finish_round_batch`` — so replica contract state is bit-equal to the
+``apply_round`` — so replica contract state is bit-equal to the
 proposer's, and to a from-scratch replay of the winning chain
 (``replay_chain``), which the property tests assert byte-for-byte.
 """
@@ -59,8 +59,8 @@ import numpy as np
 from repro.chain.contract import (_RECORD_DTYPE, TrustContract,
                                   encode_settlement_records)
 from repro.chain.ipfs import IPFSStore
-from repro.chain.ledger import (Block, DeltaCommit, Ledger, MultiTaskCommit,
-                                RecordBatch, ShardedCommit)
+from repro.chain.ledger import (Block, DeltaCommit, DenseCommit, Ledger,
+                                MultiTaskCommit, RecordBatch)
 from repro.core.gossip import ClusterExchange
 from repro.net.fork_choice import BlockTree, seal_info
 from repro.net.sim import LinkSpec, Partition, SimNet
@@ -156,7 +156,7 @@ def head_worker(round_index: int, proposer: int, workers_per_node: int) -> int:
 def settlement_records(commit: MultiTaskCommit,
                        round_index: int) -> np.ndarray:
     """The round's settlement record rows out of a block commit. Dense
-    (``ShardedCommit``) commits must be entirely this round's rows;
+    (``DenseCommit``) commits must be entirely this round's rows;
     sparse (``DeltaCommit``) commits are the full population overlay, so
     the round's changed rows are filtered out by their round stamp."""
     c = commit.commit_for(None)
@@ -164,8 +164,7 @@ def settlement_records(commit: MultiTaskCommit,
         batch = c.materialize()
         rows = np.frombuffer(batch.buf, _RECORD_DTYPE)
         return rows[rows["round"] == round_index]
-    rows = np.concatenate([np.frombuffer(s.buf, _RECORD_DTYPE)
-                           for s in c.shards])
+    rows = np.frombuffer(c.records.buf, _RECORD_DTYPE)
     if not (rows["round"] == round_index).all():
         raise ValueError("commit contains rows from a foreign round")
     return rows
@@ -177,7 +176,7 @@ def apply_block_state(contract: TrustContract, block: Block,
                       workers_per_node: int) -> None:
     """Apply one adopted block's settlement records + evidence to a
     replica contract — the same vectorized transitions, in the same id
-    order, as the proposer's ``finish_round_batch``, so replica state is
+    order, as the proposer's ``apply_round``, so replica state is
     bit-equal to the sealing node's."""
     info = seal_info(block)
     if info is None:
@@ -794,8 +793,8 @@ class EquivocatingNode(SettlementNode):
         stake_after = stake_before - pen
         records = encode_settlement_records(round_index, ids, s, pen,
                                             stake_after)
-        commit = MultiTaskCommit({None: ShardedCommit(
-            [records], self.contract.merkle_chunk_size)})
+        commit = MultiTaskCommit({None: DenseCommit(
+            records, self.contract.merkle_chunk_size)})
         txs = []
         for tx in blk.transactions:
             if isinstance(tx, dict) and tx.get("type") == "seal":
@@ -816,7 +815,7 @@ class TamperingNode(SettlementNode):
     """A byzantine head that seals an honest block but gossips it with a
     *tampered commit* — settlement records inflating its own head
     worker's post-round stake. Receivers catch the mismatch in semantic
-    validation (the super-root check on receipt) and slash it."""
+    validation (the root check on receipt) and slash it."""
 
     def maybe_propose(self, round_index: int,
                       rank_slot: int) -> Optional[Block]:
@@ -834,8 +833,8 @@ class TamperingNode(SettlementNode):
         mask = rec["worker"] == me
         rec["stake_after"] = np.where(mask, rec["stake_after"] + 5.0,
                                       rec["stake_after"])
-        forged = MultiTaskCommit({None: ShardedCommit(
-            [RecordBatch(memoryview(rec).cast("B"), _RECORD_DTYPE.itemsize)],
+        forged = MultiTaskCommit({None: DenseCommit(
+            RecordBatch(memoryview(rec).cast("B"), _RECORD_DTYPE.itemsize),
             self.contract.merkle_chunk_size)})
         self.net.broadcast(self.node_id, BlockGossip(blk, forged))
         return blk

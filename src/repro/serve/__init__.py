@@ -15,8 +15,8 @@ serves three things, all lock-free against the node's settler threads:
   token or exactly the header delta it is missing;
 * batched settlement proofs (``get_proofs``): one deduplicated Merkle
   multiproof per ``(task, round, worker_ids)`` request, resolving
-  through every commit flavor the chain produces (dense, sharded,
-  delta-overlay, multi-task) — adjacent workers share all but
+  through every commit flavor the chain produces (dense, delta-overlay,
+  multi-task) — adjacent workers share all but
   O(log(W/k)) sibling digests;
 * content-addressed checkpoint streaming (``checkpoint_manifest`` /
   ``checkpoint_chunk``): bounded byte-range reads of published model

@@ -280,6 +280,37 @@ def test_settle_round_batch_validates_inputs():
         c.settle_round_batch(1, np.zeros(4))
 
 
+@pytest.mark.parametrize("sparse", [False, True])
+def test_compute_round_mutates_nothing(sparse):
+    """``compute_round`` is pure: contract state, pending transactions and
+    the chain are unchanged until ``apply_round`` — what lets a failing
+    task drop out of a multi-task block with nothing applied."""
+    led = Ledger()
+    c = TrustContract(led, requester_deposit=100.0, worker_stake=10.0,
+                      penalty_pct=50.0, trust_threshold=0.5, top_k=2,
+                      merkle_chunk_size=4, sparse_settlement=sparse,
+                      staleness_alpha=0.5)
+    c.join_batch(24)
+    rng = np.random.default_rng(6)
+    c.settle_round_batch(0, rng.random(24), timestamp=1.0)
+    before = c.snapshot()
+    heads = [b.hash for b in led.blocks]
+    ids = rng.permutation(24)[:9]
+    rs = c.compute_round(1, rng.random(9), worker_ids=ids,
+                         staleness=rng.integers(0, 3, 9))
+    after = c.snapshot()
+    for key, val in before.items():
+        if isinstance(val, np.ndarray):
+            np.testing.assert_array_equal(after[key], val)
+        else:
+            assert after[key] == val, key
+    assert [b.hash for b in led.blocks] == heads
+    # applying it then settles exactly as the one-call path would
+    seal = c.apply_round(rs)
+    assert seal.commit is not None and len(seal.penalties) == 9
+    assert (c.stake[ids] <= before["stake"][ids]).all()
+
+
 def test_settlement_scales_to_100k_workers_under_1s():
     """Acceptance: chain-only settlement at W=100,000 completes a full round
     (vectorized Algorithm 1 + Merkle commit + block seal) in < 1s on CPU."""
@@ -328,12 +359,17 @@ def test_chunked_root_with_chunk_size_one_matches_per_record_root():
     assert MerkleTree(records, chunk_size=3).root != per_record.root
 
 
-@pytest.mark.parametrize("chunk_size", [1, 3, 64, 10])
-def test_chunked_proofs_verify_and_tampering_fails(chunk_size):
-    """Across chunk sizes {1, 3, 64, W}: every worker's settlement proof
+@pytest.mark.parametrize("W,chunk_size", [
+    pytest.param(10, k, id=str(k)) for k in (1, 3, 64, 10)] + [
+    # one record, one short of / exactly / one past a power-of-two leaf
+    # count at the benchmark cells' chunk, and a leaf wider than a page
+    pytest.param(W, k, id=f"w{W}-k{k}")
+    for W, k in ((1, 64), (1023, 64), (1024, 64), (1025, 64),
+                 (4097, 4096))])
+def test_chunked_proofs_verify_and_tampering_fails(W, chunk_size):
+    """Across (W, chunk) shapes: every checked worker's settlement proof
     verifies, tampered records fail both the proof and deep chain
     verification, and hash work shrinks to ~2·ceil(W/k) nodes."""
-    W = 10                                         # chunk_size=10 == W
     led = Ledger()
     c = TrustContract(led, requester_deposit=100.0, worker_stake=10.0,
                       penalty_pct=50.0, trust_threshold=0.5, top_k=2,
@@ -343,11 +379,15 @@ def test_chunked_proofs_verify_and_tampering_fails(chunk_size):
     pen = c.settle_round_batch(0, scores)
     assert led.verify_chain(deep=True)
     n_leaves = -(-W // chunk_size)                 # ceil
-    tree = led._record_trees[led.head.index]
+    tree = led.commit(led.head.index).commit_for().tree
     assert tree.num_leaves == n_leaves
     # ~2n−1 (+ promoted odd nodes, one per level at most)
     assert tree.hash_ops <= 2 * n_leaves + len(tree.levels)
-    for w in range(W):
+    # every worker of a small round; the edges of every leaf otherwise
+    checked = range(W) if W <= 64 else sorted(
+        {0, W - 1} | {i for j in range(0, W, chunk_size)
+                      for i in (j - 1, j) if 0 <= i < W})
+    for w in checked:
         proof = c.settlement_proof(0, w)
         assert c.verify_settlement(proof)
         assert len(proof["chunk"]) <= chunk_size
@@ -363,7 +403,7 @@ def test_chunked_proofs_verify_and_tampering_fails(chunk_size):
         # and malformed offsets are rejected, not raised on
         assert not c.verify_settlement(
             dict(proof, record={**proof["record"], "score": 0.99}))
-        assert not c.verify_settlement(dict(proof, offset=99))
+        assert not c.verify_settlement(dict(proof, offset=chunk_size + 99))
         assert not c.verify_settlement(dict(proof, offset=-1))
     # tamper one stored record: its proof and deep verification both break,
     # the shallow hash chain stays intact
@@ -371,6 +411,32 @@ def test_chunked_proofs_verify_and_tampering_fails(chunk_size):
     led.tamper_record(led.head.index, victim, b"x" * 40)
     assert led.verify_chain() and not led.verify_chain(deep=True)
     assert not led.verify_record(led.head.index, victim)
+
+
+@pytest.mark.parametrize("victim", ["first", "last"])
+def test_deep_verify_rejects_tampered_edge_record(victim):
+    """At W = 1,024 (chunk 64), deep verification rebuilds the round's tree
+    and rejects a tampered first or last record, the records whose leaf
+    sits on the tree's outer edge; the shallow hash chain stays valid."""
+    W = 1024
+    led = Ledger()
+    c = TrustContract(led, requester_deposit=100.0, worker_stake=10.0,
+                      penalty_pct=50.0, trust_threshold=0.5, top_k=2,
+                      merkle_chunk_size=64)
+    c.join_batch(W)
+    rng = np.random.default_rng(4)
+    for r in range(2):
+        c.settle_round_batch(r, rng.random(W), timestamp=float(r + 1))
+    blk = c._round_blocks[0]
+    assert led.verify_chain(deep=True)
+    ri = 0 if victim == "first" else W - 1
+    good = bytes(led.record_batch(blk)[ri])
+    led.tamper_record(blk, ri, good[:-1] + bytes([good[-1] ^ 1]))
+    assert led.verify_chain()
+    assert not led.verify_chain(deep=True)
+    assert not led.verify_record(blk, ri)
+    # the untouched round still proves
+    assert led.verify_record(c._round_blocks[1], ri)
 
 
 def test_chunked_commit_hashes_fewer_nodes_and_same_settlement():

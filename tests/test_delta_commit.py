@@ -2,8 +2,8 @@
 
 The delta path's whole consensus claim is *bit-identity*: a chain of
 ``DeltaCommit``s must commit exactly the roots a dense rebuild over the
-same records would — for any change sets, chunk sizes, and (for the dense
-reference) shard counts — while hashing only the dirty paths. These
+same records would — for any change sets and chunk sizes — while hashing
+only the dirty paths. These
 properties pin that, plus the audit surface the paper's reliability story
 needs: idle workers stay proof-covered and tamper-evident in every delta
 block.
@@ -13,9 +13,9 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.chain.contract import TrustContract, _RECORD_DTYPE
-from repro.chain.ledger import (DeltaCommit, Ledger, MerkleTree, RecordBatch,
-                                ShardedCommit, batch_leaf_digests,
-                                gathered_leaf_digests, plan_shard_bounds)
+from repro.chain.ledger import (DeltaCommit, DenseCommit, Ledger, MerkleTree,
+                                RecordBatch, batch_leaf_digests,
+                                gathered_leaf_digests)
 
 REC = _RECORD_DTYPE.itemsize
 
@@ -25,11 +25,11 @@ def _batch(rng, n):
     return buf, RecordBatch(memoryview(buf).cast("B"), REC)
 
 
-def _mk_contract(sparse=True, chunk=8, shards=1, rebase=0, W=60, seed=3):
+def _mk_contract(sparse=True, chunk=8, rebase=0, W=60, seed=3):
     led = Ledger()
     c = TrustContract(led, requester_deposit=1e3, worker_stake=10.0,
                       penalty_pct=50.0, trust_threshold=0.5, top_k=3,
-                      merkle_chunk_size=chunk, settlement_shards=shards,
+                      merkle_chunk_size=chunk,
                       sparse_settlement=sparse, sparse_rebase_every=rebase)
     c.join_batch(W)
     return led, c
@@ -83,14 +83,12 @@ def test_update_leaves_bit_identical_to_rebuild(n, k, rounds, seed):
 
 @settings(max_examples=30, deadline=None)
 @given(n=st.integers(1, 120), k=st.integers(1, 9),
-       shards=st.sampled_from([1, 2, 3, 5]),
        rounds=st.integers(1, 5), seed=st.integers(0, 99))
-def test_delta_roots_bit_identical_across_change_sets(n, k, shards, rounds,
-                                                      seed):
+def test_delta_roots_bit_identical_across_change_sets(n, k, rounds, seed):
     """Delta-commit roots equal full-rebuild roots across random change
-    sets × shard counts × chunk sizes — and equal the subtree-aligned
-    ``ShardedCommit`` super-root, so a delta block is indistinguishable
-    (by root) from a dense commit over the same population."""
+    sets × chunk sizes — and equal the ``DenseCommit`` root, so a delta
+    block is indistinguishable (by root) from a dense commit over the
+    same population."""
     rng = np.random.default_rng(seed)
     cur, rb = _batch(rng, n)
     cur = cur.reshape(n, REC)
@@ -109,11 +107,7 @@ def test_delta_roots_bit_identical_across_change_sets(n, k, shards, rounds,
                             REC)
         flat_root = MerkleTree(dense, k).root
         assert commit.root == flat_root
-        bounds = plan_shard_bounds(n, k, shards)
-        sharded = ShardedCommit(
-            [RecordBatch(dense.chunk_bytes(a, b), REC)
-             for a, b in zip(bounds, bounds[1:])], k)
-        assert sharded.root == flat_root
+        assert DenseCommit(dense, k).root == flat_root
         assert commit.recompute_root() == flat_root
         # every record — changed or inherited — is proof-covered
         i = int(rng.integers(0, n))

@@ -1,12 +1,13 @@
 """Multi-tenant chain node: N concurrent federated tasks on one ledger.
 
-Pins the multi-task block layout (canonical task_id → super-root map over
-per-task ShardedCommits), N ∈ {1, 2, 5} bit-identity of per-task commits
+Pins the multi-task block layout (canonical task_id → root map over
+per-task DenseCommits), N ∈ {1, 2, 5} bit-identity of per-task commits
 vs the single-tenant driver, task-isolation under tampering (corrupting
-task A's records never invalidates task B's proofs), three-level
-settlement-proof round-trips with malformed-proof rejection, deterministic
-round-robin fairness of the shared settler pool, and per-task failure
-isolation with task_id + round surfaced in the raised error."""
+task A's records never invalidates task B's proofs), two-level
+settlement-proof round-trips with malformed-proof rejection, run-to-run
+determinism, pipelined settlement byte-identical to the inline driver, and
+failure isolation: a failing round computation commits nothing, is sticky,
+and names its task and round in the raised error."""
 import dataclasses
 
 import numpy as np
@@ -14,10 +15,10 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.chain.contract import TrustContract
-from repro.chain.ledger import (Ledger, MerkleTree, MultiTaskCommit,
-                                ShardedCommit)
+from repro.chain.ledger import (DenseCommit, Ledger, MerkleTree,
+                                MultiTaskCommit)
 from repro.core.node import (ChainNode, TaskRoundWork, TaskSettlementError,
-                             _interleave_shard_thunks, settle_tasks_block)
+                             settle_tasks_block)
 from repro.core.protocol import SDFLBProtocol
 
 
@@ -26,11 +27,10 @@ def _records(n, seed=0, size=40):
     return [bytes(rng.bytes(size)) for _ in range(n)]
 
 
-def _contract(led, tid, W, chunk=3, shards=1, deposit=1e4):
+def _contract(led, tid, W, chunk=3, deposit=1e4):
     c = TrustContract(led, requester_deposit=deposit, worker_stake=10.0,
                       penalty_pct=50.0, trust_threshold=0.5, top_k=5,
-                      merkle_chunk_size=chunk, settlement_shards=shards,
-                      task_id=tid)
+                      merkle_chunk_size=chunk, task_id=tid)
     c.join_batch(W)
     return c
 
@@ -43,19 +43,19 @@ def _contract(led, tid, W, chunk=3, shards=1, deposit=1e4):
        k=st.integers(1, 6), seed=st.integers(0, 1000))
 def test_multi_task_commit_layers_over_sharded_commits(n_tasks, base, k,
                                                        seed):
-    """Property: a MultiTaskCommit over per-task ShardedCommits has (a) a
-    single-task root bit-equal to the task's own super-root with an empty
-    task path, and (b) for any N, per-record three-level proofs (the
-    task's own proof + the task path) that verify against the combined
-    root via the unchanged MerkleTree.verify."""
+    """Property: a MultiTaskCommit over per-task DenseCommits has (a) a
+    single-task root bit-equal to the task's own root with an empty task
+    path, and (b) for any N, per-record two-level proofs (the task's own
+    proof + the task path) that verify against the combined root via the
+    unchanged MerkleTree.verify."""
     recs = {f"t{i}": _records(base + 3 * i, seed + i)
             for i in range(n_tasks)}
-    commits = {t: ShardedCommit([r], k) for t, r in recs.items()}
+    commits = {t: DenseCommit(r, k) for t, r in recs.items()}
     mtc = MultiTaskCommit(commits)
     assert mtc.task_ids == sorted(recs)
     if n_tasks == 1:
         only = next(iter(commits.values()))
-        assert mtc.root == only.root             # bit-identical to PR-3
+        assert mtc.root == only.root             # bit-identical to one task
         assert mtc.task_path("t0") == []
     for t, r in recs.items():
         assert mtc.task_roots()[t] == commits[t].root
@@ -70,12 +70,12 @@ def test_multi_task_commit_layers_over_sharded_commits(n_tasks, base, k,
 
 def test_multi_task_commit_rejects_bad_shapes():
     recs = _records(6)
-    sc = ShardedCommit([recs], 2)
+    sc = DenseCommit(recs, 2)
     with pytest.raises(ValueError):
         MultiTaskCommit({})
     with pytest.raises(ValueError):              # anonymous only when alone
         MultiTaskCommit({None: sc, "a": sc})
-    mtc = MultiTaskCommit({"a": sc, "b": ShardedCommit([_records(4, 1)], 2)})
+    mtc = MultiTaskCommit({"a": sc, "b": DenseCommit(_records(4, 1), 2)})
     with pytest.raises(KeyError):                # multi-task needs a task_id
         mtc.commit_for(None)
     with pytest.raises(KeyError):
@@ -87,9 +87,9 @@ def test_multi_task_commit_rejects_bad_shapes():
 
 @pytest.mark.parametrize("N", [1, 2, 5])
 def test_cotenant_commits_bit_identical_to_standalone(N):
-    """N ∈ {1, 2, 5} heterogeneous tasks (different W, chunk sizes, shard
-    counts) co-committed per round through settle_tasks_block produce, for
-    every task, the byte-identical super-root, penalties, and stakes it
+    """N ∈ {1, 2, 5} heterogeneous tasks (different W and chunk sizes)
+    co-committed per round through settle_tasks_block produce, for every
+    task, the byte-identical root, penalties, and stakes it
     would commit running alone through settle_round_batch — and with N=1
     the whole block (hash included) is bit-identical to the single-tenant
     driver, regardless of task_id."""
@@ -97,14 +97,13 @@ def test_cotenant_commits_bit_identical_to_standalone(N):
     tids = [f"task-{i:02d}" for i in range(N)]
     Ws = [20 + 7 * i for i in range(N)]
     chunks = [1, 3, 4, 2, 8][:N]
-    shards = [1, 2, 3, 2, 4][:N]
     rounds = 3
     scores = {tid: rng.random((rounds, W)) for tid, W in zip(tids, Ws)}
 
     solo = {}
     for i, tid in enumerate(tids):
         led = Ledger()
-        c = _contract(led, tid, Ws[i], chunks[i], shards[i])
+        c = _contract(led, tid, Ws[i], chunks[i])
         for r in range(rounds):
             c.settle_round_batch(r, scores[tid][r], timestamp=float(r + 1))
         solo[tid] = {"roots": [b.records_root for b in led.blocks[1:]],
@@ -113,7 +112,7 @@ def test_cotenant_commits_bit_identical_to_standalone(N):
                      "requester": c.requester_balance}
 
     led = Ledger()
-    cs = {tid: _contract(led, tid, Ws[i], chunks[i], shards[i])
+    cs = {tid: _contract(led, tid, Ws[i], chunks[i])
           for i, tid in enumerate(tids)}
     blocks = []
     for r in range(rounds):
@@ -126,7 +125,7 @@ def test_cotenant_commits_bit_identical_to_standalone(N):
     assert led.verify_chain(deep=True)
 
     for tid in tids:
-        # per-task super-roots are co-tenancy independent
+        # per-task roots are co-tenancy independent
         assert [led.task_roots(b.index)[tid] for b in blocks] \
             == solo[tid]["roots"]
         np.testing.assert_array_equal(cs[tid].stake, solo[tid]["stake"])
@@ -139,7 +138,7 @@ def test_cotenant_commits_bit_identical_to_standalone(N):
         assert all(set(b.task_roots) == set(tids) for b in blocks)
         task_path_len = (N - 1).bit_length()
         for tid in tids:
-            # three-level proof = the task's own two-level proof + the
+            # two-level proof = the task's own chunk path + the
             # cross-task path to the block root
             proof = cs[tid].settlement_proof(1, 0)
             assert cs[tid].verify_settlement(proof)
@@ -149,11 +148,11 @@ def test_cotenant_commits_bit_identical_to_standalone(N):
 def test_task_isolation_under_tampering():
     """Corrupting task A's stored records breaks A's proofs and deep chain
     verification but never invalidates task B's proofs — B's sibling
-    digests are the stored task/shard roots, not A's bytes."""
+    digests are the stored task roots, not A's bytes."""
     rng = np.random.default_rng(3)
     led = Ledger()
-    a = _contract(led, "task-a", 24, chunk=2, shards=2)
-    b = _contract(led, "task-b", 16, chunk=4, shards=1)
+    a = _contract(led, "task-a", 24, chunk=2)
+    b = _contract(led, "task-b", 16, chunk=4)
     sa, sb = rng.random((2, 24)), rng.random((2, 16))
     for r in range(2):
         blk, _, errors = settle_tasks_block(
@@ -175,14 +174,14 @@ def test_task_isolation_under_tampering():
 
 
 def test_three_level_proofs_roundtrip_and_malformed_rejection():
-    """Three-level settlement proofs verify for every worker of every
-    task; forgeries at each level (chunk record, shard sibling, task
-    sibling) and malformed attacker-supplied shapes are rejected, never
-    raised on."""
+    """Settlement proofs (chunk-in-task, then task-in-block) verify for
+    every worker of every task; forgeries at each level (chunk record,
+    in-task sibling, task sibling) and malformed attacker-supplied shapes
+    are rejected, never raised on."""
     rng = np.random.default_rng(11)
     led = Ledger()
-    cs = {f"t{i}": _contract(led, f"t{i}", 12 + 4 * i, chunk=2,
-                             shards=2 if i else 1) for i in range(3)}
+    cs = {f"t{i}": _contract(led, f"t{i}", 12 + 4 * i, chunk=2)
+          for i in range(3)}
     work = [TaskRoundWork(tid, c, 0, rng.random(c.num_workers))
             for tid, c in cs.items()]
     blk, _, errors = settle_tasks_block(led, work, timestamp=1.0)
@@ -196,6 +195,11 @@ def test_three_level_proofs_roundtrip_and_malformed_rejection():
             assert len(proof["proof"]) >= task_path_len
             # chunk-level forgery
             assert not c.verify_settlement(dict(proof, leaf=b"\x01" * 40))
+            # in-task forgery: the proof's head sits inside the task's tree
+            doctored = list(proof["proof"])
+            side, _ = doctored[0]
+            doctored[0] = (side, "00" * 32)
+            assert not c.verify_settlement(dict(proof, proof=doctored))
             # task-level forgery: the proof's tail crosses tasks
             doctored = list(proof["proof"])
             side, _ = doctored[-1]
@@ -222,53 +226,26 @@ def test_settle_tasks_block_rejects_duplicate_task_ids():
         settle_tasks_block(led, [w, w], timestamp=1.0)
 
 
-# -- fairness / determinism ----------------------------------------------------
-
-
-def test_shard_thunks_interleave_round_robin():
-    """The shared pool's schedule takes shard 0 of every task (canonical
-    order) before any task's shard 1 — no task starves behind a bigger
-    co-tenant."""
-    from repro.chain.contract import RoundPrep
-    ids = np.arange(1)
-    preps = {
-        "a": RoundPrep(0, ids, ids.astype(float), ["a0", "a1", "a2"]),
-        "b": RoundPrep(0, ids, ids.astype(float), ["b0"]),
-        "c": RoundPrep(0, ids, ids.astype(float), ["c0", "c1"]),
-    }
-    sched = _interleave_shard_thunks(["a", "b", "c"], preps)
-    assert [(t, i) for t, i, _ in sched] == [
-        ("a", 0), ("b", 0), ("c", 0), ("a", 1), ("c", 1), ("a", 2)]
+# -- determinism ----------------------------------------------------------------
 
 
 def test_cotenant_settlement_deterministic_across_runs_and_pools():
     """The same 2-task score stream seals byte-identical chains run to
-    run, with and without the shared worker pool engaged (seed-reproducible
-    ordering; the pool only changes who hashes)."""
-    from repro.core.node import ShardWorkerPool
-
-    def drive(pool):
+    run (seed-reproducible ordering)."""
+    def drive():
         rng = np.random.default_rng(5)
         led = Ledger()
-        a = _contract(led, "a", 40, chunk=2, shards=4)
-        b = _contract(led, "b", 24, chunk=2, shards=3)
-        a.min_parallel_leaf_bytes = 1        # force fan-out at tiny leaves
-        b.min_parallel_leaf_bytes = 1
+        a = _contract(led, "a", 40, chunk=2)
+        b = _contract(led, "b", 24, chunk=2)
         for r in range(4):
             _, _, errors = settle_tasks_block(
                 led, [TaskRoundWork("a", a, r, rng.random(40)),
                       TaskRoundWork("b", b, r, rng.random(24))],
-                timestamp=float(r + 1), pool=pool)
+                timestamp=float(r + 1))
             assert not errors
         return [blk.hash for blk in led.blocks]
 
-    pool = ShardWorkerPool(2)
-    try:
-        serial = drive(None)
-        assert drive(None) == serial         # run-to-run deterministic
-        assert drive(pool) == serial         # pool-invariant
-    finally:
-        pool.stop()
+    assert drive() == drive()
 
 
 # -- protocol-level: the ChainNode driver --------------------------------------
@@ -288,10 +265,9 @@ def _paper_setup():
 
 def test_single_task_node_bit_identical_to_serial_wrapper():
     """An N=1 node driven through the raw multi-task API (threaded,
-    sharded, arbitrary task_id) seals the byte-identical chain — blocks,
-    heads, penalties, payouts — as the serial unsharded single-task
-    wrapper: multi-tenancy is invisible until a second task actually
-    shares a block."""
+    arbitrary task_id) seals the byte-identical chain — blocks, heads,
+    penalties, payouts — as the serial single-task wrapper: multi-tenancy
+    is invisible until a second task actually shares a block."""
     from repro.data.datasets import make_federated_mnist
 
     cfg, tc, fed = _paper_setup()
@@ -304,11 +280,8 @@ def test_single_task_node_bit_identical_to_serial_wrapper():
     serial_pay = serial.finalize()
 
     ds = make_federated_mnist(6, samples=768, seed=5)
-    node = ChainNode(pipeline_depth=3, settler_pool_size=2)
-    task = node.create_task(
-        "an-arbitrary-name", cfg,
-        dataclasses.replace(fed, settlement_shards=7), tc, seed=11)
-    task.contract.min_parallel_leaf_bytes = 1    # force pool fan-out
+    node = ChainNode(pipeline_depth=3)
+    task = node.create_task("an-arbitrary-name", cfg, fed, tc, seed=11)
     for _ in range(6):
         node.run_tick({"an-arbitrary-name": ds.round_batches(32)})
     node.flush()
@@ -341,8 +314,7 @@ def test_multi_task_node_end_to_end_heterogeneous_cadences():
     feds = {
         "mnist-a": FederationConfig(num_clusters=1, workers_per_cluster=3,
                                     trust_threshold=0.3, top_k_rewarded=2,
-                                    merkle_chunk_size=2,
-                                    settlement_shards=2),
+                                    merkle_chunk_size=2),
         "mnist-b": FederationConfig(num_clusters=2, workers_per_cluster=2,
                                     trust_threshold=0.4, top_k_rewarded=3,
                                     merkle_chunk_size=1),
@@ -370,7 +342,7 @@ def test_multi_task_node_end_to_end_heterogeneous_cadences():
     assert multi and solo                      # both layouts exercised
     assert set(multi[0].task_roots) == set(feds)   # tick 0: all three fire
     assert node.ledger.verify_chain(deep=True)
-    # three-level proof out of a genuinely multi-task block
+    # two-level proof out of a genuinely multi-task block
     a = tasks["mnist-a"].contract
     proof = a.settlement_proof(0, 1)
     assert proof["block_index"] == multi[0].index
@@ -425,7 +397,7 @@ def test_task_joining_running_node_is_deterministic():
 
 
 def test_task_failure_isolated_and_error_names_task_and_round():
-    """Satellite regression: a failing shard aborts only its own task's
+    """Regression: a failing round computation aborts only its own task's
     round — the raised TaskSettlementError carries the task_id AND the
     round index (the settle failure used to report only the round), the
     co-tenant keeps settling and finalizes normally, and the failed
@@ -437,22 +409,21 @@ def test_task_failure_isolated_and_error_names_task_and_round():
     cfg = get_config("paper-net")
     tc = TrainConfig(lr=0.01, momentum=0.5, optimizer="sgd", remat=False)
     fed = FederationConfig(num_clusters=1, workers_per_cluster=3,
-                           trust_threshold=0.2, merkle_chunk_size=1,
-                           settlement_shards=3)
-    node = ChainNode(pipeline_depth=2, settler_pool_size=2)
+                           trust_threshold=0.2, merkle_chunk_size=1)
+    node = ChainNode(pipeline_depth=2)
     a = node.create_task("task-a", cfg, fed, tc, seed=0)
     b = node.create_task("task-b", cfg, fed, tc, seed=1)
     dsa = make_federated_mnist(3, samples=256, seed=0)
     dsb = make_federated_mnist(3, samples=256, seed=1)
 
-    orig = a.contract.settle_shard
+    orig = a.contract.compute_round
 
-    def failing_shard(round_index, ids, s, start, stop):
+    def failing_compute(round_index, *args, **kwargs):
         if round_index >= 1:
-            raise RuntimeError("shard worker died")
-        return orig(round_index, ids, s, start, stop)
+            raise RuntimeError("round computation died")
+        return orig(round_index, *args, **kwargs)
 
-    a.contract.settle_shard = failing_shard
+    a.contract.compute_round = failing_compute
     node.run_tick({"task-a": dsa.round_batches(16),
                    "task-b": dsb.round_batches(16)})
     stake_before = a.contract.stake.copy()     # settled through round 0
@@ -489,3 +460,74 @@ def test_task_failure_isolated_and_error_names_task_and_round():
     payouts = node.finalize()                  # skips the poisoned task
     assert set(payouts) == {"task-b"}
     assert node.ledger.verify_chain(deep=True)
+
+
+def _decision_trace(proto):
+    return {
+        "blocks": [b.hash for b in proto.ledger.blocks],
+        "heads": [tuple(r.heads) for r in proto.history],
+        "penalties": np.stack([r.penalties for r in proto.history]),
+        "cids": [r.model_cid for r in proto.history],
+    }
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_pipelined_settler_matches_inline_driver(depth):
+    """Property: the settler thread (pipeline_depth ∈ {1, 2}) produces
+    byte-identical chains, elections, penalties, model cids and payouts to
+    the inline reference driver (depth 0) on the same data."""
+    from repro.data.datasets import make_federated_mnist
+
+    cfg, tc, fed = _paper_setup()
+    runs = {}
+    for d in (0, depth):
+        ds = make_federated_mnist(6, samples=768, seed=5)
+        proto = SDFLBProtocol(cfg, dataclasses.replace(fed, pipeline_depth=d),
+                              tc, use_blockchain=True, seed=11)
+        for _ in range(6):
+            proto.run_round(ds.round_batches(32))
+        proto.flush()
+        payouts = proto.finalize()
+        assert proto.ledger.verify_chain(deep=True)
+        runs[d] = (_decision_trace(proto), payouts)
+    inline, piped = runs[0], runs[depth]
+    assert inline[0]["blocks"] == piped[0]["blocks"]      # byte-identical
+    assert inline[0]["heads"] == piped[0]["heads"]
+    assert inline[0]["cids"] == piped[0]["cids"]
+    np.testing.assert_array_equal(inline[0]["penalties"],
+                                  piped[0]["penalties"])
+    assert inline[1] == piped[1]                          # payouts
+
+
+def test_failed_round_computation_is_sticky_and_never_half_commits():
+    """A round whose computation fails aborts with contract state and
+    chain untouched, poisons the task for later rounds (sticky re-raise),
+    and discards everything still queued."""
+    from repro.configs.base import FederationConfig, TrainConfig
+    from repro.configs.registry import get_config
+    from repro.data.datasets import make_federated_mnist
+
+    cfg = get_config("paper-net")
+    tc = TrainConfig(lr=0.01, momentum=0.5, optimizer="sgd", remat=False)
+    fed = FederationConfig(num_clusters=1, workers_per_cluster=6,
+                           trust_threshold=0.2, merkle_chunk_size=1,
+                           pipeline_depth=2)
+    ds = make_federated_mnist(6, samples=256, seed=0)
+    proto = SDFLBProtocol(cfg, fed, tc, use_blockchain=True, seed=0)
+
+    def failing_compute(*args, **kwargs):
+        raise RuntimeError("round computation died")
+
+    proto.contract.compute_round = failing_compute
+    stake_before = proto.contract.stake.copy()
+    with pytest.raises(RuntimeError):
+        for _ in range(4):
+            proto.run_round(ds.round_batches(16))
+    with pytest.raises(RuntimeError):
+        proto.flush()
+    with pytest.raises(RuntimeError):                     # sticky
+        proto.flush()
+    # nothing was applied or committed: genesis only, stakes untouched
+    assert len(proto.ledger.blocks) == 1
+    np.testing.assert_array_equal(proto.contract.stake, stake_before)
+    assert proto.contract.requester_balance == 0.0

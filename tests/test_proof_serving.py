@@ -2,7 +2,7 @@
 the ``repro.serve`` server/light-client pair.
 
 Pins (a) the unified proof surface verifying across every commit flavor
-(dense, sharded, delta-overlay, multi-task) with the deprecated wrappers
+(dense, delta-overlay, multi-task) with the deprecated wrappers
 emitting bit-identical proofs; (b) batched multiproof round-trips with
 shared-path deduplication, and rejection of tampering at every level
 (chunk bytes, shipped siblings, offsets, plan, root, and the stored
@@ -30,12 +30,11 @@ from repro.serve import (ChainReadServer, HeaderVerificationError,
                          StaleProofError)
 
 
-def _contract(W, *, sparse=False, shards=1, chunk=8, multi=None):
+def _contract(W, *, sparse=False, chunk=8, multi=None):
     c = TrustContract(Ledger(), requester_deposit=1e6, worker_stake=10.0,
                       penalty_pct=50.0, trust_threshold=0.5,
                       top_k=max(W // 4, 1), merkle_chunk_size=chunk,
-                      sparse_settlement=sparse, settlement_shards=shards,
-                      task_id=multi)
+                      sparse_settlement=sparse, task_id=multi)
     c.join_batch(W)
     return c
 
@@ -55,10 +54,11 @@ def _settle(c, rounds=2, seed=0, cohort=None):
 
 
 def _flavors():
-    """One settled contract per commit flavor the chain produces."""
+    """One settled contract per commit flavor the chain produces; the
+    dense-tail round has a partial last leaf and odd-node promotions."""
     return {
         "dense": _settle(_contract(64)),
-        "sharded": _settle(_contract(64, shards=4)),
+        "dense-tail": _settle(_contract(70)),
         "delta": _settle(_contract(64, sparse=True), cohort=16),
     }
 
@@ -66,7 +66,7 @@ def _flavors():
 # -- (a) unified SettlementProof across flavors -------------------------------
 
 
-@pytest.mark.parametrize("flavor", ["dense", "sharded", "delta"])
+@pytest.mark.parametrize("flavor", ["dense", "dense-tail", "delta"])
 def test_settlement_proof_roundtrip_all_flavors(flavor):
     c = _flavors()[flavor]
     for w in (0, 7, 63):
@@ -81,7 +81,7 @@ def test_settlement_proof_roundtrip_all_flavors(flavor):
 
 def test_settlement_proof_multi_task_block():
     """Two co-tenant tasks settling in one multi-task block: each task's
-    proof resolves through the third (task) Merkle level, single and
+    proof resolves through the task Merkle level, single and
     batched, and the serve path spans both tenants."""
     from repro.core.node import TaskRoundWork, settle_tasks_block
     ledger = Ledger()
@@ -133,12 +133,12 @@ def test_verify_rejects_wrong_head_and_garbage():
 
 
 @settings(max_examples=20, deadline=None)
-@given(chunk=st.sampled_from([1, 3, 8]), shards=st.sampled_from([1, 4]),
+@given(chunk=st.sampled_from([1, 3, 8]),
        w=st.integers(min_value=0, max_value=23))
-def test_legacy_wrapper_bit_identity(chunk, shards, w):
+def test_legacy_wrapper_bit_identity(chunk, w):
     """The deprecated dict ``settlement_proof`` is exactly the typed
     proof's legacy projection, and ``Ledger.merkle_proof`` is its path."""
-    c = _settle(_contract(24, chunk=chunk, shards=shards), rounds=1)
+    c = _settle(_contract(24, chunk=chunk), rounds=1)
     sp = c.proof(0, w)
     legacy = c.settlement_proof(0, w)
     assert legacy == sp.as_legacy_dict()
@@ -164,7 +164,7 @@ def test_verify_settlement_rejects_malformed_dicts():
 # -- (b) batched multiproofs ---------------------------------------------------
 
 
-@pytest.mark.parametrize("flavor", ["dense", "sharded", "delta"])
+@pytest.mark.parametrize("flavor", ["dense", "dense-tail", "delta"])
 def test_proof_batch_roundtrip_and_dedup(flavor):
     c = _flavors()[flavor]
     blk = c.ledger.blocks[c._round_blocks[1]]
@@ -186,7 +186,7 @@ def test_proof_batch_tamper_rejection_every_level():
     """Flipping any component — leaf chunk bytes, any shipped sibling,
     record offset, plan, claimed root, or the record's leaf assignment —
     must flip verification to False (never raise)."""
-    c = _settle(_contract(64, shards=4))
+    c = _settle(_contract(64))
     blk = c.ledger.blocks[c._round_blocks[1]]
     wids = [0, 9, 33, 63]
 
@@ -215,8 +215,8 @@ def test_proof_batch_tamper_rejection_every_level():
     assert not verify_proof_batch(b, blk)
     # record pointed at a key never lifted to the root
     b = fresh()
-    b.chunks[("S", 99, 0, 0)] = b.chunks[key]
-    b.records[0] = (ri, ("S", 99, 0, 0), 0)
+    b.chunks[("N", 99, 0)] = b.chunks[key]
+    b.records[0] = (ri, ("N", 99, 0), 0)
     assert not verify_proof_batch(b, blk)
     # truncated plan: root never computed
     b = fresh()
